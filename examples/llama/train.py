@@ -31,7 +31,8 @@ import common  # noqa: E402,F401
 def main():
     p = argparse.ArgumentParser(description="Llama training (GSPMD sharded)")
     p.add_argument("--device", default="auto", choices=["auto", "cpu", "tpu"],
-                   help="cpu pins the host backend before JAX init")
+                   help="cpu pins the host backend before JAX init; tpu "
+                        "fails unless jax resolved to a TPU")
     p.add_argument("--preset", default="tiny", choices=["tiny", "small", "8b"])
     p.add_argument("--dp", type=int, default=1, help="data-parallel ways")
     p.add_argument("--tp", type=int, default=1, help="tensor-parallel ways")
@@ -88,7 +89,9 @@ def main():
         import jax
         jax.config.update("jax_platforms", "cpu")
 
-    from singa_tpu import models, opt, parallel, tensor
+    from singa_tpu import device, models, opt, parallel, tensor
+
+    device.set_default_device(common.make_device(args.device))
 
     presets = {
         "tiny": models.LlamaConfig.tiny,

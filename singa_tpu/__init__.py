@@ -13,7 +13,6 @@ scripts run with only the device line changed.
 
 __version__ = "0.3.0"
 
-from . import _compat  # jax version shims — must run before submodules
 from . import device
 from . import proto
 from . import tensor

@@ -62,24 +62,15 @@ class CapturedGraph:
     def compiled_hlo(self) -> str:
         if self.compiled is None:
             return ""
-        try:
-            return self.compiled.as_text()
-        except Exception:
-            return ""
+        return self.compiled.as_text()
 
     def cost_analysis(self) -> Dict[str, Any]:
         """XLA cost analysis of the compiled module (flops, bytes)."""
         if self.compiled is None:
             return {}
         from .obs import events as obs_events
-        try:
-            with obs_events.span("graph.cost_analysis", graph=self.name):
-                ca = self.compiled.cost_analysis()
-            if isinstance(ca, list):
-                ca = ca[0] if ca else {}
-            return dict(ca)
-        except Exception:
-            return {}
+        with obs_events.span("graph.cost_analysis", graph=self.name):
+            return dict(self.compiled.cost_analysis())
 
     def flops(self) -> float:
         return float(self.cost_analysis().get("flops", 0.0))
@@ -87,11 +78,8 @@ class CapturedGraph:
     def memory_analysis(self) -> Dict[str, Any]:
         if self.compiled is None:
             return {}
-        try:
-            ma = self.compiled.memory_analysis()
-            return {k: getattr(ma, k) for k in dir(ma) if not k.startswith("_")}
-        except Exception:
-            return {}
+        ma = self.compiled.memory_analysis()
+        return {k: getattr(ma, k) for k in dir(ma) if not k.startswith("_")}
 
     def save_hlo(self, path: str) -> None:
         with open(path, "w") as f:

@@ -4,8 +4,8 @@ telemetry artifacts this repo commits.
 Why this exists: round 5 lost its on-chip evidence because the record
 files had no contract — a CPU smoke session silently overwrote the
 on-chip `tpu_session.json`, and the README generator then crashed with a
-raw ``KeyError: 'batch'`` against the record actually committed
-(VERDICT.md).  Every consumer of a record now goes through
+raw ``KeyError: 'batch'`` against the record actually committed.
+Every consumer of a record now goes through
 :func:`require`, so a missing field fails loudly with its *name* and the
 context it was needed in, and :func:`validate_entry` checks whole
 entries so a stale or truncated record is caught at write/lint time.

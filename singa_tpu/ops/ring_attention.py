@@ -126,7 +126,8 @@ def _flash_ring_auto(Tl: int, D: int) -> bool:
     and drives exercise the interpret-mode flash ring."""
     import os
 
-    from .flash_attention import _on_tpu, _tileable
+    from ..device import on_tpu
+    from .flash_attention import _tileable
     if not _tileable(Tl, Tl, D):
         return False
     if os.environ.get("SINGA_DISABLE_FLASH"):
@@ -136,7 +137,7 @@ def _flash_ring_auto(Tl: int, D: int) -> bool:
         return True
     if force == "0":
         return False
-    return _on_tpu()
+    return on_tpu()
 
 
 def _ring_local_flash(q, k, v, axis: str, causal: bool, scale: float):

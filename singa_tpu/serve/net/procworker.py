@@ -345,7 +345,9 @@ def main(argv=None) -> int:
 
     # platform pinning BEFORE any backend init (same recipe as
     # tests/conftest.py — bitwise identity with in-process engines
-    # requires the same virtual platform)
+    # requires the same virtual platform).  Every worker runs on the
+    # CPU, also on a machine with a chip: a chip belongs to one process,
+    # and N workers cannot share it (docs/serving.md)
     from singa_tpu.utils import virtcpu
     if not virtcpu.pin_virtual_cpu(int(cfg.get("devices", 1))):
         print(f"procworker {args.name}: could not pin virtual CPU "
@@ -376,9 +378,14 @@ def main(argv=None) -> int:
         engine_kwargs["draft_model"] = model
         engine_kwargs["spec_k"] = int(cfg["self_spec_k"])
     engine = ServeEngine(model, **engine_kwargs)
+    import jax
+    dev = jax.devices()[0]
+    # the platform THIS process runs on: tier records carry it, not
+    # whatever backend the supervising process happens to hold
     ready = {"op": "ready", "name": args.name, "ok": True,
              "ready_ms": (time.perf_counter() - t0) * 1e3,
-             "pid": os.getpid()}
+             "pid": os.getpid(), "platform": dev.platform,
+             "device_kind": dev.device_kind}
     try:
         from singa_tpu.autotune import table as autotune_table
         ready["model_key"] = autotune_table.model_key(model)

@@ -159,6 +159,9 @@ class WorkerProc:
         self.model_key: Optional[str] = None
         self.compiles: Optional[dict] = None
         self.ready_ms: Optional[float] = None
+        #: where this worker's engine runs, as the worker reported it
+        self.platform: Optional[str] = None
+        self.device_kind: Optional[str] = None
         #: a timed-out / errored socket may sit mid-frame — the next
         #: recv on it would misparse stale bytes as a fresh reply, so
         #: the FIRST WorkerDied poisons the connection for good and
@@ -350,6 +353,8 @@ class _Fabric:
                     w.model_key = ready.get("model_key")
                     w.compiles = ready.get("compiles")
                     w.ready_ms = ready.get("ready_ms")
+                    w.platform = ready.get("platform")
+                    w.device_kind = ready.get("device_kind")
                     out.append(w)
                 return out
             except socket.timeout:
@@ -738,6 +743,10 @@ class ProcRouter:
         if len(set(names)) != len(names):
             raise ValueError(f"worker names must be unique, got {names}")
         self.fabric = self.prefill[0].fabric
+        #: records carry the WORKERS' platform — the work runs there;
+        #: this process may hold another backend, or none
+        self.platform = self.prefill[0].platform
+        self.device_kind = self.prefill[0].device_kind
         self.slo_classes = dict(slo_classes or {})
         self.record_store = record_store
         self.run_id = run_id or obs_record.new_run_id("mptier")
@@ -1608,17 +1617,14 @@ class ProcRouter:
         if not self.record_store:
             return
         try:
-            import jax
-            platform = jax.default_backend()
-            dev = jax.devices()[0]
             payload = {"site": site, "fault": fault, "ref": ref,
                        "outcome": outcome, "retries": int(retries),
                        "engine_run": self.run_id}
             if flight_ref:
                 payload["flight_ref"] = flight_ref
             entry = obs_record.new_entry(
-                "incident", platform, platform != "tpu",
-                getattr(dev, "device_kind", "") or platform,
+                "incident", self.platform, self.platform != "tpu",
+                self.device_kind,
                 run_id=f"{self.run_id}-inc{next(self._incident_seq)}",
                 payload=payload)
             obs_record.RunRecord(self.record_store).append(entry)

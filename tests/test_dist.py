@@ -10,21 +10,8 @@ import numpy as np
 import pytest
 
 from singa_tpu import autograd, device, layer, model, opt, parallel, tensor
-from singa_tpu._compat import legacy_jax
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-
-# ZeRO-1 shards optimizer slots via donated buffers; the 0.4.37-era
-# XLA mis-aliases the donation under GSPMD (wrong update numerics /
-# xla_extension errors).  Pre-existing at seed on such images; on
-# modern jax the condition deactivates the marker entirely, so the
-# tests run — and must pass — there.  run=False: executing a known-
-# wrong multi-compile training comparison on the legacy image only
-# burns tier-1 wall clock (2-core box, 870 s budget).
-_zero1_xfail = pytest.mark.xfail(
-    legacy_jax(), strict=False, run=False,
-    reason="jax<0.5: XLA donation aliasing under GSPMD breaks ZeRO-1 "
-           "sharded slot updates (pre-existing on 0.4.37-era images)")
 
 
 class MLP(model.Model):
@@ -644,7 +631,6 @@ def test_two_batch_shapes_no_donated_slot_aliasing():
     assert np.isfinite(float(loss.to_numpy()))
 
 
-@_zero1_xfail
 def test_zero1_sharded_weight_update_matches_single_device():
     """DistOpt(shard_weight_update=True): ZeRO-1 slot sharding over the
     data axis must not change the training trajectory vs a single-device
@@ -655,7 +641,6 @@ def test_zero1_sharded_weight_update_matches_single_device():
     np.testing.assert_allclose(l_single, l_z1, rtol=2e-4, atol=1e-5)
 
 
-@_zero1_xfail
 def test_zero1_slots_physically_sharded():
     """Optimizer moments must live sharded over 'data' (1/N HBM per
     device) for eligible leaves, replicated for indivisible ones."""
@@ -672,7 +657,6 @@ def test_zero1_slots_physically_sharded():
     assert ("reduce-scatter" in hlo) or ("all-reduce" in hlo)
 
 
-@_zero1_xfail
 def test_zero1_checkpoint_resume_natural_shapes(tmp_path):
     """save_states under ZeRO-1 must write natural-shaped moments (the
     jax.Array is global-shaped; sharding is physical only), and a

@@ -58,7 +58,7 @@ class TestFaultPlan:
         with pytest.raises(ValueError, match="unknown fault kind"):
             FaultSpec("serve.decode", "explode")
 
-    def test_site_kind_compatibility(self):
+    def test_site_kind_pairing(self):
         # serve.prefill supports error/hang, not nan or torn_write
         with pytest.raises(ValueError, match="does not support"):
             FaultSpec("serve.prefill", "nan")

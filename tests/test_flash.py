@@ -146,8 +146,8 @@ def test_flash_tq_ne_tk_noncausal():
 
 
 def test_flash_block_size_override(monkeypatch):
-    """SINGA_FLASH_BLOCK tunes the kernel tiles; invalid overrides fall
-    back; numerics unchanged either way (interpret mode)."""
+    """SINGA_FLASH_BLOCK tunes the kernel tiles; an override that does
+    not tile raises; numerics unchanged (interpret mode)."""
     import jax.numpy as jnp
 
     from singa_tpu.ops.attention import _sdpa_reference
@@ -157,10 +157,10 @@ def test_flash_block_size_override(monkeypatch):
     assert _block_sizes(256, 256) == (256, 256)
     monkeypatch.setenv("SINGA_FLASH_BLOCK", "128,128")
     assert _block_sizes(256, 256) == (128, 128)
-    monkeypatch.setenv("SINGA_FLASH_BLOCK", "384,128")   # 384 ∤ 256
-    assert _block_sizes(256, 256) == (256, 256)
-    monkeypatch.setenv("SINGA_FLASH_BLOCK", "garbage")
-    assert _block_sizes(256, 256) == (256, 256)
+    for bad in ("384,128", "garbage"):                   # 384 ∤ 256
+        monkeypatch.setenv("SINGA_FLASH_BLOCK", bad)
+        with pytest.raises(ValueError, match="SINGA_FLASH_BLOCK"):
+            _block_sizes(256, 256)
 
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 256, 2, 32).astype(np.float32))

@@ -322,6 +322,7 @@ class _HealWorker:
         self.load = 0
         self.pid = 1000
         self.model_key = "fake"
+        self.platform = self.device_kind = "cpu"
         self.poisoned = False
         self.last_ok = time.monotonic()
         self.ok_ticks = 99

@@ -9,24 +9,8 @@ import numpy as np
 import pytest
 
 from singa_tpu import parallel
-from singa_tpu._compat import legacy_jax
 from singa_tpu.parallel import pipeline as pp
 from singa_tpu.parallel.mesh import P
-
-# The experimental shard_map these images promote to jax.shard_map
-# (singa_tpu._compat) carries the old gradient/replication semantics,
-# which skews the GPipe schedule's numerics-vs-sequential checks.
-# Pre-existing at seed on 0.4.37-era images; on modern jax the
-# condition deactivates the marker entirely, so the tests run — and
-# must pass — there.  run=False: each of these compiles a pipelined
-# AND a sequential model just to reproduce a known-wrong comparison on
-# the legacy image — wasted tier-1 wall clock (2-core box, 870 s
-# budget).
-_old_shard_map_xfail = pytest.mark.xfail(
-    legacy_jax(), strict=False, run=False,
-    reason="jax<0.5: experimental shard_map's old grad semantics break "
-           "pipeline-vs-sequential numerics (pre-existing on 0.4.37-era "
-           "images)")
 
 
 def _stages(S, d, seed=0):
@@ -178,23 +162,17 @@ class TestModelAPIPipeline:
         finally:
             parallel.set_mesh(None)
         return m, losses, hlo
-
-    @_old_shard_map_xfail
     def test_llama_pipeline_matches_sequential(self):
         _, l_seq, _ = self._run(False)
         _, l_pipe, hlo = self._run(True)
         np.testing.assert_allclose(l_seq, l_pipe, rtol=2e-4, atol=2e-5)
         # the schedule's activation hand-off must ride collective-permute
         assert "collective-permute" in hlo
-
-    @_old_shard_map_xfail
     def test_llama_pipeline_more_microbatches(self):
         """n_micro > stages (smaller bubbles) stays equivalent."""
         _, l_seq, _ = self._run(False, steps=2)
         _, l_pipe, _ = self._run(True, steps=2, micro=8)
         np.testing.assert_allclose(l_seq, l_pipe, rtol=2e-4, atol=2e-5)
-
-    @_old_shard_map_xfail
     def test_llama_pipeline_with_remat_matches(self):
         _, l_seq, _ = self._run(False, steps=2)
         _, l_pipe, _ = self._run(True, steps=2, remat=True)
@@ -266,14 +244,10 @@ class TestPipelineComposition:
             return losses
         finally:
             parallel.set_mesh(None)
-
-    @_old_shard_map_xfail
     def test_dp_sp_pipe_matches_sequential(self):
         l_seq = self._run(None, 0)
         l_3d = self._run({"data": 2, "seq": 2, "pipe": 2}, 2)
         np.testing.assert_allclose(l_seq, l_3d, rtol=2e-4, atol=2e-5)
-
-    @_old_shard_map_xfail
     def test_dp_tp_pipe_matches_sequential(self):
         l_seq = self._run(None, 0)
         l_3d = self._run({"data": 2, "model": 2, "pipe": 2}, 2)
@@ -284,8 +258,6 @@ class TestPipelineExtras:
     """Masked transformer blocks pipeline too: non-grad batch-leading
     extras (padding masks) are microbatched and gathered per stage per
     tick; GPT-2 gains pipeline_stages."""
-
-    @_old_shard_map_xfail
     def test_gpt2_pipeline_matches_sequential(self):
         from singa_tpu import models, opt, tensor
 
@@ -318,8 +290,6 @@ class TestPipelineExtras:
 
         np.testing.assert_allclose(run(False), run(True),
                                    rtol=2e-4, atol=2e-5)
-
-    @_old_shard_map_xfail
     def test_masked_blocks_pipeline_matches_sequential(self):
         from singa_tpu import autograd, layer, model, models, opt, tensor
         from singa_tpu.models.transformer import (_GPT2Block,

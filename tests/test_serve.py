@@ -61,6 +61,13 @@ class TestGreedyEquivalence:
         np.testing.assert_array_equal(ref, np.asarray(h.tokens))
         np.testing.assert_array_equal(
             h.result(), np.concatenate([prompt, ref]))
+        # the tolerance form of the same check, used on the chip where
+        # bf16 breaks bitwise identity: 0 for a greedy stream, large
+        # once a token is not the arg-max
+        seq = h.result()
+        assert llama.greedy_margin(seq, prompt.size) == 0.0
+        seq[-3] = (seq[-3] + 1) % llama.cfg.vocab_size
+        assert llama.greedy_margin(seq, prompt.size) > 1e-3
 
     def test_mixed_lengths_concurrent_match_generate(self, llama, engine):
         """Six requests of four distinct prompt lengths decode

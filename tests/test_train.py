@@ -29,7 +29,6 @@ import numpy as np
 import pytest
 
 from singa_tpu import models, opt, parallel, tensor
-from singa_tpu._compat import legacy_jax
 from singa_tpu.obs import events, record
 from singa_tpu.obs.record import RunRecord
 from singa_tpu.obs.schema import SchemaError
@@ -619,13 +618,7 @@ class TestTrainRunRecords:
 # ZeRO-1: sharded optimizer state must round-trip through the orchestrator
 # ---------------------------------------------------------------------------
 
-_zero1_xfail = pytest.mark.xfail(
-    legacy_jax(), strict=False, run=False,
-    reason="jax<0.5: XLA donation aliasing under GSPMD breaks ZeRO-1 "
-           "sharded slot updates (pre-existing on 0.4.37-era images)")
 
-
-@_zero1_xfail
 def test_zero1_opt_state_roundtrips_through_orchestrator(tmp_path):
     """DistOpt(shard_weight_update=True): checkpoints written by the
     orchestrator hold natural-shaped moments, and a resumed run seeds

@@ -415,12 +415,10 @@ def run_campaign(seed: int, n_events: int, *, per_phase: int = 4,
         payload["flight_ref"] = flight_ref
     ok = bitwise_ok and not problems and completed == requests
     if store:
-        import jax
-        platform = jax.default_backend()
-        dev = jax.devices()[0]
+        # the campaign's work ran in the tier's worker processes
         entry = obs_record.new_entry(
-            "chaos_campaign", platform, platform != "tpu",
-            getattr(dev, "device_kind", "") or platform,
+            "chaos_campaign", tier.platform, tier.platform != "tpu",
+            tier.device_kind,
             run_id=obs_record.new_run_id("chaos"), payload=payload)
         obs_record.RunRecord(store).append(entry)
     return {"ok": bool(ok), "payload": payload, "problems": problems}

@@ -234,7 +234,7 @@ def run_load(engine, workload: List[_Arrival], *,
 
 
 def append_record(payload: dict, store: Optional[str] = None,
-                  prefix: str = "load") -> str:
+                  prefix: str = "load", tier=None) -> str:
     """Write the headline (schema-required fields + numeric extras;
     the ``detail`` sub-dict stays out of the durable record) as a
     ``serve_load`` entry.  Returns the store path.
@@ -243,20 +243,26 @@ def append_record(payload: dict, store: Optional[str] = None,
     the same second: the store keys entries by ``(run_id, platform,
     smoke)`` and ``new_run_id``'s timestamp has second resolution, so
     back-to-back same-prefix appends (the --spec-compare pair) would
-    silently overwrite each other."""
-    import jax
+    silently overwrite each other.
 
+    The record names the platform the work ran on: this process's jax
+    backend, or — for a ``--procs`` ``tier`` — the one its worker
+    processes reported (they run on the CPU whatever this process
+    holds)."""
     from singa_tpu.obs import record as obs_record
     from singa_tpu.obs import schema
 
     body = {k: v for k, v in payload.items() if k != "detail"}
     body.update({k: v for k, v in payload["detail"].items()
                  if isinstance(v, (int, float))})
-    platform = jax.default_backend()
-    dev = jax.devices()[0]
+    if tier is not None:
+        platform, device_kind = tier.platform, tier.device_kind
+    else:
+        import jax
+        dev = jax.devices()[0]
+        platform, device_kind = dev.platform, dev.device_kind
     entry = obs_record.new_entry(
-        "serve_load", platform, platform != "tpu",
-        getattr(dev, "device_kind", "") or platform,
+        "serve_load", platform, platform != "tpu", device_kind,
         run_id=obs_record.new_run_id(prefix), payload=body)
     schema.validate_entry(entry)           # fail before touching disk
     store = store or os.path.join(_REPO, obs_record.DEFAULT_STORE)
@@ -847,6 +853,11 @@ def main(argv=None) -> int:
                          "engine only; default: no spill tier)")
     args = ap.parse_args(argv)
 
+    import jax
+
+    from singa_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache(jax.devices()[0].platform)
+
     if args.disagg_smoke:
         return disagg_smoke()
     if args.mp_smoke:
@@ -948,7 +959,8 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             print(json.dumps(payload, indent=2))
             if store is not None:
-                append_record(payload, store, prefix=f"mpload{i}")
+                append_record(payload, store, prefix=f"mpload{i}",
+                              tier=tier)
         if store is not None:
             print(f"# {len(rows)} serve_load entries (mp sweep "
                   f"{sweep_id}) appended to {store}", file=sys.stderr)
@@ -1055,7 +1067,8 @@ def main(argv=None) -> int:
     print(json.dumps(payload, indent=2))
     if store is not None:
         append_record(payload, store,
-                      prefix="mpload" if args.procs else "load")
+                      prefix="mpload" if args.procs else "load",
+                      tier=eng if args.procs else None)
         print(f"# serve_load entry appended to {store}", file=sys.stderr)
     if not args.procs:
         # attribution is per-process: the supervisor dispatches no XLA
