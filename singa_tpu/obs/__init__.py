@@ -9,10 +9,11 @@ The observability subsystem (ISSUE 1):
   JSONL store of bench/session runs keyed by
   ``(run_id, platform, smoke)`` with atomic write-temp-then-rename;
   smoke/CPU entries can never overwrite or shadow on-chip entries.
-* :mod:`~singa_tpu.obs.events` — ``trace_span`` / ``counter`` /
-  ``gauge`` with a JSONL sink and optional ``jax.profiler``
-  annotation passthrough, wired into the compiled-step, collective,
-  and grad-sync hot paths.
+* :mod:`~singa_tpu.obs.events` — ``span`` / ``counter`` / ``gauge``
+  with a JSONL sink, wired into the compiled-step, collective,
+  grad-sync and serve-engine hot paths; every span is also a
+  ``jax.profiler.TraceAnnotation``, so a profiler trace shows it on
+  the device ops' clock.
 * :mod:`~singa_tpu.obs.trace` — contextvar-carried request/step trace
   contexts (ISSUE 11): every event emitted inside an active trace is
   stamped with its id, spans nest, and worker threads inherit (or
@@ -34,7 +35,7 @@ rule.
 
 from . import attr, events, flight, record, schema, trace
 from .events import (configure, counter, gauge, histogram,
-                     histogram_summary, reset_histograms, span, trace_span)
+                     histogram_summary, reset_histograms, span)
 from .flight import FlightRecorder
 from .record import RunRecord, is_onchip_session_doc, new_entry, new_run_id
 from .schema import SCHEMA_VERSION, SchemaError, require
@@ -43,5 +44,5 @@ __all__ = ["schema", "record", "events", "trace", "flight", "attr",
            "FlightRecorder", "RunRecord", "SchemaError",
            "SCHEMA_VERSION", "require", "new_entry", "new_run_id",
            "is_onchip_session_doc", "configure", "counter", "gauge",
-           "span", "trace_span", "histogram", "histogram_summary",
+           "span", "histogram", "histogram_summary",
            "reset_histograms"]

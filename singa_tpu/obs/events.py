@@ -1,19 +1,23 @@
 """Structured telemetry events: spans, counters, gauges.
 
 A thin host-side event layer over the hot paths (compiled-step
-dispatch, XLA compiles, collective staging, grad sync).  Disabled by
-default and engineered so the disabled path costs one attribute check —
-`span()` returns a shared no-op context manager and `counter()/gauge()`
-return immediately — because `Model.train_step` calls into here every
-step.
+dispatch, XLA compiles, collective staging, grad sync, the serve
+engine's step).  The JSONL sink is off by default and the sinkless path
+is engineered to cost about a microsecond — `counter()/gauge()` return
+after one attribute check and `span()` is a bare
+``jax.profiler.TraceAnnotation`` — because `Model.train_step` and
+`ServeEngine.step` call into here every step.
 
-Enable with either:
+Every span is a ``jax.profiler.TraceAnnotation`` under the span's own
+name (its attributes become the event's stats): inert without a
+profiler session, and with one (``jax.profiler.start_trace``) recorded
+in the same ``.xplane.pb`` as the device's ops, on the same clock —
+the host thread's line says which phase of a step the chip was waiting
+on.  There is no switch for that.
 
-* ``SINGA_OBS=/path/to/events.jsonl`` in the environment (one JSON
-  object per line), or programmatically ``events.configure(path=...)``;
-* ``SINGA_OBS_XPROF=1`` to additionally wrap spans in
-  ``jax.profiler.TraceAnnotation`` so they show up on the XProf/
-  TensorBoard timeline next to the device trace.
+Enable the JSONL sink with ``SINGA_OBS=/path/to/events.jsonl`` in the
+environment (one JSON object per line), or programmatically
+``events.configure(path=...)``.
 
 Semantics worth knowing before reading the numbers:
 
@@ -33,7 +37,6 @@ Semantics worth knowing before reading the numbers:
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -41,11 +44,13 @@ import time
 import warnings
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from . import trace
 
 __all__ = ["JsonlSink", "configure", "enabled", "get_sink", "span",
-           "trace_span", "counter", "gauge", "histogram",
-           "histogram_summary", "reset_histograms"]
+           "counter", "gauge", "histogram", "histogram_summary",
+           "reset_histograms"]
 
 
 class JsonlSink:
@@ -114,33 +119,29 @@ def _jsonable(v):
 
 
 _sink: Optional[JsonlSink] = None
-_annotate = False
 #: serializes sink swaps: two concurrent configure() calls would both
 #: read the same ``old`` and one replaced sink would never be closed
 _config_lock = threading.Lock()
 
 
 def configure(sink: Optional[JsonlSink] = None, path: Optional[str] = None,
-              annotate: Optional[bool] = None,
               max_bytes: Optional[int] = None) -> None:
-    """Install/replace the event sink and/or the XProf annotation flag.
+    """Install/replace the event sink.
 
     ``configure()`` with no arguments disables the JSONL sink (closing
-    the old one) and leaves annotation untouched.  ``max_bytes``
-    applies to a sink built from ``path`` (size-based rollover to
-    ``<path>.1``; ``SINGA_OBS_MAX_BYTES`` in the environment).
+    the old one).  ``max_bytes`` applies to a sink built from ``path``
+    (size-based rollover to ``<path>.1``; ``SINGA_OBS_MAX_BYTES`` in
+    the environment).
 
     Safe to call while other threads emit: emitters snapshot the sink
     reference once per event (see ``_emit``), and a swapped-out sink's
     ``emit`` degrades to a no-op once closed."""
     if path is not None:
         sink = JsonlSink(path, max_bytes=max_bytes)
-    global _sink, _annotate
+    global _sink
     with _config_lock:
         old = _sink
         _sink = sink
-        if annotate is not None:
-            _annotate = bool(annotate)
     if old is not None and old is not sink:
         old.close()
 
@@ -168,13 +169,11 @@ def _init_from_env() -> None:
         except (OSError, ValueError):
             # unwritable path / bad limit must never break training
             pass
-    if os.environ.get("SINGA_OBS_XPROF") == "1":
-        configure(sink=_sink, annotate=True)
 
 
 def enabled() -> bool:
-    """Cheap hot-path check: is any telemetry consumer installed?"""
-    return _sink is not None or _annotate
+    """Cheap hot-path check: is the JSONL sink installed?"""
+    return _sink is not None
 
 
 def get_sink() -> Optional[JsonlSink]:
@@ -322,21 +321,6 @@ def reset_histograms(name: Optional[str] = None) -> None:
             _hists.pop(name, None)
 
 
-class _NullCtx:
-    """Shared no-op context manager for the disabled fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullCtx()
-
-
 class _Span:
     __slots__ = ("name", "attrs", "_t0", "_ann", "_sid", "_parent",
                  "_tok")
@@ -345,19 +329,13 @@ class _Span:
         self.name = name
         self.attrs = attrs
         self._t0 = 0.0
-        self._ann = None
+        self._ann = TraceAnnotation(name, **attrs)
         self._sid = None
         self._parent = None
         self._tok = None
 
     def __enter__(self):
-        if _annotate:
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:  # profiler optional; never break the step
-                self._ann = None
+        self._ann.__enter__()
         # inside an active trace, spans nest: this span takes a span id,
         # records the current parent, and becomes the parent for any
         # span opened within its extent (contextvar push, popped on
@@ -373,9 +351,7 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
         trace._pop_span(self._tok)
-        if self._ann is not None:
-            with contextlib.suppress(Exception):
-                self._ann.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
         attrs = self.attrs
         attrs["dur_ms"] = round(dur * 1e3, 3)
         if self._sid is not None:
@@ -389,20 +365,21 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Context manager timing a host-side region.
+    """Context manager marking (and, with a sink, timing) a host-side
+    region.
 
         with events.span("graph.compile", graph="llama.train"):
             compiled = lowered.compile()
 
-    Emits ``{"kind": "span", "name": ..., "dur_ms": ...}`` to the sink
-    and (with SINGA_OBS_XPROF=1) annotates the XProf timeline.  Returns
-    a shared no-op context when telemetry is disabled."""
-    if _sink is None and not _annotate:
-        return _NULL
+    Always a ``jax.profiler.TraceAnnotation`` named ``name`` (``attrs``
+    become its stats): inert outside a profiler session, inside one an
+    event on this thread's line of the trace, on the device ops' clock.
+    With a sink installed it also emits ``{"kind": "span", "name": ...,
+    "dur_ms": ...}`` (and ``span``/``parent`` ids under an ``obs.trace``
+    context); with none it does nothing else — no bookkeeping, no ids."""
+    if _sink is None:
+        return TraceAnnotation(name, **attrs)
     return _Span(name, attrs)
 
-
-#: alias matching the subsystem spec (`trace_span` in ISSUE.md)
-trace_span = span
 
 _init_from_env()

@@ -771,31 +771,34 @@ class ServeEngine:
             now = time.monotonic()
             delivered = 0
 
-            # 0. hang recovery — the Heartbeat monitor thread can only
-            #    REQUEST it; the rebuild must run here, on the step
-            #    thread, which owns the arena
-            if self._recover_flag.is_set():
-                self._recover_flag.clear()
-                self._recover("heartbeat")
+            with events.span("serve.expire"):
+                # 0. hang recovery — the Heartbeat monitor thread can
+                #    only REQUEST it; the rebuild must run here, on the
+                #    step thread, which owns the arena
+                if self._recover_flag.is_set():
+                    self._recover_flag.clear()
+                    self._recover("heartbeat")
 
-            # 1. deadline eviction — queued requests that died waiting
-            #    and running requests past their deadline vacate first,
-            #    so their slots/blocks are admittable this same tick
-            for req in self.sched.expire_queued(now):
-                with obs_trace.activate(req.trace_id):
-                    self.metrics.on_evict("deadline")
-            for slot in [s for s, r in self._running.items()
-                         if r.expired(now)]:
-                req = self._running[slot]
-                req.finish_reason = "deadline"
-                self._finalize(slot, evicted=True)
+                # 1. deadline eviction — queued requests that died
+                #    waiting and running requests past their deadline
+                #    vacate first, so their slots/blocks are admittable
+                #    this same tick
+                for req in self.sched.expire_queued(now):
+                    with obs_trace.activate(req.trace_id):
+                        self.metrics.on_evict("deadline")
+                for slot in [s for s, r in self._running.items()
+                             if r.expired(now)]:
+                    req = self._running[slot]
+                    req.finish_reason = "deadline"
+                    self._finalize(slot, evicted=True)
 
-            # 1b. deadline-aware overload shedding — queued requests
-            #     that cannot plausibly deliver a first token before
-            #     their deadline are shed before burning a prefill
-            for req in self.sched.shed_overload(now, self._eta_first_token):
-                with obs_trace.activate(req.trace_id):
-                    self.metrics.on_evict("shed")
+                # 1b. deadline-aware overload shedding — queued requests
+                #     that cannot plausibly deliver a first token before
+                #     their deadline are shed before burning a prefill
+                for req in self.sched.shed_overload(now,
+                                                    self._eta_first_token):
+                    with obs_trace.activate(req.trace_id):
+                        self.metrics.on_evict("shed")
 
             # 2. admission — prefill into free slots between decode
             #    steps.  A slot row is not enough: the head-of-queue
@@ -803,10 +806,11 @@ class ServeEngine:
             #    blocks (FIFO: a too-big head blocks the line rather
             #    than being overtaken)
             while self.pool.free_count:
-                req = self.sched.peek()
-                if req is None or not self._admittable(req):
-                    break
-                self.sched.pop_for_admission()
+                with events.span("serve.admit.probe"):
+                    req = self.sched.peek()
+                    if req is None or not self._admittable(req):
+                        break
+                    self.sched.pop_for_admission()
                 delivered += self._admit(req)
 
             # 3. block-table growth + one decode tick over the whole
@@ -815,7 +819,8 @@ class ServeEngine:
             #    rebuild + re-prefill instead of crashing the engine
             if self._running and decode:
                 try:
-                    self._ensure_blocks()
+                    with events.span("serve.grow"):
+                        self._ensure_blocks()
                     if self._running:
                         delivered += (self._spec_tick()
                                       if self._verify is not None
@@ -825,19 +830,21 @@ class ServeEngine:
                         raise
                     self._recover(f"decode: {type(e).__name__}: {e}")
 
-            # settle spill payloads onto host numpy AFTER the tick's
-            # token-extraction sync: the D2H copies are already done,
-            # so this collects without waiting, and device-side spill
-            # buffers live at most one tick
-            if self._spill is not None:
-                self._spill.settle()
+            with events.span("serve.step.tail"):
+                # settle spill payloads onto host numpy AFTER the tick's
+                # token-extraction sync: the D2H copies are already
+                # done, so this collects without waiting, and
+                # device-side spill buffers live at most one tick
+                if self._spill is not None:
+                    self._spill.settle()
 
-            self.metrics.on_step(self.sched.depth, self.pool.active_count,
-                                 self.pool.blocks_in_use,
-                                 self.pool.blocks_in_use_bytes)
-            dt = time.monotonic() - now
-            self._tick_ewma = dt if self._tick_ewma is None else \
-                0.8 * self._tick_ewma + 0.2 * dt
+                self.metrics.on_step(self.sched.depth,
+                                     self.pool.active_count,
+                                     self.pool.blocks_in_use,
+                                     self.pool.blocks_in_use_bytes)
+                dt = time.monotonic() - now
+                self._tick_ewma = dt if self._tick_ewma is None else \
+                    0.8 * self._tick_ewma + 0.2 * dt
         return delivered
 
     def _eta_first_token(self, position: int) -> float:
@@ -1055,7 +1062,7 @@ class ServeEngine:
         # the whole admission — block claim, prefix hit, prefill chunks,
         # first-token delivery, quarantine on failure — runs under the
         # request's trace, so each of those events carries its id
-        with obs_trace.activate(req.trace_id):
+        with obs_trace.activate(req.trace_id), events.span("serve.admit"):
             return self._admit_traced(req)
 
     def _admit_traced(self, req: Request) -> int:
@@ -1077,53 +1084,57 @@ class ServeEngine:
         # quarantine must attribute the failure to the seam that died
         fail_site, fail_attempts = "serve.block_alloc", 1
         try:
-            n_shared, shared_ids = self.pool.match_prefix(
-                req.prompt, self._share_limit(req),
-                keys=self._req_keys(req))
-            owned = self._alloc_blocks(
-                self._blocks_needed(req, n_shared), req.rid) or []
-            if len(owned) < self._blocks_needed(req, n_shared):
-                # _admittable() held when we were popped and nothing
-                # ran since — an all-or-nothing alloc can only come up
-                # short through a bug; fail THIS request loudly
-                raise RuntimeError("block allocation came up short")
-            fail_site = "serve.prefill"
-            fail_attempts = self.max_dispatch_retries + 1
-            self.pool.map_slot(slot, shared_ids + owned)
-            mapped = True
+            with events.span("serve.admit.claim"):
+                n_shared, shared_ids = self.pool.match_prefix(
+                    req.prompt, self._share_limit(req),
+                    keys=self._req_keys(req))
+                owned = self._alloc_blocks(
+                    self._blocks_needed(req, n_shared), req.rid) or []
+                if len(owned) < self._blocks_needed(req, n_shared):
+                    # _admittable() held when we were popped and nothing
+                    # ran since — an all-or-nothing alloc can only come
+                    # up short through a bug; fail THIS request loudly
+                    raise RuntimeError("block allocation came up short")
+                fail_site = "serve.prefill"
+                fail_attempts = self.max_dispatch_retries + 1
+                self.pool.map_slot(slot, shared_ids + owned)
+                mapped = True
             start0 = n_shared * bs
             if n_shared:
                 self.metrics.on_prefix_hit(start0)
             with events.span("serve.prefill", slot=slot, prompt=P,
                              shared=start0):
                 for start in range(start0, P, bs):
-                    ids = np.zeros((1, bs), np.int32)
-                    chunk = replay[start:start + bs]
-                    ids[0, :chunk.size] = chunk
-                    if self._verify is not None:
-                        # spec engine: the ONE prefill program writes
-                        # the chunk into BOTH arenas (target + draft)
-                        (self._toks, self.pool.caches,
-                         self.pool.draft_caches) = self._dispatch(
-                            "serve.prefill", self._prefill,
-                            (self._params, self._buffers, self._dparams,
-                             self._dbuffers, jnp.asarray(ids),
-                             jnp.asarray(start, jnp.int32),
-                             jnp.asarray(chunk.size - 1, jnp.int32),
-                             jnp.asarray(slot, jnp.int32),
-                             self.pool.tables, self._toks,
-                             self.pool.caches, self.pool.draft_caches),
-                            rid=req.rid)
-                        continue
-                    self._toks, self.pool.caches = self._dispatch(
-                        "serve.prefill", self._prefill,
-                        (self._params, self._buffers, jnp.asarray(ids),
-                         jnp.asarray(start, jnp.int32),
-                         jnp.asarray(chunk.size - 1, jnp.int32),
-                         jnp.asarray(slot, jnp.int32),
-                         self.pool.tables, self._toks, self.pool.caches),
-                        rid=req.rid)
-                tok = int(np.asarray(self._toks)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token
+                    with events.span("serve.prefill.stage"):
+                        ids = np.zeros((1, bs), np.int32)
+                        chunk = replay[start:start + bs]
+                        ids[0, :chunk.size] = chunk
+                        staged = (jnp.asarray(ids),
+                                  jnp.asarray(start, jnp.int32),
+                                  jnp.asarray(chunk.size - 1, jnp.int32),
+                                  jnp.asarray(slot, jnp.int32))
+                    with events.span("serve.prefill.dispatch"):
+                        if self._verify is not None:
+                            # spec engine: the ONE prefill program
+                            # writes the chunk into BOTH arenas (target
+                            # + draft)
+                            (self._toks, self.pool.caches,
+                             self.pool.draft_caches) = self._dispatch(
+                                "serve.prefill", self._prefill,
+                                (self._params, self._buffers,
+                                 self._dparams, self._dbuffers, *staged,
+                                 self.pool.tables, self._toks,
+                                 self.pool.caches, self.pool.draft_caches),
+                                rid=req.rid)
+                        else:
+                            self._toks, self.pool.caches = self._dispatch(
+                                "serve.prefill", self._prefill,
+                                (self._params, self._buffers, *staged,
+                                 self.pool.tables, self._toks,
+                                 self.pool.caches),
+                                rid=req.rid)
+                with events.span("serve.prefill.fetch"):
+                    tok = int(np.asarray(self._toks)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token
         except (RuntimeError, OSError) as e:
             if isinstance(e, failure.FailureDetected):
                 raise
@@ -1139,27 +1150,28 @@ class ServeEngine:
                 self.pool.release_slot_row(slot)
             self._quarantine(req, e, fail_site, fail_attempts)
             return 0
-        if self.share_prefix:
-            self.pool.register_prefix(req.prompt, slot,
-                                      req.prompt.size // bs,
-                                      keys=self._req_keys(req))
-        self.pool.activate(slot, P)
-        req.slot = slot
-        req.state = RUNNING
-        self._running[slot] = req
-        if first:
-            # preemption/recovery re-prefills count under their own
-            # counters, not here — ``admitted`` stays comparable to
-            # ``submitted``
-            self.metrics.on_admit()
-        done = req.deliver(tok)       # prefill yields the (next) token
-        self.metrics.on_deliver(req.rid, len(req.tokens))
-        if first:
-            self.metrics.on_first_token(req.ttft_s)
-        if req.on_token is not None:
-            req.on_token(tok, req.handle)
-        if done:
-            self._finalize(slot)
+        with events.span("serve.admit.finish"):
+            if self.share_prefix:
+                self.pool.register_prefix(req.prompt, slot,
+                                          req.prompt.size // bs,
+                                          keys=self._req_keys(req))
+            self.pool.activate(slot, P)
+            req.slot = slot
+            req.state = RUNNING
+            self._running[slot] = req
+            if first:
+                # preemption/recovery re-prefills count under their own
+                # counters, not here — ``admitted`` stays comparable to
+                # ``submitted``
+                self.metrics.on_admit()
+            done = req.deliver(tok)   # prefill yields the (next) token
+            self.metrics.on_deliver(req.rid, len(req.tokens))
+            if first:
+                self.metrics.on_first_token(req.ttft_s)
+            if req.on_token is not None:
+                req.on_token(tok, req.handle)
+            if done:
+                self._finalize(slot)
         return 1
 
     def _quarantine(self, req: Request, err: Exception,
@@ -1223,33 +1235,36 @@ class ServeEngine:
     def _decode_tick(self) -> int:
         t0 = time.perf_counter()
         with events.span("serve.decode", active=len(self._running)):
-            self._toks, new_pos, self.pool.caches = self._dispatch(
-                "serve.decode", self._decode,
-                (self._params, self._buffers, self._toks,
-                 self.pool.pos, self.pool.active, self.pool.tables,
-                 self.pool.caches),
-                active=len(self._running))
-            toks = np.asarray(self._toks)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic
+            with events.span("serve.decode.dispatch"):
+                self._toks, new_pos, self.pool.caches = self._dispatch(
+                    "serve.decode", self._decode,
+                    (self._params, self._buffers, self._toks,
+                     self.pool.pos, self.pool.active, self.pool.tables,
+                     self.pool.caches),
+                    active=len(self._running))
+            with events.span("serve.decode.fetch"):
+                toks = np.asarray(self._toks)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic
         self.pool.pos = new_pos
         dt = time.perf_counter() - t0
         delivered = 0
-        for slot in list(self._running):
-            req = self._running[slot]
-            tok = int(toks[slot])
-            # one batched decode dispatch delivers to many requests;
-            # the per-request section runs under each request's trace
-            # so its token events attribute correctly
-            with obs_trace.activate(req.trace_id):
-                done = req.deliver(tok)
-                self.metrics.on_token(dt)
-                self.metrics.on_deliver(req.rid, len(req.tokens))
-                self.metrics.on_slot_dispatch(1)
-            if req.on_token is not None:
-                req.on_token(tok, req.handle)
-            delivered += 1
-            if done:
-                self._finalize(slot)
-        self._note_tpt(delivered, delivered)
+        with events.span("serve.deliver"):
+            for slot in list(self._running):
+                req = self._running[slot]
+                tok = int(toks[slot])
+                # one batched decode dispatch delivers to many requests;
+                # the per-request section runs under each request's
+                # trace so its token events attribute correctly
+                with obs_trace.activate(req.trace_id):
+                    done = req.deliver(tok)
+                    self.metrics.on_token(dt)
+                    self.metrics.on_deliver(req.rid, len(req.tokens))
+                    self.metrics.on_slot_dispatch(1)
+                if req.on_token is not None:
+                    req.on_token(tok, req.handle)
+                delivered += 1
+                if done:
+                    self._finalize(slot)
+            self._note_tpt(delivered, delivered)
         return delivered
 
     def _spec_tick(self) -> int:

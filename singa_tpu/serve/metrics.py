@@ -50,9 +50,40 @@ name                        kind       meaning
 ``serve.prefetch_wait_ms``  histogram  host-side restore orchestration
                                        per prefetched block (the copy
                                        itself rides JAX async dispatch)
-``serve.step``              span       one engine step (host wall clock)
-``serve.prefill``           span       one prefill dispatch (+ fetch)
-``serve.decode``            span       one decode dispatch (+ fetch)
+``serve.step``              span       one engine step (host wall clock);
+                                       the phase spans below tile it
+``serve.expire``            span       recovery flag, deadline eviction,
+                                       overload shedding
+``serve.admit.probe``       span       head-of-queue peek + block
+                                       feasibility probe, once per turn
+                                       of the admission loop
+``serve.admit``             span       one admission, whole (under the
+                                       request's trace id)
+``serve.admit.claim``       span       prefix match, block allocation,
+                                       slot table mapping
+``serve.prefill``           span       all chunks of one admission and
+                                       the token fetch (``slot``,
+                                       ``prompt``, ``shared`` attrs)
+``serve.prefill.stage``     span       per chunk: host staging of the
+                                       ids and scalar arguments
+``serve.prefill.dispatch``  span       per chunk: the guarded dispatch
+                                       of ``prefill_chunk``
+``serve.prefill.fetch``     span       the blocking token fetch after
+                                       the last chunk
+``serve.admit.finish``      span       prefix registration, slot
+                                       activation, first-token delivery
+                                       and callback
+``serve.grow``              span       decode-time block-table growth
+                                       (and any preemption it triggers)
+``serve.decode``            span       one decode tick: dispatch + fetch
+``serve.decode.dispatch``   span       the guarded dispatch of
+                                       ``decode_paged``
+``serve.decode.fetch``      span       the tick's blocking token fetch
+``serve.deliver``           span       the per-slot loop after the fetch:
+                                       delivery, metrics, flight notes,
+                                       ``on_token`` callbacks, finalize
+``serve.step.tail``         span       spill settle, per-step gauges,
+                                       the tick EWMA
 ``serve.verify``            span       one speculative verify round
                                        (draft propose-k + target
                                        verify in ONE dispatch + fetch;
@@ -74,7 +105,14 @@ name                        kind       meaning
 ``serve.token_ms``          histogram  per generated token, decode path
 ==========================  =========  ==================================
 
-Counters/gauges cost one attribute check when no sink is configured.
+Counters/gauges cost one attribute check when no sink is configured;
+a span costs about a microsecond (it is always a
+``jax.profiler.TraceAnnotation``, inert outside a profiler session —
+inside one the spans above land on the stepping thread's line of the
+trace, on the device ops' clock, which is how the benchmark's
+``idle_unattributed.serve`` / ``idle_engine_python.serve`` name the
+phase the chip was waiting on).  Code added to ``step()`` goes inside
+one of the phase spans, or under a new ``serve.*`` one.
 Latency aggregation is PER ENGINE: each ServeMetrics owns its own
 histogram state (``snapshot()`` reads it), so two engines in one
 process never reset or pollute each other's percentiles; the emitted

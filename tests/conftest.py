@@ -3,6 +3,8 @@ sharding/collective path is exercised without TPU hardware (SURVEY.md §4
 item 3).  Pinned in-process, so a bare `pytest` works without
 JAX_PLATFORMS / XLA_FLAGS in the environment."""
 
+import contextlib
+import glob
 import os
 import sys
 
@@ -41,3 +43,35 @@ def _reset_singa_state():
 def cpu_dev():
     import singa_tpu as st
     return st.device.get_default_device()
+
+
+@contextlib.contextmanager
+def _host_profile(out_dir, prefixes):
+    """A `jax.profiler` session with the Python tracer off, as the
+    benchmark's traced window runs it.  Yields a list that holds, once
+    the block has ended, one list of (name, start_ns, end_ns, stats)
+    per host-thread line of the trace that has events whose names
+    start with one of `prefixes` (those events only)."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    lines = []
+    jax.profiler.start_trace(str(out_dir), profiler_options=opts)
+    try:
+        yield lines
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(os.path.join(str(out_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    for plane in ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            kept = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats))
+                    for e in line.events if e.name.startswith(prefixes)]
+            if kept and not plane.name.startswith("/device:"):
+                lines.append(kept)
+
+
+@pytest.fixture(scope="session")
+def host_profile():
+    return _host_profile

@@ -42,7 +42,7 @@ def _no_plan_leak():
     of the suite with a live plan (or a lingering sink)."""
     yield
     faults.uninstall()
-    events.configure(annotate=False)
+    events.configure()
 
 
 # ---------------------------------------------------------------------------

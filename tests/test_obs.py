@@ -38,7 +38,7 @@ def _smoke_entry(run_id="r-smoke"):
 @pytest.fixture(autouse=True)
 def _reset_events():
     yield
-    events.configure(annotate=False)
+    events.configure()
 
 
 class TestRunRecordStore:
@@ -192,10 +192,54 @@ class TestSchema:
 
 
 class TestEvents:
-    def test_disabled_is_a_shared_noop(self):
-        events.configure(sink=None, annotate=False)
+    def test_sinkless_span_is_a_bare_annotation(self, monkeypatch):
+        """No sink, no profiler session: span() is the profiler's own
+        (inert) TraceAnnotation — no _Span, no emit, no id allocation,
+        even inside an active obs.trace context."""
+        from jax.profiler import TraceAnnotation
+        from singa_tpu.obs import trace
+        events.configure()
         assert not events.enabled()
-        assert events.span("a") is events.span("b")
+        calls = []
+        monkeypatch.setattr(events, "_emit",
+                            lambda *a: calls.append(("emit",) + a))
+        monkeypatch.setattr(events._Span, "__init__",
+                            lambda *a: calls.append(("_Span",) + a))
+        monkeypatch.setattr(trace, "new_span_id",
+                            lambda: calls.append(("id",)) or "s0")
+        with trace.activate("tr-quiet"):
+            with events.span("quiet", slot=3) as outer:
+                with events.span("quiet.child"):
+                    pass
+        assert type(outer) is TraceAnnotation
+        assert calls == []
+
+    @pytest.mark.parametrize("sink", [False, True])
+    def test_span_reaches_the_profiler_under_its_own_name(
+            self, tmp_path, host_profile, sink):
+        """With or without the JSONL sink, a span inside a profiler
+        session (Python tracer off) is an event of the host thread's
+        line named by the span's name alone; attributes become stats.
+        There is no switch: nothing is configured for this."""
+        p = str(tmp_path / "ev.jsonl")
+        events.configure(path=p) if sink else events.configure()
+        try:
+            with host_profile(tmp_path / "xprof", ("obs.",)) as lines:
+                with events.span("obs.outer"):
+                    with events.span("obs.inner", slot=3):
+                        pass
+        finally:
+            events.configure()
+        (line,) = lines
+        by_name = {e[0]: e for e in line}
+        assert sorted(by_name) == ["obs.inner", "obs.outer"]
+        inner, outer = by_name["obs.inner"], by_name["obs.outer"]
+        assert outer[1] <= inner[1] and inner[2] <= outer[2]
+        assert inner[3]["slot"] == 3
+        if sink:
+            evs = [json.loads(l) for l in open(p)]
+            assert [e["name"] for e in evs] == ["obs.inner", "obs.outer"]
+            assert evs[0]["slot"] == 3 and "dur_ms" in evs[0]
 
     def test_span_counter_gauge_roundtrip(self, tmp_path):
         p = str(tmp_path / "ev.jsonl")
@@ -381,7 +425,7 @@ class TestHotPathEmission:
         assert grads[0]["axis"] == "data"
 
     def test_disabled_emission_does_not_perturb_training(self):
-        events.configure(sink=None, annotate=False)
+        events.configure()
         m = _TinyMLP()
         m.set_optimizer(st.opt.SGD(lr=0.1))
         x = st.tensor.from_numpy(np.random.randn(8, 8).astype(np.float32))

@@ -52,6 +52,81 @@ def _prompts(n, lens, vocab=256, seed=7):
             for i in range(n)]
 
 
+#: every phase span of an engine step (docs/observability.md); the
+#: benchmark's idle metrics key on the `serve.` prefix of these names
+STEP_SPANS = ("serve.expire", "serve.admit.probe", "serve.admit",
+              "serve.admit.claim", "serve.prefill", "serve.prefill.stage",
+              "serve.prefill.dispatch", "serve.prefill.fetch",
+              "serve.admit.finish", "serve.grow", "serve.decode",
+              "serve.decode.dispatch", "serve.decode.fetch",
+              "serve.deliver", "serve.step.tail")
+
+
+@pytest.fixture(scope="module")
+def step_trace(llama, host_profile, tmp_path_factory):
+    """A tiny engine stepped under a profiler session with the Python
+    tracer off and NO sink, as the benchmark's traced window runs it.
+    Returns the (name, start_ns, end_ns) `serve.*` and `bench.*` events
+    of every host line that holds one, the `_Span`s built meanwhile,
+    and the engine's program counts after."""
+    import jax
+    assert events.get_sink() is None
+    eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+    eng.submit(_prompts(1, [5])[0], max_new_tokens=2)
+    eng.run_until_idle()                      # compile outside the session
+    built, span_cls = [], events._Span
+    events._Span = lambda *a: built.append(a) or span_cls(*a)
+    try:
+        with host_profile(tmp_path_factory.mktemp("xprof"),
+                          ("serve.", "bench.")) as lines:
+            hs = [eng.submit(p, max_new_tokens=4)
+                  for p in _prompts(3, [12, 5], seed=9)]
+            while eng.pending:
+                with jax.profiler.TraceAnnotation("bench.step"):
+                    eng.step()
+    finally:
+        events._Span = span_cls
+    assert all(h.done for h in hs)
+    return ([[e[:3] for e in evs] for evs in lines], built,
+            eng.compiled_counts())
+
+
+class TestStepSpansInTheProfile:
+    def test_one_host_line_no_span_objects_no_new_program(self, step_trace):
+        lines, built, counts = step_trace
+        assert len(lines) == 1      # the thread that called step()
+        assert built == []          # no sink: annotations only
+        assert counts == (1, 1)     # spans add no device work
+
+    @pytest.mark.parametrize("name", STEP_SPANS)
+    def test_span_lies_inside_a_serve_step(self, step_trace, name):
+        (line,), _, _ = step_trace
+        steps = [(s, e) for n, s, e in line if n == "serve.step"]
+        mine = [(s, e) for n, s, e in line if n == name]
+        assert steps and mine, f"{name} is not on the host line"
+        for s, e in mine:
+            assert any(s0 <= s and e <= e0 for s0, e0 in steps), name
+
+    def test_every_step_is_inside_the_callers_annotation(self, step_trace):
+        """`serve.step` nests in the benchmark's `bench.step` on the
+        same line, and its direct children tile it: the names a
+        midpoint can fall on."""
+        (line,), _, _ = step_trace
+        outer = [(s, e) for n, s, e in line if n == "bench.step"]
+        steps = [(s, e) for n, s, e in line if n == "serve.step"]
+        assert len(outer) == len(steps) > 0
+        for s, e in steps:
+            assert any(s0 <= s and e <= e0 for s0, e0 in outer)
+        top = ("serve.expire", "serve.admit.probe", "serve.admit",
+               "serve.grow", "serve.decode", "serve.deliver",
+               "serve.step.tail")
+        s0, e0 = steps[-1]          # a decode-only step: no admission
+        kids = sorted((s, e) for n, s, e in line
+                      if n in top and s0 <= s and e <= e0)
+        assert kids[0][0] >= s0 and kids[-1][1] <= e0
+        assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:]))
+
+
 class TestGreedyEquivalence:
     def test_single_request_matches_generate(self, llama, engine):
         prompt = _prompts(1, [8])[0]
@@ -235,6 +310,39 @@ class TestStreamingAndMetrics:
                          ("hist", "serve.ttft_ms"),
                          ("hist", "serve.token_ms")):
             assert expected in names, f"missing {expected} in {names}"
+
+    def test_sink_lines_of_the_step_spans(self, engine, tmp_path):
+        """With a sink every phase span of a step is a JSONL line with
+        `dur_ms`; the spans of one admission carry the request's trace
+        id and nest under its `serve.admit` by `span`/`parent`."""
+        path = str(tmp_path / "serve_spans.jsonl")
+        events.configure(path=path)
+        try:
+            h = engine.submit(_prompts(1, [12], seed=5)[0],
+                              max_new_tokens=3)
+            engine.run_until_idle()
+        finally:
+            events.configure()
+        spans = [json.loads(l) for l in open(path)]
+        spans = [e for e in spans if e["kind"] == "span"]
+        assert {e["name"] for e in spans} >= set(STEP_SPANS)
+        assert all(e["dur_ms"] >= 0 for e in spans)
+        (admit,) = [e for e in spans if e["name"] == "serve.admit"]
+        assert admit["trace"] == h.trace_id and "parent" not in admit
+        by_id = {e["span"]: e for e in spans if "span" in e}
+        for e in spans:
+            if e["name"].startswith(("serve.admit.", "serve.prefill")) \
+                    and e["name"] != "serve.admit.probe":
+                assert e["trace"] == h.trace_id
+                top = e
+                while "parent" in top:
+                    top = by_id[top["parent"]]
+                assert top is admit, e
+        # a 12-token prompt at block_size 8: two chunks, one fetch
+        names = [e["name"] for e in spans]
+        assert names.count("serve.prefill.stage") == 2
+        assert names.count("serve.prefill.dispatch") == 2
+        assert names.count("serve.prefill.fetch") == 1
 
     def test_snapshot_counts(self, engine):
         from singa_tpu.serve.metrics import ServeMetrics

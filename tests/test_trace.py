@@ -21,7 +21,7 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 @pytest.fixture(autouse=True)
 def _reset_events():
     yield
-    events.configure(annotate=False)
+    events.configure()
 
 
 def _read(path):

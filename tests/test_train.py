@@ -46,7 +46,7 @@ N, DIM, CLASSES, BS = 32, 8, 4, 8
 @pytest.fixture(autouse=True)
 def _reset_events():
     yield
-    events.configure(annotate=False)
+    events.configure()
 
 
 def _arrays(seed=7, n=N, dim=DIM):
