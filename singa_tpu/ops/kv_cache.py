@@ -139,12 +139,30 @@ def gather_block_kv(ck, cv, table):
     the attention ``limit`` mask makes unreachable).  A :class:`QuantKV`
     arena gathers codes AND scales through the same take and
     dequantizes in-program — the dense view is f32 either way the
-    attention math sees it."""
+    attention math sees it.
+
+    Every table entry must be a valid block id in ``[0, num_blocks)``.
+    ``serve.slots.BlockPool`` guarantees it: its tables start at zeros
+    (the null block) and rows are only ever set from ids the pool
+    handed out.  So the take asks for no out-of-bounds fill
+    (``mode="clip"``): ``jnp.take``'s default ``mode="fill"`` lowers
+    to the gather PLUS a select that reads and rewrites the whole
+    dense view to put NaN where an index was out of range — a second
+    pass over a ``max_len``-sized view in every serve program, for
+    nothing.  A NaN would not guard anything either: the attention mask
+    zeroes the probability, and ``0 x NaN`` in ``P @ V`` is NaN.  For
+    in-range ids fill and clip return the same bytes.  Reading a
+    trace: before the reshape the view is ``(B * max_blocks,
+    block_size, K, D)``, which is the arena's own shape when
+    ``num_blocks == B * max_blocks`` and one block short of it at
+    ``BlockPool``'s default ``num_blocks`` — an op of that shape is
+    not necessarily a write to the arena."""
     B, M = table.shape
     bs = ck.shape[1]
 
     def dense(c):
-        g = jnp.take(c, table.reshape(-1), axis=0)        # (B*M, bs, K, D)
+        g = jnp.take(c, table.reshape(-1), axis=0,
+                     mode="clip")                         # (B*M, bs, K, D)
         return g.reshape((B, M * bs) + c.shape[2:])
 
     if isinstance(ck, QuantKV):

@@ -487,6 +487,92 @@ class TestPagedArena:
         assert_program_count(eng, (1, 1))
 
 
+#: (num_blocks, block_size, K, D) of the helper test's tiny arena
+_ARENA = (5, 8, 2, 4)
+
+
+def _arena(kind):
+    """One (ck, cv) paged arena of random content: a plain bf16 or f32
+    pool, or a QuantKV one (int8 codes + per-position f32 scales)."""
+    import jax.numpy as jnp
+    from singa_tpu.ops import kv_cache as kv_ops
+    rng = np.random.RandomState(0)
+
+    def pool():
+        if kind == "int8":
+            return kv_ops.QuantKV(
+                jnp.asarray(rng.randint(-127, 128, _ARENA), jnp.int8),
+                jnp.asarray(rng.rand(*_ARENA[:2], 1, 1) + 0.5, jnp.float32))
+        return jnp.asarray(rng.randn(*_ARENA), kind)
+    return pool(), pool()
+
+
+class TestPagedGatherHasNoFill:
+    """`gather_block_kv` lowers to the gather alone (ISSUE 27): no
+    out-of-bounds fill — `jnp.take`'s default mode selects NaN over the
+    whole dense view, a second pass over a max_len-sized buffer in
+    every serve program — and the tables it is handed only ever hold
+    valid block ids, so nothing needs one."""
+
+    @pytest.mark.parametrize("kind", ["bfloat16", "float32", "int8"])
+    def test_helper_is_a_bare_gather_equal_to_indexing(self, kind):
+        import jax
+        from singa_tpu.ops import kv_cache as kv_ops
+        ck, cv = _arena(kind)
+        # repeated ids, the null block 0 and the highest id
+        t = np.asarray([[4, 0, 2], [2, 2, 4]], np.int32)
+        assert t.max() == _ARENA[0] - 1
+        jaxpr = str(jax.make_jaxpr(kv_ops.gather_block_kv)(ck, cv, t))
+        assert "gather" in jaxpr
+        assert "select_n" not in jaxpr and "FILL_OR_DROP" not in jaxpr
+
+        def plain(c):
+            if kind == "int8":
+                c = (np.asarray(c.q)[t].astype(np.float32)
+                     * np.asarray(c.scale)[t])
+            else:
+                c = np.asarray(c)[t]
+            return c.reshape((t.shape[0], -1) + c.shape[3:])
+        for got, c in zip(kv_ops.gather_block_kv(ck, cv, t), (ck, cv)):
+            want = plain(c)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+    @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+    def test_program_holds_no_select_over_the_view(self, engine, program):
+        """The lowered serve programs: no `select` whose result has the
+        gathered view's shape (decode gathers every slot's row, a
+        prefill chunk one slot's)."""
+        rows = engine.pool.num_slots if program == "decode" else 1
+        ck = engine.pool.caches[0][0]
+        view = "x".join(map(str, (rows * engine.pool.max_blocks,)
+                            + tuple(ck.shape[1:])))
+        text = engine.lower_programs(names=(program,))[program].as_text()
+        assert f"tensor<{view}x" in text     # the view itself is there
+        bad = [ln.strip() for ln in text.splitlines()
+               if "stablehlo.select" in ln and f"tensor<{view}x" in ln]
+        assert bad == []
+
+    def test_tables_only_ever_hold_valid_block_ids(self, llama):
+        """The invariant `gather_block_kv` rests on, after every step
+        of a run with admissions, block growth, a preemption and
+        evictions: each table entry lies in [0, num_blocks)."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8,
+                          num_blocks=6)      # 5 usable blocks
+        hs = [eng.submit(p, max_new_tokens=16)
+              for p in _prompts(3, [7, 12], seed=37)]
+        seen = set()
+        while eng.pending:
+            eng.step()
+            t = np.asarray(eng.pool.tables)
+            assert ((0 <= t) & (t < eng.pool.num_blocks)).all()
+            seen.update(t.ravel().tolist())
+        assert all(h.finish_reason == "length" for h in hs)
+        assert eng.metrics.preempted >= 1
+        # the run reached the null block and the highest id
+        assert {0, eng.pool.num_blocks - 1} <= seen
+
+
 def test_loadgen_quick_run_emits_valid_record(llama, engine, tmp_path):
     """tools/loadgen.py end-to-end against the shared engine: an
     open-loop burst completes, every request is accounted for
