@@ -676,19 +676,22 @@ class MultiHeadAttention(Layer):
 
 
 class _MoEOp(autograd.Operator):
-    def __init__(self, cf, top_k=1, swiglu=False, dispatch_mode="auto"):
+    def __init__(self, cf, top_k=1, swiglu=False, dispatch_mode="auto",
+                 dropless=False):
         super().__init__()
         self.cf = cf
         self.top_k = top_k
         self.swiglu = swiglu
         self.dispatch_mode = dispatch_mode
+        self.dropless = dropless
 
     def fwd(self, xa, rw, wi, wo, *wg):
         from .ops.moe import moe_forward
         out, aux = moe_forward(xa, rw, wi, wo, self.cf, return_aux=True,
                                top_k=self.top_k,
                                w_gate=wg[0] if self.swiglu else None,
-                               dispatch_mode=self.dispatch_mode)
+                               dispatch_mode=self.dispatch_mode,
+                               dropless=self.dropless)
         return out, aux
 
 
@@ -715,7 +718,8 @@ class MoE(Layer):
 
     def __init__(self, num_experts: int, ffn_dim: int,
                  capacity_factor: float = 1.25, top_k: int = 1,
-                 act: str = "relu", dispatch_mode: str = "auto", name=None):
+                 act: str = "relu", dispatch_mode: str = "auto",
+                 dropless: bool = False, name=None):
         super().__init__(name)
         if not 1 <= top_k <= num_experts:
             raise ValueError(
@@ -734,6 +738,9 @@ class MoE(Layer):
         # resolves the global mesh at trace time — pass scatter/einsum
         # to pin the form independent of when the mesh is installed
         self.dispatch_mode = dispatch_mode
+        # exact top-k with every assignment computed (ops/moe.py): the
+        # serving form; capacity_factor and dispatch_mode then do nothing
+        self.dropless = dropless
         self._aux_losses: List[Tensor] = []
 
     def initialize(self, x: Tensor):
@@ -757,7 +764,8 @@ class MoE(Layer):
         # router stays f32 master: moe_forward computes routing in f32
         extra = (self.w_gate,) if self.act == "swiglu" else ()
         out, aux = _MoEOp(self.capacity_factor, self.top_k,
-                          self.act == "swiglu", self.dispatch_mode)(
+                          self.act == "swiglu", self.dispatch_mode,
+                          self.dropless)(
             x, self.router, self.w_in, self.w_out, *extra)
         # accumulate only in training: eval/compile-time dry runs must
         # not leave stale entries (an init-trace tracer here would crash

@@ -94,7 +94,7 @@ def decode_step(model):
     return decode
 
 
-def resume_step(model):
+def resume_step(model, device=None):
     """Build the chunked-prefill closure of the paged serving engine
     (serve.engine): (params, buffers, ids (B, C), pos, caches) ->
     (logits (B, C, V), caches).  Unlike :func:`prefill_step` it takes
@@ -103,11 +103,18 @@ def resume_step(model):
     its k/v at [pos, pos+C) and attends the cache below ``pos + C``
     (``cached_sdpa``'s bottom-right-aligned causal window), which is
     what lets a shared-prefix request skip the chunks that are already
-    resident in the arena."""
+    resident in the arena.
+
+    ``device``: the Device the token ids enter on (default: the
+    model's).  Its ``default_dtype`` is the dtype the embedding casts
+    the activations to, and every layer follows them: a device made
+    with ``default_dtype=float32`` runs f32 weights in f32 on a TPU,
+    where the model's own device would compute in bf16."""
 
     def resume(params, buffers, ids, pos, caches):
         with _bound(model, params, buffers):
-            t = Tensor(data=ids, device=_dev(model), requires_grad=False)
+            t = Tensor(data=ids, device=device or _dev(model),
+                       requires_grad=False)
             logits, caches = model.forward_cached(t, caches=caches,
                                                   pos=pos)
         return logits.data, caches

@@ -21,6 +21,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
+
 from .. import autograd, layer, model
 from ..ops import kv_cache as kv_ops
 from ..ops import rope as rope_ops
@@ -62,6 +64,21 @@ class LlamaConfig:
     # the chunked banded path (ops.attention.banded_attention — O(T*W)
     # memory); incompatible with the 'seq' (ring attention) axis.
     sliding_window: int = 0
+    # width of one attention head; 0 = dim // num_heads.  Models whose
+    # q/k/v projections are wider or narrower than the residual stream
+    # state it (o_proj then maps num_heads * head_size back to dim).
+    head_size: int = 0
+    # per-block attention type, one of "sliding_attention" (window
+    # `sliding_window`, the plain RoPE table) or "full_attention"
+    # (causal, no window; the YaRN table when `yarn_factor` is set) for
+    # each of the num_layers blocks.  () = every block alike: windowed
+    # by `sliding_window` if that is set, one RoPE table (Mistral).
+    layer_types: tuple = ()
+    # YaRN for the full-attention blocks of `layer_types`
+    # (ops.rope.yarn_frequencies: beta_fast 32, beta_slow 1 and the
+    # attention factor 0.1 ln(factor) + 1 are fixed there): 0 = off
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 8192
     eps: float = 1e-5
     # opt-in chunked fused lm-head+CE loss (never materializes the
     # (B*T, V) logits; autograd.FusedLinearCrossEntropy).  NOTE: with it
@@ -100,6 +117,10 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # exact top-k with every assignment computed, in every path of the
+    # model (layer.MoE(dropless=True)): what a served model needs, where
+    # a capacity drop silently changes a token.  Off: capacity routing.
+    moe_dropless: bool = False
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -141,21 +162,43 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.num_heads
+        return self.head_size or self.dim // self.num_heads
+
+    def layer_type(self, i: int) -> Optional[str]:
+        """Block i's entry of `layer_types`, None when there are none."""
+        if not self.layer_types:
+            return None
+        if len(self.layer_types) != self.num_layers:
+            raise ValueError(
+                f"layer_types has {len(self.layer_types)} entries for "
+                f"{self.num_layers} layers")
+        kind = self.layer_types[i]
+        if kind not in ("sliding_attention", "full_attention"):
+            raise ValueError(f"unknown layer type {kind!r}")
+        return kind
 
 
 class _LlamaAttention(layer.Layer):
-    def __init__(self, cfg: LlamaConfig, name=None):
+    def __init__(self, cfg: LlamaConfig, kind: Optional[str] = None,
+                 name=None):
         super().__init__(name)
         c = cfg
         self.cfg = c
+        # this block's window (0 = full causal) and RoPE table
+        self.window = 0 if kind == "full_attention" else c.sliding_window
+        self._scope = "attn.sliding" if self.window else "attn.full"
         self.q_proj = layer.Linear(c.num_heads * c.head_dim, bias=False)
         self.k_proj = layer.Linear(c.num_kv_heads * c.head_dim, bias=False)
         self.v_proj = layer.Linear(c.num_kv_heads * c.head_dim, bias=False)
         self.o_proj = layer.Linear(c.dim, bias=False)
-        self._rope = rope_ops.rope_frequencies(
-            c.head_dim, c.max_position, c.rope_theta, c.rope_scaling,
-            c.rope_scaling_original_max_position)
+        if kind == "full_attention" and c.yarn_factor:
+            self._rope = rope_ops.yarn_frequencies(
+                c.head_dim, c.max_position, c.rope_theta, c.yarn_factor,
+                c.yarn_original_max_position)
+        else:
+            self._rope = rope_ops.rope_frequencies(
+                c.head_dim, c.max_position, c.rope_theta, c.rope_scaling,
+                c.rope_scaling_original_max_position)
 
     def _banded(self, q, k, v, device):
         """Sliding-window attention: causal AND within the last
@@ -171,9 +214,13 @@ class _LlamaAttention(layer.Layer):
                 "sliding_window attention does not compose with the "
                 "'seq' (ring attention) mesh axis — drop the seq axis "
                 "or use full causal attention")
-        return banded_attention(q, k, v, self.cfg.sliding_window)
+        return banded_attention(q, k, v, self.window)
 
     def forward(self, x: Tensor, cache=None, pos=0):
+        with jax.named_scope(self._scope):
+            return self._forward(x, cache, pos)
+
+    def _forward(self, x: Tensor, cache, pos):
         c = self.cfg
         B, T, _ = x.shape
         cos, sin = self._rope
@@ -182,7 +229,7 @@ class _LlamaAttention(layer.Layer):
         v = self.v_proj(x).reshape((B, T, c.num_kv_heads, c.head_dim))
         q = rope_ops.apply_rope(q, cos, sin, offset=pos)
         k = rope_ops.apply_rope(k, cos, sin, offset=pos)
-        windowed = bool(c.sliding_window) and c.sliding_window < T
+        windowed = bool(self.window) and self.window < T
         if cache is not None:
             ck, cv = kv_ops.update_cache(cache[0], cache[1],
                                          k.data, v.data, pos)
@@ -194,7 +241,7 @@ class _LlamaAttention(layer.Layer):
             else:
                 o_arr = kv_ops.cached_sdpa(
                     q.data, ck, cv, limit=pos + T,
-                    window=c.sliding_window or None)
+                    window=self.window or None)
                 o = Tensor(data=o_arr, device=x.device, requires_grad=False)
             out = self.o_proj(o.reshape((B, T, c.num_heads * c.head_dim)))
             return out, (ck, cv)
@@ -219,15 +266,17 @@ class _SwiGLU(layer.Layer):
 
 
 class _LlamaBlock(layer.Layer):
-    def __init__(self, cfg: LlamaConfig, name=None):
+    def __init__(self, cfg: LlamaConfig, kind: Optional[str] = None,
+                 name=None):
         super().__init__(name)
         self.attn_norm = layer.RMSNorm(cfg.dim, eps=cfg.eps)
-        self.attn = _LlamaAttention(cfg)
+        self.attn = _LlamaAttention(cfg, kind)
         self.ffn_norm = layer.RMSNorm(cfg.dim, eps=cfg.eps)
         if cfg.num_experts:
             self.ffn = layer.MoE(cfg.num_experts, ffn_dim=cfg.ffn_dim,
                                  capacity_factor=cfg.moe_capacity_factor,
-                                 top_k=cfg.moe_top_k, act="swiglu")
+                                 top_k=cfg.moe_top_k, act="swiglu",
+                                 dropless=cfg.moe_dropless)
         else:
             self.ffn = _SwiGLU(cfg)
 
@@ -250,7 +299,12 @@ class Llama(GenerateMixin, model.Model):
         self.cfg = cfg or LlamaConfig(**kw)
         c = self.cfg
         self.tok_emb = layer.Embedding(c.vocab_size, c.dim)
-        blocks = [_LlamaBlock(c) for _ in range(c.num_layers)]
+        blocks = [_LlamaBlock(c, c.layer_type(i))
+                  for i in range(c.num_layers)]
+        if c.pipeline_stages and len(set(c.layer_types)) > 1:
+            raise NotImplementedError(
+                "pipeline_stages runs one block's program over stacked "
+                "weights: blocks of different layer_types cannot share it")
         if c.pipeline_stages:
             # embed and lm head stay outside the pipeline (replicated /
             # 'model'-sharded as usual); only the shape-preserving block
@@ -357,9 +411,10 @@ class Llama(GenerateMixin, model.Model):
             n = max(n - c.num_layers * (c.num_experts - c.moe_top_k)
                     * expert_p, 0)
         # sliding-window attention computes only min(T, W) keys/query
-        attn_span = min(seq_len, c.sliding_window) if c.sliding_window \
-            else seq_len
-        f = 6 * n + 12 * c.num_layers * c.dim * attn_span
+        spans = sum(min(seq_len, c.sliding_window) if c.sliding_window
+                    and c.layer_type(i) != "full_attention" else seq_len
+                    for i in range(c.num_layers))
+        f = 6 * n + 12 * c.num_heads * c.head_dim * spans
         if c.fused_loss:
             f += 2 * c.dim * c.vocab_size
         return f

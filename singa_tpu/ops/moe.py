@@ -67,6 +67,16 @@ def load_balance_loss(probs, onehot):
     return E * jnp.sum(frac * prob)
 
 
+def _topk_gates(logits, k: int):
+    """(probs (N, E) f32, topi (N, k), gates (N, k)): softmax in f32, the
+    k largest, their weights renormalised to sum 1 when k > 1."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    topv, topi = jax.lax.top_k(probs, k)
+    gates = topv if k == 1 else \
+        topv / jnp.sum(topv, axis=-1, keepdims=True)
+    return probs, topi, gates
+
+
 def _route(logits, capacity: int, k: int):
     """Shared top-k routing state, rank-major (GShard priority: every
     token's first choice outranks any second choice for a slot).
@@ -75,10 +85,7 @@ def _route(logits, capacity: int, k: int):
     pos (k*N,) slot index within the expert, keep (k*N,) bool,
     probs (N, E), onehot (N, E) of the first choice)."""
     N, E = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)               # (N, k)
-    gates = topv if k == 1 else \
-        topv / jnp.sum(topv, axis=-1, keepdims=True)
+    probs, topi, gates = _topk_gates(logits, k)        # (N, k)
     e_flat = topi.T.reshape(-1)                        # rank-major (k*N,)
     oh = jax.nn.one_hot(e_flat, E, dtype=jnp.float32)
     pos = jnp.sum(jnp.cumsum(oh, axis=0) * oh - oh, axis=-1)  # (k*N,)
@@ -121,9 +128,46 @@ def _expert_ffn(buf, w_in, w_out, w_gate):
     return jnp.einsum("ech,ehd->ecd", h, w_out.astype(buf.dtype))
 
 
+def _moe_dropless(xf, router_w, w_in, w_out, w_gate, top_k):
+    """(N, D) rows -> ((N, D) f32, balance loss): exact top-k, every
+    assignment computed: every expert over every row, the unrouted ones
+    weighted 0.  No buffer, no capacity, row n's result a function of
+    row n alone.
+
+    Right where a dispatch holds a few rows an expert (serving: 32
+    rows, top 8 of 64), because the matmuls are then weight streaming
+    whatever the rows: on one v5e chip 1.07 ms a layer of 64 x 3 x
+    2304 x 896 bf16, 90% of the HBM peak, against 1.10 ms for the
+    scatter path at capacity = N and 4.69 ms for a sort and
+    `jax.lax.ragged_dot` (PERF.md, PR 28).  At training's row counts it
+    multiplies E / k times too many rows: that path keeps capacity."""
+    N, E = xf.shape[0], router_w.shape[-1]
+    with jax.named_scope("moe.route"):
+        logits = jnp.matmul(xf.astype(jnp.float32),
+                            router_w.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs, topi, gates = _topk_gates(logits, top_k)
+        w = jnp.zeros((N, E), jnp.float32).at[
+            jnp.arange(N)[:, None], topi].set(gates)   # (N, E), k nonzero
+    f32 = dict(preferred_element_type=jnp.float32)
+    with jax.named_scope("moe.experts"):
+        h = jnp.einsum("nd,edh->enh", xf, w_in.astype(xf.dtype), **f32)
+        if w_gate is not None:
+            h = jax.nn.silu(jnp.einsum("nd,edh->enh", xf,
+                                       w_gate.astype(xf.dtype), **f32)) * h
+        else:
+            h = jax.nn.relu(h)
+        # the gate weight rides the hidden row, so the down projection
+        # and the sum over experts are one contraction over (e, h)
+        h = (h * w.T[:, :, None]).astype(xf.dtype)
+        out = jnp.einsum("enh,ehd->nd", h, w_out.astype(xf.dtype), **f32)
+    onehot = jax.nn.one_hot(topi[:, 0], E, dtype=jnp.float32)
+    return out, load_balance_loss(probs, onehot)
+
+
 def moe_forward(x, router_w, w_in, w_out, capacity_factor: float = 1.25,
                 return_aux: bool = False, top_k: int = 1, w_gate=None,
-                dispatch_mode: str = "auto"):
+                dispatch_mode: str = "auto", dropless: bool = False):
     """Top-k MoE FFN over flattened tokens (k=1 Switch, k=2 GShard).
 
     x: (..., D); router_w: (D, E); w_in: (E, D, H); w_out: (E, H, D).
@@ -155,7 +199,12 @@ def moe_forward(x, router_w, w_in, w_out, capacity_factor: float = 1.25,
         fires when 'auto' resolves under a trace.
 
     Both modes share `_route` (identical routing, gating, capacity
-    drops) and are equivalence-tested against each other."""
+    drops) and are equivalence-tested against each other.
+
+    dropless: exact top-k, every assignment computed, a row's result
+    independent of what else the batch holds: what serving needs (a
+    capacity drop there silently changes a served token).  It takes the
+    place of capacity and `dispatch_mode`."""
     orig_shape = x.shape
     D = orig_shape[-1]
     xf = x.reshape(-1, D)
@@ -164,6 +213,10 @@ def moe_forward(x, router_w, w_in, w_out, capacity_factor: float = 1.25,
     # capacity covers the k-fold assignment load at the same factor
     capacity = max(1, math.ceil(capacity_factor * top_k * N / E))
 
+    if dropless:
+        out, aux = _moe_dropless(xf, router_w, w_in, w_out, w_gate, top_k)
+        out = out.astype(xf.dtype).reshape(orig_shape)
+        return (out, aux) if return_aux else out
     logits = xf.astype(jnp.float32) @ router_w.astype(jnp.float32)
     if dispatch_mode == "auto":
         from ..parallel import mesh as mesh_mod

@@ -4,13 +4,15 @@
 from __future__ import annotations
 
 import functools
+import math
 
 import jax.numpy as jnp
 
 from .. import autograd
 from ..tensor import Tensor
 
-__all__ = ["rope_frequencies", "apply_rope", "llama31_rope_scaling"]
+__all__ = ["rope_frequencies", "yarn_frequencies", "apply_rope",
+           "llama31_rope_scaling"]
 
 
 def llama31_rope_scaling(inv_freq, scale_factor: float = 8.0,
@@ -56,6 +58,37 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
     t = jnp.arange(max_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+@functools.lru_cache(maxsize=32)
+def yarn_frequencies(head_dim: int, max_len: int, theta: float, factor: float,
+                     original_max_position: int):
+    """(cos, sin) tables of shape (max_len, head_dim//2) under YaRN
+    (Peng et al. 2023, arXiv:2309.00071), as transformers'
+    `_compute_yarn_parameters` has it at its defaults: pair j keeps its
+    frequency when it turns more than 32 times (beta_fast) inside the
+    pretrained context `original_max_position`, is divided by `factor`
+    when it turns less than once (beta_slow), and blends linearly over
+    the pairs between.  Both tables are multiplied by the paper's
+    attention factor 0.1 ln(factor) + 1, which scales q.k by its
+    square."""
+    half = head_dim // 2
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                           / head_dim))
+
+    def pair_turning(rotations):
+        return head_dim * math.log(
+            original_max_position / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(pair_turning(32.0)), 0)
+    hi = min(math.ceil(pair_turning(1.0)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    inv = inv / factor * ramp + inv * (1.0 - ramp)
+    scale = 0.1 * math.log(factor) + 1.0
+    freqs = jnp.outer(jnp.arange(max_len, dtype=jnp.float32), inv)
+    return jnp.cos(freqs) * scale, jnp.sin(freqs) * scale
 
 
 def _rope_fn(x, cos, sin, offset=0):

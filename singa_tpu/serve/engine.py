@@ -259,6 +259,11 @@ class ServeEngine:
             raise ValueError(
                 f"max_len ({max_len}) exceeds the model's max_position "
                 f"({max_pos})")
+        # experts a token is routed to (0: a dense model), for the
+        # host-side count of what each dispatch routes
+        cfg = getattr(model, "cfg", None)
+        self._moe_top_k = int(getattr(cfg, "moe_top_k", 0)) \
+            if getattr(cfg, "num_experts", 0) else 0
         self.share_prefix = bool(share_prefix)
         self.sched = Scheduler(
             max_queue=2 * num_slots if max_queue is None else max_queue)
@@ -512,6 +517,14 @@ class ServeEngine:
         self._handoff = jax.jit(handoff_gather)
 
     # -- introspection ----------------------------------------------------
+    def weights(self):
+        """``(params, buffers)`` as every compiled program reads them:
+        the model's, snapshotted at construction, after the one-time
+        ``param_dtype`` cast.  For a check that must score what the
+        engine serves with and not the model's masters (a teacher-forced
+        pass through ``models._generate.resume_step`` under them)."""
+        return self._params, self._buffers
+
     def compiled_counts(self):
         """(prefill, decode) jit-cache entry counts — the no-recompile
         invariant says both stay at 1 after warmup (tested via
@@ -1133,6 +1146,9 @@ class ServeEngine:
                                  self.pool.tables, self._toks,
                                  self.pool.caches),
                                 rid=req.rid)
+                        if self._moe_top_k:
+                            self.metrics.on_moe_dispatch(
+                                chunk.size * self._moe_top_k)
                 with events.span("serve.prefill.fetch"):
                     tok = int(np.asarray(self._toks)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token
         except (RuntimeError, OSError) as e:
@@ -1242,6 +1258,9 @@ class ServeEngine:
                      self.pool.pos, self.pool.active, self.pool.tables,
                      self.pool.caches),
                     active=len(self._running))
+                if self._moe_top_k:
+                    self.metrics.on_moe_dispatch(
+                        len(self._running) * self._moe_top_k)
             with events.span("serve.decode.fetch"):
                 toks = np.asarray(self._toks)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic
         self.pool.pos = new_pos

@@ -96,6 +96,15 @@ name                        kind       meaning
                                        plain decode (``serve.verify``
                                        fault past retries)
 ``serve.accept_rate``       histogram  per-(slot, round) accepted / k
+``serve.moe_assignments``   counter    (token, expert) pairs one prefill
+                                       chunk or decode tick of a
+                                       mixture-of-experts model routes:
+                                       its valid tokens x top-k, known
+                                       on the host (no device read);
+                                       never emitted for a dense model.
+                                       ``snapshot()`` keeps the total
+                                       and ``moe_dispatches``, the
+                                       number of such dispatches
 ``serve.token``             counter    one token delivered to a request
                                        (prefill first token, decode
                                        tick, recovery/preemption replay
@@ -175,6 +184,10 @@ class ServeMetrics:
         self.spec_fallbacks = 0
         self.slot_dispatches = 0
         self.slot_dispatch_tokens = 0
+        # mixture-of-experts models: dispatches that ran the router and
+        # the (token, expert) pairs they routed; both 0 for a dense model
+        self.moe_dispatches = 0
+        self.moe_assignments = 0
         self._accept = _Hist()
         self._ttft = _Hist()
         self._token = _Hist()
@@ -286,6 +299,13 @@ class ServeMetrics:
         self.slot_dispatches += 1
         self.slot_dispatch_tokens += tokens
 
+    def on_moe_dispatch(self, assignments: int) -> None:
+        """One prefill chunk or decode tick of a mixture-of-experts
+        model: ``assignments`` = its valid tokens x top-k."""
+        self.moe_dispatches += 1
+        self.moe_assignments += assignments
+        events.counter("serve.moe_assignments", assignments)
+
     @property
     def accept_rate(self) -> Optional[float]:
         """Overall accepted / proposed (None before any verify round)."""
@@ -355,6 +375,8 @@ class ServeMetrics:
             "spec_fallbacks": self.spec_fallbacks,
             "slot_dispatches": self.slot_dispatches,
             "slot_dispatch_tokens": self.slot_dispatch_tokens,
+            "moe_dispatches": self.moe_dispatches,
+            "moe_assignments": self.moe_assignments,
             "accept_rate": self.accept_rate,
             "tokens_per_dispatch": self.tokens_per_dispatch,
             "accept_rate_hist": self._accept.summary(),
