@@ -6,15 +6,19 @@ training stack's single-compiled-module discipline: for a given
 (model, num_slots, max_len, block_size) it compiles exactly TWO XLA
 programs —
 
-* **prefill-chunk** — ``block_size`` tokens of one request's prompt at
-  a traced block-aligned offset: the slot's block-table row is
-  gathered into a dense cache view, the chunk's k/v are written at
-  [pos, pos+block_size) and exactly ONE physical block is scattered
-  back (``ops.kv_cache.scatter_block_kv``).  A prompt prefills as
-  ``ceil(len / block_size)`` dispatches of this one program — and a
-  request whose leading prompt blocks are already resident (prefix
-  cache) SKIPS those dispatches entirely: prefill cost scales with the
-  unshared suffix, which is the TTFT win paging buys.
+* **prefill-chunk** — ``C`` tokens of one request's prompt at a traced
+  block-aligned offset, ``C`` being several whole blocks (256 rows'
+  worth, see ``_PREFILL_ROWS``: a chunk streams every weight once, so
+  the more rows share that pass the cheaper a prompt token): the
+  slot's block-table row is gathered into a dense cache view, the
+  chunk's k/v are written at [pos, pos+C) and the ``C / block_size``
+  physical blocks it covers are scattered back
+  (``ops.kv_cache.scatter_block_kv``).  A prompt prefills as
+  ``ceil(len / C)`` dispatches of this one program, its tail padded
+  inside the fixed ``(1, C)`` shape — and a request whose leading
+  prompt blocks are already resident (prefix cache) SKIPS them:
+  prefill cost scales with the unshared suffix, which is the TTFT win
+  paging buys.
 * **decode-over-block-tables** — ONE token for every slot per
   dispatch: the (num_slots, max_blocks) block tables gather every
   slot's dense view, per-slot positions drive RoPE offsets and
@@ -148,6 +152,23 @@ __all__ = ["ServeEngine", "QueueFull", "EngineClosed", "SharedPrograms"]
 #: distinguishes engines built in the same second+pid (run_id suffix)
 _ENGINE_SEQ = itertools.count()
 
+#: rows one prefill dispatch feeds the MXU.  A chunk streams every
+#: weight once whatever its length, so a prompt token gets cheaper with
+#: every row that shares the pass, until the matmuls leave the
+#: bandwidth roof: in bf16 on a TPU v5e at 197e12 / 819e9 = 240 rows
+#: (dropless experts multiply every expert by every row, so they share
+#: that ridge).  256 is the power of two at the ridge; past it a chunk
+#: costs its rows in full, pad rows included, and stalls the running
+#: streams that much longer.  Timed on the chip at 64 / 128 / 256 / 512
+#: in both serve cells of the benchmark (PERF.md, PR 29).
+_PREFILL_ROWS = 256
+
+
+def _chunk_tokens(block_size: int, max_blocks: int) -> int:
+    """Tokens a prefill dispatch covers: ``_PREFILL_ROWS`` rounded down
+    to whole blocks, at least one block, at most the slot's view."""
+    return block_size * max(1, min(_PREFILL_ROWS // block_size, max_blocks))
+
 
 class EngineClosed(RuntimeError):
     """submit()/step() refused: the engine is draining or closed."""
@@ -160,11 +181,11 @@ class SharedPrograms(NamedTuple):
     executables: every same-config worker dispatches through the same
     jitted callables, so N prefill + M decode workers cost exactly the
     template's compiles (the per-worker jit-cache assertions then count
-    the shared caches).  Sharing requires the SAME model object and
-    block size (the closures capture both); arena shapes
-    (num_slots/max_len/num_blocks) may differ, but each distinct shape
-    adds a cache entry to the shared programs, so homogeneous pools are
-    what keeps the per-worker (1, 1) invariant literal."""
+    the shared caches).  Sharing requires the SAME model object, block
+    size and prefill chunk (the closures capture all three); arena
+    shapes (num_slots/max_len/num_blocks) may differ, but each distinct
+    shape adds a cache entry to the shared programs, so homogeneous
+    pools are what keeps the per-worker (1, 1) invariant literal."""
 
     model_ref: object
     block_size: int
@@ -187,6 +208,9 @@ class SharedPrograms(NamedTuple):
     #: equality up front.
     kv_dtype: object = None
     draft_kv_dtype: object = None
+    #: tokens a prefill dispatch covers (``_chunk_tokens``): baked into
+    #: the prefill closure's scatter, and the shape of its ``ids``
+    chunk: int = 0
 
 
 class ServeEngine:
@@ -379,6 +403,11 @@ class ServeEngine:
                               spill=self._spill)
         self._wire_spill()
 
+        # tokens one prefill dispatch covers: derived from the arena,
+        # the same for every prompt, so prefill stays ONE program
+        self._chunk = _chunk_tokens(self.pool.block_size,
+                                    self.pool.max_blocks)
+
         self._running: Dict[int, Request] = {}      # slot -> request
         # device-resident per-slot last tokens: written by prefill (the
         # request's first token) and decode (each next token); the host
@@ -401,6 +430,12 @@ class ServeEngine:
                     f"programs= sharing requires matching block_size "
                     f"(template {programs.block_size}, this engine "
                     f"{self.pool.block_size})")
+            if programs.chunk != self._chunk:
+                raise ValueError(
+                    f"programs= sharing requires the same prefill chunk "
+                    f"(template {programs.chunk} tokens, this engine "
+                    f"{self._chunk}: a max_len under {_PREFILL_ROWS} "
+                    f"tokens caps it)")
             if programs.draft_ref is not draft_model or \
                     programs.spec_k != self.spec_k:
                 raise ValueError(
@@ -423,18 +458,19 @@ class ServeEngine:
             self._handoff = programs.handoff
             self._verify = programs.verify
             return
-        bs = self.pool.block_size
+        bs, chunk = self.pool.block_size, self._chunk
         resume = resume_step(model)
 
         from . import spec as spec_mod
 
         def prefill_chunk(params, buffers, ids, pos, last_idx, slot,
-                          tables, toks, caches):
+                          fresh, tables, toks, caches):
             # one block-aligned chunk of one request's prompt: gather
             # the slot's dense view, run the cached forward at the
             # traced offset, pick the chunk's last valid token
             # in-program (only the final chunk's pick survives), and
-            # scatter the ONE block this chunk filled back to the arena
+            # scatter the blocks this chunk filled, those from position
+            # ``fresh`` on, back to the arena
             # (the gather/forward/scatter halves are the SAME helpers
             # the speculative prefill composes — serve/spec.py — so
             # the two prefill programs' semantics cannot drift apart)
@@ -448,7 +484,8 @@ class ServeEngine:
             # _pick_impl's temperature-0 branch in generate())
             tok = jnp.argmax(last, axis=-1).astype(jnp.int32)[0]
             toks = toks.at[slot].set(tok)
-            new = spec_mod.scatter_chunk(row, pos, caches, dense, bs)
+            new = spec_mod.scatter_chunk(row, pos, fresh, caches, dense,
+                                         bs, chunk)
             return toks, new
 
         dec = decode_step(model)
@@ -505,13 +542,13 @@ class ServeEngine:
             # so the fixed compiled set is (prefill, decode, verify,
             # handoff), asserted via spec_compiled_counts()
             self._prefill = jax.jit(
-                spec_mod.make_spec_prefill(model, draft_model, bs),
-                donate_argnums=(10, 11))
+                spec_mod.make_spec_prefill(model, draft_model, bs, chunk),
+                donate_argnums=(11, 12))
             self._verify = jax.jit(
                 spec_mod.make_verify(model, draft_model, self.spec_k, bs),
                 donate_argnums=(8, 9))
         else:
-            self._prefill = jax.jit(prefill_chunk, donate_argnums=(8,))
+            self._prefill = jax.jit(prefill_chunk, donate_argnums=(9,))
             self._verify = None
         self._decode = jax.jit(decode_paged, donate_argnums=(6,))
         self._handoff = jax.jit(handoff_gather)
@@ -558,7 +595,8 @@ class ServeEngine:
         return SharedPrograms(self.model, self.pool.block_size,
                               self._prefill, self._decode, self._handoff,
                               self.draft_model, self.spec_k, self._verify,
-                              self._kv_dtype, self._draft_kv_dtype)
+                              self._kv_dtype, self._draft_kv_dtype,
+                              self._chunk)
 
     def lower_programs(self, names=None):
         """jax ``Lowered`` handles of the exactly-two programs (keyed
@@ -573,20 +611,19 @@ class ServeEngine:
         and the jit caches (:meth:`compiled_counts`) are untouched.
         The traced shapes are exactly the runtime dispatch shapes, so
         the audited modules ARE the serving modules."""
-        bs = self.pool.block_size
         zero = jnp.asarray(0, jnp.int32)
 
         def lower_prefill():
+            staged = (jnp.zeros((1, self._chunk), jnp.int32), zero,
+                      jnp.asarray(self._chunk - 1, jnp.int32), zero, zero)
             if self._verify is not None:
                 return self._prefill.lower(
                     self._params, self._buffers, self._dparams,
-                    self._dbuffers, jnp.zeros((1, bs), jnp.int32),
-                    zero, jnp.asarray(bs - 1, jnp.int32), zero,
+                    self._dbuffers, *staged,
                     self.pool.tables, self._toks, self.pool.caches,
                     self.pool.draft_caches)
             return self._prefill.lower(
-                self._params, self._buffers, jnp.zeros((1, bs), jnp.int32),
-                zero, jnp.asarray(bs - 1, jnp.int32), zero,
+                self._params, self._buffers, *staged,
                 self.pool.tables, self._toks, self.pool.caches)
 
         def lower_handoff():
@@ -1115,17 +1152,27 @@ class ServeEngine:
             start0 = n_shared * bs
             if n_shared:
                 self.metrics.on_prefix_hit(start0)
+            C = self._chunk
+            view = self.pool.max_blocks * bs
             with events.span("serve.prefill", slot=slot, prompt=P,
-                             shared=start0):
-                for start in range(start0, P, bs):
+                             shared=start0, chunks=-(-(P - start0) // C)):
+                for fresh in range(start0, P, C):
                     with events.span("serve.prefill.stage"):
-                        ids = np.zeros((1, bs), np.int32)
-                        chunk = replay[start:start + bs]
+                        # the program writes all C rows at [start,
+                        # start + C) whatever is valid, and
+                        # dynamic_update_slice clamps silently: a chunk
+                        # that would cross the view's end starts early
+                        # instead and recomputes the tokens below
+                        # ``fresh``, whose blocks the scatter leaves be
+                        start = min(fresh, view - C)
+                        ids = np.zeros((1, C), np.int32)
+                        chunk = replay[start:start + C]
                         ids[0, :chunk.size] = chunk
                         staged = (jnp.asarray(ids),
                                   jnp.asarray(start, jnp.int32),
                                   jnp.asarray(chunk.size - 1, jnp.int32),
-                                  jnp.asarray(slot, jnp.int32))
+                                  jnp.asarray(slot, jnp.int32),
+                                  jnp.asarray(fresh, jnp.int32))
                     with events.span("serve.prefill.dispatch"):
                         if self._verify is not None:
                             # spec engine: the ONE prefill program
@@ -1146,6 +1193,8 @@ class ServeEngine:
                                  self.pool.tables, self._toks,
                                  self.pool.caches),
                                 rid=req.rid)
+                        self.metrics.on_prefill_chunk(
+                            start + chunk.size - fresh)
                         if self._moe_top_k:
                             self.metrics.on_moe_dispatch(
                                 chunk.size * self._moe_top_k)

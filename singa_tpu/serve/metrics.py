@@ -63,7 +63,8 @@ name                        kind       meaning
                                        slot table mapping
 ``serve.prefill``           span       all chunks of one admission and
                                        the token fetch (``slot``,
-                                       ``prompt``, ``shared`` attrs)
+                                       ``prompt``, ``shared``,
+                                       ``chunks`` attrs)
 ``serve.prefill.stage``     span       per chunk: host staging of the
                                        ids and scalar arguments
 ``serve.prefill.dispatch``  span       per chunk: the guarded dispatch
@@ -96,6 +97,15 @@ name                        kind       meaning
                                        plain decode (``serve.verify``
                                        fault past retries)
 ``serve.accept_rate``       histogram  per-(slot, round) accepted / k
+``serve.prefill_chunk_rows``  counter  prompt tokens one dispatch of
+                                       ``prefill_chunk`` prefilled: the
+                                       filled rows of its fixed (1, C)
+                                       shape, not counting pad rows or
+                                       tokens it only recomputed.
+                                       ``snapshot()`` keeps the total
+                                       and ``prefill_chunks``, the
+                                       number of dispatches: rows per
+                                       chunk is how full a chunk runs
 ``serve.moe_assignments``   counter    (token, expert) pairs one prefill
                                        chunk or decode tick of a
                                        mixture-of-experts model routes:
@@ -184,6 +194,9 @@ class ServeMetrics:
         self.spec_fallbacks = 0
         self.slot_dispatches = 0
         self.slot_dispatch_tokens = 0
+        # prefill dispatches, and the prompt tokens they prefilled
+        self.prefill_chunks = 0
+        self.prefill_chunk_rows = 0
         # mixture-of-experts models: dispatches that ran the router and
         # the (token, expert) pairs they routed; both 0 for a dense model
         self.moe_dispatches = 0
@@ -299,6 +312,13 @@ class ServeMetrics:
         self.slot_dispatches += 1
         self.slot_dispatch_tokens += tokens
 
+    def on_prefill_chunk(self, rows: int) -> None:
+        """One dispatch of ``prefill_chunk`` that prefilled ``rows``
+        prompt tokens."""
+        self.prefill_chunks += 1
+        self.prefill_chunk_rows += rows
+        events.counter("serve.prefill_chunk_rows", rows)
+
     def on_moe_dispatch(self, assignments: int) -> None:
         """One prefill chunk or decode tick of a mixture-of-experts
         model: ``assignments`` = its valid tokens x top-k."""
@@ -375,6 +395,8 @@ class ServeMetrics:
             "spec_fallbacks": self.spec_fallbacks,
             "slot_dispatches": self.slot_dispatches,
             "slot_dispatch_tokens": self.slot_dispatch_tokens,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_chunk_rows": self.prefill_chunk_rows,
             "moe_dispatches": self.moe_dispatches,
             "moe_assignments": self.moe_assignments,
             "accept_rate": self.accept_rate,

@@ -91,34 +91,59 @@ def resume_on_row(resume, params, buffers, ids, pos, row, caches):
     return resume(params, buffers, ids, pos, dense)
 
 
-def scatter_chunk(row, pos, caches, dense, block_size):
-    """Scatter the ONE physical block a prefill chunk filled back into
-    the paged arena — the shared second half of every prefill-chunk
-    program (see :func:`resume_on_row`)."""
-    bs = block_size
-    wb = jax.lax.dynamic_index_in_dim(row[0], pos // bs, keepdims=False)
+def scatter_chunk(row, pos, fresh, caches, dense, block_size, chunk):
+    """Scatter the ``chunk // block_size`` physical blocks a prefill
+    chunk covers back into the paged arena — the shared second half of
+    every prefill-chunk program (see :func:`resume_on_row`).
+
+    The chunk's view rows ``[pos, pos + chunk)`` go out through the
+    slot's table row, except the blocks below ``fresh`` (a traced
+    block-aligned position), which go to the null block 0: they hold
+    tokens the chunk only RECOMPUTED — a shared, refcounted prefix
+    block, or one an earlier chunk of this prompt already wrote — and
+    what is resident is never rewritten.  Rows past the prompt's end
+    are written too, whatever ``last_idx`` says: into the slot's own
+    later blocks, where decode overwrites each position before any mask
+    admits it, or, past the mapped part of the row, into the null block
+    (``BlockPool._sync_table_row`` zeroes the rest of a row).  So only
+    scatter through a row that ``map_slot`` has just installed.  The
+    caller keeps ``[pos, pos + chunk)`` inside the view:
+    ``dynamic_slice`` would clamp a crossing chunk silently and send
+    K/V to the wrong positions."""
+    bs, n = block_size, chunk // block_size
+    idx = pos // bs + jnp.arange(n)
+    wb = jnp.where(idx * bs >= fresh, jnp.take(row[0], idx, mode="clip"), 0)
     new = []
     for (ck, cv), (dk, dv) in zip(caches, dense):
-        kb = jax.lax.dynamic_slice_in_dim(dk[0], pos, bs, axis=0)
-        vb = jax.lax.dynamic_slice_in_dim(dv[0], pos, bs, axis=0)
-        new.append(kv_ops.scatter_block_kv(ck, cv, wb, kb, vb))
+        kb = jax.lax.dynamic_slice_in_dim(dk[0], pos, chunk, axis=0)
+        vb = jax.lax.dynamic_slice_in_dim(dv[0], pos, chunk, axis=0)
+        # one in-place block write each, not one scatter at the vector
+        # of ids: for an arena of 4 KV heads the TPU compiler gives that
+        # scatter a layout of its own and copies the whole arena there
+        # and back, 2.4 ms of an 8.8 ms chunk (PERF.md, PR 29)
+        for i in range(n):
+            ck, cv = kv_ops.scatter_block_kv(
+                ck, cv, wb[i], kb[i * bs:(i + 1) * bs],
+                vb[i * bs:(i + 1) * bs])
+        new.append((ck, cv))
     return new
 
 
-def make_spec_prefill(model, draft, block_size: int):
+def make_spec_prefill(model, draft, block_size: int, chunk: int):
     """The spec engine's prefill-chunk closure: identical to the plain
     engine's (gather the slot's dense view, run the cached forward at
     the traced offset, pick the chunk's last token in-program, scatter
-    ONE block back) — plus the same chunk through the DRAFT model into
-    the draft arena, so a prefilled slot always has both caches warm.
-    The draft's chunk logits are unused (the TARGET picks the first
-    token) and XLA dead-code-eliminates its lm_head."""
+    the chunk's blocks back) — plus the same chunk through the DRAFT
+    model into the draft arena, so a prefilled slot always has both
+    caches warm.  The draft's chunk logits are unused (the TARGET picks
+    the first token) and XLA dead-code-eliminates its lm_head."""
     bs = block_size
     resume = resume_step(model)
     dresume = resume_step(draft)
 
     def prefill_chunk_spec(params, buffers, dparams, dbuffers, ids, pos,
-                           last_idx, slot, tables, toks, caches, dcaches):
+                           last_idx, slot, fresh, tables, toks, caches,
+                           dcaches):
         row = jax.lax.dynamic_index_in_dim(tables, slot, axis=0,
                                            keepdims=True)       # (1, MB)
         logits, dense = resume_on_row(resume, params, buffers, ids,
@@ -127,10 +152,10 @@ def make_spec_prefill(model, draft, block_size: int):
             logits, last_idx, 1, axis=1)[:, 0, :]
         tok = jnp.argmax(last, axis=-1).astype(jnp.int32)[0]
         toks = toks.at[slot].set(tok)
-        new = scatter_chunk(row, pos, caches, dense, bs)
+        new = scatter_chunk(row, pos, fresh, caches, dense, bs, chunk)
         _, ddense = resume_on_row(dresume, dparams, dbuffers, ids, pos,
                                   row, dcaches)
-        dnew = scatter_chunk(row, pos, dcaches, ddense, bs)
+        dnew = scatter_chunk(row, pos, fresh, dcaches, ddense, bs, chunk)
         return toks, new, dnew
 
     return prefill_chunk_spec

@@ -111,10 +111,16 @@ def test_chunked_prefill_then_decode_matches_the_reference(mellum):
 
 @pytest.fixture(scope="module")
 def served(mellum):
-    """Two requests that share a 19-token prefix, through one engine."""
+    """Two requests that share a 19-token prefix, through one engine
+    whose prefill chunk is two blocks (a chunk of the whole `MAX_LEN`
+    would start at 0 and recompute the shared prefix)."""
+    from singa_tpu.serve import engine as engine_mod
     prefix = _ids(19, 4)
     prompts = [np.concatenate([prefix, _ids(n, 5 + n)]) for n in (6, 9)]
-    eng = ServeEngine(mellum, num_slots=2, max_len=MAX_LEN, block_size=BS)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "_PREFILL_ROWS", 2 * BS)
+        eng = ServeEngine(mellum, num_slots=2, max_len=MAX_LEN,
+                          block_size=BS)
     hs = [eng.submit(p, max_new_tokens=12) for p in prompts]
     eng.run_until_idle()
     return eng, prompts, hs
@@ -152,7 +158,10 @@ def test_engine_compiles_two_programs_and_counts_what_it_routes(served):
     prefilled = sum(p.size for p in prompts) - 16
     decoded = sum(len(h.tokens) - 1 for h in hs)
     assert snap["moe_assignments"] == 4 * (prefilled + decoded)
-    chunks = -(-prompts[0].size // BS) + -(-(prompts[1].size - 16) // BS)
+    chunks = -(-prompts[0].size // (2 * BS)) \
+        + -(-(prompts[1].size - 16) // (2 * BS))
+    assert (snap["prefill_chunks"], snap["prefill_chunk_rows"]) \
+        == (chunks, prefilled)
     assert snap["moe_dispatches"] == chunks + 11    # both decode together
 
 

@@ -361,13 +361,17 @@ class TestSpillTier:
         assert hits > 0
         np.testing.assert_array_equal(ref, np.asarray(h.tokens))
 
-    def test_ttft_rehit_beats_reprefill(self):
+    def test_ttft_rehit_beats_reprefill(self, monkeypatch):
         """THE spill-tier acceptance number: serving a prefix re-hit
         from the spill store must beat re-prefilling it.  Needs a model
         whose prefill costs real FLOPs (serve_bench, not tiny — on the
-        tiny model a 48-token re-prefill is cheaper than any restore).
-        Interleaved trials, medians — single passes on a shared CPU box
-        drift more than the effect."""
+        tiny model a 48-token re-prefill is cheaper than any restore)
+        and a prefix of several chunks (one block a chunk here, one
+        chunk against seven: under the 256-row chunk a 52-token prompt
+        is one dispatch, hit or miss).  Interleaved trials, medians —
+        single passes on a shared CPU box drift more than the effect."""
+        from singa_tpu.serve import engine as engine_mod
+        monkeypatch.setattr(engine_mod, "_PREFILL_ROWS", 8)
         tensor.set_seed(0)
         m = models.Llama(models.LlamaConfig.serve_bench())
         m.eval()

@@ -311,14 +311,19 @@ class TestStreamingAndMetrics:
                          ("hist", "serve.token_ms")):
             assert expected in names, f"missing {expected} in {names}"
 
-    def test_sink_lines_of_the_step_spans(self, engine, tmp_path):
+    def test_sink_lines_of_the_step_spans(self, llama, monkeypatch,
+                                          tmp_path):
         """With a sink every phase span of a step is a JSONL line with
         `dur_ms`; the spans of one admission carry the request's trace
         id and nest under its `serve.admit` by `span`/`parent`."""
+        from singa_tpu.serve import engine as engine_mod
+        # one block a chunk, so that a prompt under max_len is three
+        monkeypatch.setattr(engine_mod, "_PREFILL_ROWS", 8)
+        engine = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
         path = str(tmp_path / "serve_spans.jsonl")
         events.configure(path=path)
         try:
-            h = engine.submit(_prompts(1, [12], seed=5)[0],
+            h = engine.submit(_prompts(1, [20], seed=5)[0],
                               max_new_tokens=3)
             engine.run_until_idle()
         finally:
@@ -338,11 +343,14 @@ class TestStreamingAndMetrics:
                 while "parent" in top:
                     top = by_id[top["parent"]]
                 assert top is admit, e
-        # a 12-token prompt at block_size 8: two chunks, one fetch
+        # a 20-token prompt in chunks of 8: three chunks, one fetch
         names = [e["name"] for e in spans]
-        assert names.count("serve.prefill.stage") == 2
-        assert names.count("serve.prefill.dispatch") == 2
+        assert names.count("serve.prefill.stage") == 3
+        assert names.count("serve.prefill.dispatch") == 3
         assert names.count("serve.prefill.fetch") == 1
+        (prefill,) = [e for e in spans if e["name"] == "serve.prefill"]
+        assert (prefill["prompt"], prefill["shared"],
+                prefill["chunks"]) == (20, 0, 3)
 
     def test_snapshot_counts(self, engine):
         from singa_tpu.serve.metrics import ServeMetrics
@@ -355,6 +363,8 @@ class TestStreamingAndMetrics:
         assert snap["evicted"] == {"length": 2}
         assert snap["ttft_ms"]["count"] == 2
         assert snap["token_ms"]["count"] == 4   # 2 reqs x 2 decode tokens
+        # a 4-token prompt is one chunk that fills 4 of its rows
+        assert (snap["prefill_chunks"], snap["prefill_chunk_rows"]) == (2, 8)
 
 
 class TestPrefixSharing:
@@ -485,6 +495,215 @@ class TestPagedArena:
             np.testing.assert_array_equal(ref, np.asarray(h.tokens))
         assert eng.metrics.preempted >= 1
         assert_program_count(eng, (1, 1))
+
+
+#: the prefill chunk of the tests below, and a `max_len` of three
+CH, VIEW = 256, 768
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """LlamaConfig.tiny() with room for three whole chunks: at
+    block_size 8 a prefill chunk is 256 tokens = 32 blocks."""
+    import dataclasses
+    tensor.set_seed(0)
+    m = models.Llama(dataclasses.replace(models.LlamaConfig.tiny(),
+                                         max_position=VIEW))
+    m.eval()
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32))],
+              is_train=False, use_graph=False)
+    return m
+
+
+@pytest.fixture(scope="module")
+def wide_engine(wide):
+    eng = ServeEngine(wide, num_slots=3, max_len=VIEW, block_size=8)
+    assert eng.programs().chunk == CH
+    return eng
+
+
+def _toks(n, seed):
+    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+
+
+def _serve_one(eng, model, prompt, new):
+    """Serve `prompt` alone; its stream must be generate()'s.  Returns
+    the chunks dispatched and the rows they filled."""
+    before = eng.metrics.snapshot()
+    h = eng.submit(prompt, max_new_tokens=new)
+    eng.run_until_idle()
+    want = model.generate(prompt[None], max_new_tokens=new)[0, prompt.size:]
+    np.testing.assert_array_equal(want, np.asarray(h.tokens))
+    snap = eng.metrics.snapshot()
+    return (snap["prefill_chunks"] - before["prefill_chunks"],
+            snap["prefill_chunk_rows"] - before["prefill_chunk_rows"])
+
+
+def _slot_of(eng, handle):
+    return next(s for s, r in eng.running_items() if r.rid == handle.rid)
+
+
+def _shared_blocks(eng, n):
+    """Bytes of physical blocks `n` (a list of ids), every layer."""
+    return [np.asarray(c)[n] for kv in eng.pool.caches for c in kv]
+
+
+def _prompt_of(P, new):
+    def case(model, eng):
+        assert _serve_one(eng, model, _toks(P, P), new) == (-(-P // CH), P)
+    return case
+
+
+def _prefix_hit_off_the_grid(model, eng):
+    """A hit of 40 tokens (five blocks, no multiple of the chunk) under
+    a prompt that reaches into the last chunk of `max_len`: the chunks
+    start at 40, 296 and 552, and [552, 808) would cross 768, where
+    `dynamic_update_slice` clamps and writes K/V 40 positions early.
+    The engine starts that chunk at 512 and writes from 552 on."""
+    shared = _toks(40, 1)
+    _serve_one(eng, model, np.concatenate([shared, _toks(10, 2)]), 2)
+    hit0 = eng.metrics.prefix_hit_tokens
+    got = _serve_one(eng, model, np.concatenate([shared, _toks(714, 3)]), 8)
+    assert eng.metrics.prefix_hit_tokens - hit0 == 40
+    assert got == (3, 714)
+
+
+def _shared_blocks_are_not_rewritten(model, eng):
+    """A hit of 552 tokens under a 626-token prompt: the only chunk
+    starts at 512 and recomputes 40 shared tokens, whose five blocks
+    the scatter sends to the null block.  The 69 shared blocks hold
+    the same bytes after as before."""
+    shared = _toks(552, 4)
+    first = eng.submit(np.concatenate([shared, _toks(6, 5)]),
+                       max_new_tokens=40)
+    eng.step()                      # the first request stays in its slot
+    ids = eng.pool.mapped_blocks(_slot_of(eng, first))[:69]
+    before = _shared_blocks(eng, ids)
+    hit0 = eng.metrics.prefix_hit_tokens
+    second = np.concatenate([shared, _toks(74, 6)])
+    h = eng.submit(second, max_new_tokens=8)
+    eng.step()
+    assert eng.metrics.prefix_hit_tokens - hit0 == 552
+    assert (eng.pool.ref[ids] == 2).all()
+    for was, now in zip(before, _shared_blocks(eng, ids)):
+        np.testing.assert_array_equal(was, now)
+    eng.run_until_idle()
+    want = model.generate(second[None], max_new_tokens=8)[0, second.size:]
+    np.testing.assert_array_equal(want, np.asarray(h.tokens))
+
+
+def _int8_arena(model, eng):
+    """The first layer's K and V depend on nothing cached, so an int8
+    arena must hold the codes and scales of what the f32 arena holds,
+    position by position, through every block of three chunks (to an
+    ulp: the program fuses the division by the scale)."""
+    from singa_tpu.ops import kv_cache as kv_ops
+    q8 = ServeEngine(model, num_slots=1, max_len=VIEW, block_size=8,
+                     kv_dtype="int8")
+    prompt = _toks(600, 7)
+    views = []
+    for e in (eng, q8):
+        h = e.submit(prompt, max_new_tokens=40)
+        e.step()
+        row = e.pool.tables[_slot_of(e, h)][None]
+        views.append(kv_ops.gather_block_kv(*e.pool.caches[0], row))
+        e.run_until_idle()
+        assert h.finish_reason == "length"
+    assert_program_count(q8, (1, 1))
+    for full, quant in zip(*views):
+        code, scale = kv_ops.quantize_kv(full[0, :600])
+        np.testing.assert_allclose(
+            np.asarray(quant[0, :600]),
+            np.asarray(kv_ops.dequantize_kv(code, scale)), rtol=1e-6, atol=0)
+
+
+def _speculative_engine(model, eng):
+    spec = ServeEngine(model, num_slots=2, max_len=VIEW, block_size=8,
+                       draft_model=model, spec_k=2)
+    shared = _toks(40, 8)
+    _serve_one(spec, model, np.concatenate([shared, _toks(10, 9)]), 3)
+    assert _serve_one(spec, model,
+                      np.concatenate([shared, _toks(714, 10)]), 8) == (3, 714)
+    assert spec.spec_compiled_counts()[0] == 1
+
+
+def _preempted_replay(model, eng):
+    """Two 362-token prompts (two chunks, 46 blocks each) outgrow a
+    pool of 96 blocks mid-decode: the youngest replays prompt + tokens
+    so far."""
+    small = ServeEngine(model, num_slots=2, max_len=VIEW, block_size=8,
+                        num_blocks=97)
+    prompts = [_toks(362, 11), _toks(362, 12)]
+    hs = [small.submit(p, max_new_tokens=30) for p in prompts]
+    small.run_until_idle()
+    assert small.metrics.preempted >= 1
+    for p, h in zip(prompts, hs):
+        want = model.generate(p[None], max_new_tokens=30)[0, p.size:]
+        np.testing.assert_array_equal(want, np.asarray(h.tokens))
+    assert_program_count(small, (1, 1))
+
+
+class TestChunkOfSeveralBlocks:
+    """A prefill dispatch covers `C` = 256 tokens, 32 blocks at
+    block_size 8 (ISSUE 29): every stream stays generate()'s, bit for
+    bit, wherever a prompt ends inside a chunk and wherever a chunk
+    starts; resident blocks are never rewritten."""
+
+    CASES = {
+        "shorter_than_a_block": _prompt_of(5, 4),
+        "not_a_multiple_of_a_block": _prompt_of(29, 4),
+        "exactly_one_chunk": _prompt_of(CH, 4),
+        "one_chunk_and_a_token": _prompt_of(CH + 1, 4),
+        "three_chunks": _prompt_of(2 * CH + 44, 4),
+        "ends_in_the_last_chunk_of_max_len": _prompt_of(VIEW - 6, 5),
+        "prefix_hit_off_the_grid": _prefix_hit_off_the_grid,
+        "shared_blocks_are_not_rewritten": _shared_blocks_are_not_rewritten,
+        "int8_arena": _int8_arena,
+        "speculative_engine": _speculative_engine,
+        "preempted_replay": _preempted_replay,
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_streams_are_generates(self, wide, wide_engine, case):
+        self.CASES[case](wide, wide_engine)
+        assert wide_engine.pending == 0
+        assert (wide_engine.pool.ref == 0).all()
+        assert_program_count(wide_engine, (1, 1))
+
+    @pytest.mark.parametrize("kind", ["float32", "int8"])
+    def test_scatter_leaves_resident_and_unmapped_blocks_alone(self, kind):
+        """`scatter_chunk` alone, a chunk of three blocks at position 16
+        of a row that maps four: the block below `fresh` (resident) and
+        the one past the mapped part go to the null block; only the one
+        between is written, with its own rows of the view."""
+        import jax.numpy as jnp
+        from singa_tpu.ops import kv_cache as kv_ops
+        from singa_tpu.serve import spec
+        ck, cv = _arena(kind)
+        row = jnp.asarray([[3, 1, 2, 4, 0, 0]], jnp.int32)
+        rng = np.random.RandomState(1)
+        dk, dv = (jnp.asarray(rng.randn(1, 48, *_ARENA[2:]), jnp.float32)
+                  for _ in range(2))
+        (nk, nv), = spec.scatter_chunk(row, 16, 24, [(ck, cv)], [(dk, dv)],
+                                       block_size=8, chunk=24)
+        for new, old, view in ((nk, ck, dk), (nv, cv, dv)):
+            want = view[0, 24:32]
+            if kind == "int8":
+                want = kv_ops.dequantize_kv(*kv_ops.quantize_kv(want))
+                new, old = (kv_ops.dequantize_kv(c.q, c.scale)
+                            for c in (new, old))
+            np.testing.assert_array_equal(np.asarray(new[4]),
+                                          np.asarray(want))
+            np.testing.assert_array_equal(np.asarray(new[1:4]),
+                                          np.asarray(old[1:4]))
+
+    def test_shared_programs_refuse_another_chunk(self, llama, engine):
+        """`max_len` 32 caps the chunk at 32 tokens, `max_len` 128 at
+        128: the closures bake the chunk in."""
+        assert engine.programs().chunk == 32
+        with pytest.raises(ValueError, match="prefill chunk"):
+            ServeEngine(llama, num_slots=2, max_len=128, block_size=8,
+                        programs=engine.programs())
 
 
 #: (num_blocks, block_size, K, D) of the helper test's tiny arena
