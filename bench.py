@@ -343,8 +343,7 @@ def _serve_knobs(model, platform: str, defaults: dict) -> dict:
             "block_size": int(knobs["block_size"])}
 
 
-def bench_serve(dev, on_tpu: bool, record: bool = True,
-                perf_attr: str | None = None) -> None:
+def bench_serve(dev, on_tpu: bool, record: bool = True) -> None:
     """serve_throughput: a mixed prompt-length request stream through
     the continuous-batching ServeEngine vs the same stream served as
     sequential GenerateMixin.generate calls (ISSUE 2 acceptance: >=1.5x
@@ -381,19 +380,11 @@ def bench_serve(dev, on_tpu: bool, record: bool = True,
 
     Appends a validated `serve_throughput` entry to the obs run-record
     store (CPU runs as smoke entries, same rule as the training bench).
-
-    ISSUE 16 adds runtime attribution: a per-program ledger
-    (``obs.attr``) is installed around the two timed engine windows
-    (plain + speculative), its snapshot is joined against the analytic
-    cost model of the live engine's OWN lowered programs, and the
-    result is dumped to ``perf_attr`` (a path) and/or appended as a
-    ``perf_attr`` record — the trajectory ``tools.lint --perf`` gates.
     """
     import numpy as np
 
     from singa_tpu import models, tensor
     from singa_tpu.models._generate import GREEDY_TOL_BF16
-    from singa_tpu.obs import attr as obs_attr
     from singa_tpu.serve import ServeEngine
     from singa_tpu.serve.metrics import ServeMetrics
 
@@ -443,15 +434,10 @@ def bench_serve(dev, on_tpu: bool, record: bool = True,
     eng.submit(prompts[0], max_new_tokens=n_new)
     eng.run_until_idle()
     eng.metrics = ServeMetrics()
-    # runtime-attribution ledger (ISSUE 16): covers exactly the two
-    # timed windows below, so attributed_frac is meaningful against
-    # window_s = t_eng + t_spec (warmup dispatches excluded)
-    led = obs_attr.install()
     t0 = time.perf_counter()
     handles = [eng.submit(p, max_new_tokens=n_new) for p in prompts]
     eng.run_until_idle()
     t_eng = time.perf_counter() - t0
-    obs_attr.uninstall()
 
     def diverged(hs):
         """Handles whose stream differs from sequential generate()."""
@@ -479,13 +465,11 @@ def bench_serve(dev, on_tpu: bool, record: bool = True,
     seng.submit(prompts[0], max_new_tokens=n_new)
     seng.run_until_idle()
     seng.metrics = ServeMetrics()
-    obs_attr.install(led)       # same ledger: one attribution window
     t0 = time.perf_counter()
     spec_handles = [seng.submit(p, max_new_tokens=n_new)
                     for p in prompts]
     seng.run_until_idle()
     t_spec = time.perf_counter() - t0
-    obs_attr.uninstall()
     bad += diverged(spec_handles)
     sm = seng.metrics.snapshot()
 
@@ -592,9 +576,6 @@ def bench_serve(dev, on_tpu: bool, record: bool = True,
     if record:
         _record_serve(payload, "tpu" if on_tpu else "cpu",
                       dev.device_kind)
-    _emit_perf_attr(led, seng, t_eng + t_spec, perf_attr,
-                    record=record, on_tpu=on_tpu,
-                    device_kind=dev.device_kind)
 
 
 def bench_arena_compare(dev, on_tpu: bool, record: bool = True) -> None:
@@ -756,35 +737,6 @@ def bench_arena_compare(dev, on_tpu: bool, record: bool = True) -> None:
     if record:
         _record_serve(payload, "tpu" if on_tpu else "cpu",
                       dev.device_kind)
-
-
-def _emit_perf_attr(led, seng, window_s: float, dump_path: str | None,
-                    *, record: bool, on_tpu: bool,
-                    device_kind: str) -> None:
-    """Join the serve bench's attribution ledger against the analytic
-    cost model of the SPEC engine's own lowered programs (the superset:
-    prefill_chunk/decode/verify at exactly the serving shapes), dump the
-    payload to ``dump_path`` when given (the CI gate feeds it to
-    ``tools.lint --perf``), and append a ``perf_attr`` record when
-    ``record``."""
-    from singa_tpu.obs import attr as obs_attr
-    from tools.lint.perf import engine_features
-
-    payload = obs_attr.attribution_payload(
-        led.snapshot(), engine_features(seng), window_s)
-    if dump_path:
-        with open(dump_path, "w", encoding="utf-8") as f:
-            json.dump(payload, f, indent=1, sort_keys=True)
-        print(f"# perf_attr payload written to {dump_path}",
-              file=sys.stderr)
-    if record:
-        store = _append_record(
-            "perf_attr", "tpu" if on_tpu else "cpu", device_kind,
-            "perfattr", payload)
-        print(f"# perf_attr entry appended to {store} "
-              f"({len(payload['programs'])} programs, "
-              f"attributed {payload['attributed_frac']:.0%} of "
-              f"{window_s:.2f} s)", file=sys.stderr)
 
 
 def _append_record(kind: str, platform: str, device_kind: str,
@@ -1071,8 +1023,6 @@ def _serve_only_main() -> None:
     the ISSUE-2 acceptance numbers without the full orchestrator.
     `--no-record` skips the store append (the CI gate's table-resolved
     smoke must not dirty the committed store on every run);
-    `--perf-attr PATH` additionally dumps the runtime-attribution
-    payload (ISSUE 16) to PATH for `tools.lint --perf`;
     `--arena-compare` instead runs the ISSUE-17 equal-memory
     f32-vs-int8 KV arena comparison (bench_arena_compare)."""
     import jax
@@ -1089,14 +1039,7 @@ def _serve_only_main() -> None:
         bench_arena_compare(dev, on_tpu,
                             record="--no-record" not in sys.argv)
         return
-    perf_attr = None
-    if "--perf-attr" in sys.argv:
-        idx = sys.argv.index("--perf-attr")
-        if idx + 1 >= len(sys.argv):
-            raise SystemExit("bench.py: --perf-attr needs a PATH")
-        perf_attr = sys.argv[idx + 1]
-    bench_serve(dev, on_tpu, record="--no-record" not in sys.argv,
-                perf_attr=perf_attr)
+    bench_serve(dev, on_tpu, record="--no-record" not in sys.argv)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,8 @@
 """singa_tpu.autotune — the record-driven autotuner (ISSUE 14).
 
 Closes the loop ROADMAP item 4 names: the obs record store already
-holds analytic per-program cost features (``tools.lint.cost.
-cost_features()``, appended on every bench run) and a measured bench/
+holds analytic per-program cost numbers (``tools.lint.cost.
+summarize_cost()``, appended on every bench run) and a measured bench/
 serve trajectory; this package turns them into config decisions —
 
 * :mod:`~singa_tpu.autotune.knobs` — the closed registry of tunable

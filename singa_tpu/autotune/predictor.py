@@ -5,7 +5,7 @@ record-fitted predictors beating analytic cost models for exactly the
 config-choice problem this module serves — but its GNN needs a corpus
 this repo does not have.  What the repo DOES have is a small, exact
 feature vector per sweep point: the knob values themselves plus the
-analytic ``tools.lint.cost.cost_features()`` quantities measured off
+analytic ``tools.lint.cost.summarize_cost()`` quantities measured off
 the point's own lowering (wire bytes per int8_ring setting, etc.).  At
 this scale the right learner is a closed-form one:
 

@@ -30,7 +30,6 @@ re-capture (ibid.).
 
 from __future__ import annotations
 
-import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -41,7 +40,6 @@ import numpy as np
 from . import autograd
 from . import tensor as tensor_mod
 from .graph import CapturedGraph
-from .obs import attr as obs_attr
 from .obs import events as obs_events
 from .layer import Layer
 from .opt import DistOpt, Optimizer
@@ -572,28 +570,6 @@ class _StepExecutor:
 
         self._jitted = jax.jit(wrapped, donate_argnums=(0, 1, 2))
 
-    def _attr_program(self) -> str:
-        """The runtime-attribution ledger key of this executor's
-        program, matching the flagship names the cost model lowers
-        (tools/lint/hlo.py FLAGSHIP_PROGRAMS) so the measured and
-        modeled halves join: ``train_step`` plain, ``train_step_dp2``
-        under DistOpt, ``train_step_dp2_int8`` with the int8 ring.  A
-        non-train executor keys as ``<tag>_step`` — visible in the
-        live view, dropped from ``perf_attr`` records (no modeled
-        side)."""
-        key = getattr(self, "_attr_key", None)
-        if key is None:
-            if not self.is_train:
-                key = f"{self.tag}_step"
-            elif isinstance(self.opt, DistOpt):
-                key = ("train_step_dp2_int8"
-                       if getattr(self.opt, "compression", None)
-                       == "int8_ring" else "train_step_dp2")
-            else:
-                key = "train_step"
-            self._attr_key = key
-        return key
-
     def __call__(self, batch_arrays):
         m = self.model
         params = {n: t.data for n, t in self.param_tensors.items()}
@@ -684,17 +660,10 @@ class _StepExecutor:
         # corrupts the step outputs after a clean dispatch
         faults.fire("device.execute", graph=f"{m.name}.{self.tag}",
                     step=step_host)
-        # runtime attribution (obs.attr): time the jitted dispatch
-        # host-side when a ledger is installed — off path is one global
-        # read, no clock, no allocation (the overhead-honesty contract)
-        led = obs_attr.get()
-        t0 = time.perf_counter() if led is not None else 0.0
         with obs_events.span("graph.execute",
                              graph=f"{m.name}.{self.tag}", step=step_host):
             outs, new_params, new_buffers, new_slots = self._jitted(
                 params, buffers, self.slots, step, rng, *batch_arrays)
-        if led is not None:
-            led.note(self._attr_program(), time.perf_counter() - t0)
         outs = faults.corrupt("device.execute", outs)
         # rebind updated state into the live tensors
         for n, t in self.param_tensors.items():

@@ -22,10 +22,6 @@ The observability subsystem (ISSUE 1):
   in-memory incident ring dumped to ``runs/incidents/`` (and referenced
   from ``incident``/``train_run`` records via ``flight_ref``) when a
   fault fires through to quarantine/recovery/fatal.
-* :mod:`~singa_tpu.obs.attr` — the runtime-attribution ledger
-  (ISSUE 16): per-program dispatch timing at the jitted call seams,
-  joined against the analytic cost model into ``perf_attr`` records
-  and gated by the PERF00x sentinel (tools/lint/perf.py).
 
 ``tools/obsq.py`` is the query layer over all three (timeline
 rendering, trace-derived SLO recomputation, record trajectories).  See
@@ -33,14 +29,14 @@ docs/observability.md for the schema and the smoke-vs-chip protection
 rule.
 """
 
-from . import attr, events, flight, record, schema, trace
+from . import events, flight, record, schema, trace
 from .events import (configure, counter, gauge, histogram,
                      histogram_summary, reset_histograms, span)
 from .flight import FlightRecorder
 from .record import RunRecord, is_onchip_session_doc, new_entry, new_run_id
 from .schema import SCHEMA_VERSION, SchemaError, require
 
-__all__ = ["schema", "record", "events", "trace", "flight", "attr",
+__all__ = ["schema", "record", "events", "trace", "flight",
            "FlightRecorder", "RunRecord", "SchemaError",
            "SCHEMA_VERSION", "require", "new_entry", "new_run_id",
            "is_onchip_session_doc", "configure", "counter", "gauge",

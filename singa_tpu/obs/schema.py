@@ -40,7 +40,7 @@ __all__ = ["SCHEMA_VERSION", "SchemaError", "require", "validate_entry",
            "validate_serve_load_payload", "validate_train_run_payload",
            "validate_incident_payload", "validate_chaos_campaign_payload",
            "validate_hlo_audit_payload",
-           "validate_autotune_sweep_payload", "validate_perf_attr_payload",
+           "validate_autotune_sweep_payload",
            "validate_wire_byte_fields", "validate_flight_ref",
            "validate_serve_tier_fields", "validate_spec_fields",
            "validate_serve_spill_fields", "validate_serve_arena_fields",
@@ -51,7 +51,7 @@ SCHEMA_VERSION = 1
 
 _KINDS = ("session", "bench", "serve_throughput", "serve_load",
           "train_run", "incident", "hlo_audit", "autotune_sweep",
-          "perf_attr", "chaos_campaign")
+          "chaos_campaign")
 
 #: required numeric payload fields of a serve_throughput entry — the
 #: serving bench's headline quantities (tools/record_check.py lints
@@ -175,21 +175,6 @@ _HLO_AUDIT_FIELDS = ("programs", "drifted", "fusions", "collectives",
 _AUTOTUNE_STR_FIELDS = ("domain", "model", "objective_name", "sweep_id")
 _AUTOTUNE_NUM_FIELDS = ("objective", "point")
 _AUTOTUNE_DOMAINS = ("train", "serve")
-
-#: required numeric payload fields of a perf_attr entry (ISSUE 16) —
-#: the enclosing measured window and how much of it the ledger
-#: attributed to programs; ``programs`` itself is validated
-#: per-program (``_PERF_ATTR_PROGRAM_FIELDS``)
-_PERF_ATTR_FIELDS = ("window_s", "attributed_s", "attributed_frac")
-
-#: required numerics per program of a perf_attr payload: the exact
-#: dispatch count/total, the ring percentiles, and the
-#: achieved-roofline fraction joined from the analytic cost model
-#: (singa_tpu.obs.attr.attribution_payload) — a program row missing
-#: its achieved fraction is a clock with no model to reconcile
-#: against, which is the gap this record kind exists to close
-_PERF_ATTR_PROGRAM_FIELDS = ("count", "total_s", "p50_s", "p99_s",
-                             "achieved_flops_frac")
 
 #: required string payload fields of an incident entry — one fired
 #: fault or recovery action (singa_tpu.faults / ServeEngine resilience):
@@ -325,9 +310,6 @@ def validate_entry(entry: Any, ctx: str = "entry") -> None:
         elif kind == "autotune_sweep":
             validate_autotune_sweep_payload(
                 payload, f"{ctx}: autotune_sweep payload")
-        elif kind == "perf_attr":
-            validate_perf_attr_payload(payload,
-                                       f"{ctx}: perf_attr payload")
         elif kind == "chaos_campaign":
             validate_chaos_campaign_payload(
                 payload, f"{ctx}: chaos_campaign payload")
@@ -538,30 +520,6 @@ def validate_autotune_sweep_payload(payload: Any,
                 f"{ctx}: 'loo_rel_err' belongs to the fit record "
                 f"(point == -1), not a measurement point",
                 field="loo_rel_err")
-
-
-def validate_perf_attr_payload(payload: Any,
-                               ctx: str = "perf_attr payload") -> None:
-    """One runtime-attribution window (ISSUE 16): the measured window
-    and attributed totals numeric, and a non-empty ``programs`` object
-    whose every row carries ``_PERF_ATTR_PROGRAM_FIELDS`` numeric — a
-    ledger row whose count or achieved fraction went missing could not
-    support the measured-vs-modeled reconciliation later, which is the
-    record's entire reason to exist.  Program-key REALITY (subset of
-    the flagship set the cost model lowers) is the dynamic audit's job
-    (``python -m tools.lint --records`` imports tools.lint.hlo),
-    keeping this module free of a tools import."""
-    _require_numeric_fields(payload, _PERF_ATTR_FIELDS, ctx)
-    programs = require(payload, "programs", ctx)
-    _expect(isinstance(programs, dict) and bool(programs),
-            f"{ctx}: 'programs' must be a non-empty object, got "
-            f"{programs!r}", field="programs")
-    for name, row in programs.items():
-        _expect(isinstance(name, str) and name,
-                f"{ctx}: program keys must be non-empty strings, got "
-                f"{name!r}", field="programs")
-        _require_numeric_fields(row, _PERF_ATTR_PROGRAM_FIELDS,
-                                f"{ctx}: program {name!r}")
 
 
 def validate_incident_payload(payload: Any,

@@ -1,8 +1,7 @@
 """singa_tpu.serve.disagg — disaggregated serving (ISSUE 12).
 
 Prefill and decode live in opposite roofline classes (prefill
-compute-bound, decode memory-bound — hlocost's committed baselines),
-so one engine co-scheduling both wastes whichever resource the traffic
+compute-bound, decode memory-bound), so one engine co-scheduling both wastes whichever resource the traffic
 mix doesn't saturate.  This package splits them into separately scaled
 pools behind an SLO-aware front door:
 

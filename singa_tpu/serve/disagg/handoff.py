@@ -43,30 +43,14 @@ design (same subsystem package).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import List, Optional
 
 import jax.numpy as jnp
 
-from ...obs import attr as obs_attr
 from ...ops import kv_cache as kv_ops
 from ..scheduler import RUNNING, Request
 
-
-def _gather(engine, *args):
-    """One ``handoff_gather`` dispatch, timed for the runtime-
-    attribution ledger when one is installed (obs.attr — same
-    zero-overhead-when-off seam as ``ServeEngine._dispatch``; the
-    gather bypasses ``_dispatch`` because it must not retry: a retried
-    gather after a partial failure could ship a torn package)."""
-    led = obs_attr.get()
-    if led is None:
-        return engine._handoff(*args)
-    t0 = time.perf_counter()
-    out = engine._handoff(*args)
-    led.note("handoff_gather", time.perf_counter() - t0)
-    return out
 
 __all__ = ["HandoffPackage", "extract", "inject", "can_accept"]
 
@@ -118,12 +102,12 @@ def extract(engine, slot: int) -> HandoffPackage:
         # per-layer list (target caches + draft caches — a pytree, so
         # the handoff program still has exactly one jit-cache entry),
         # split back host-side
-        both = _gather(engine, pool.tables, jnp.asarray(slot, jnp.int32),
-                       pool.caches + pool.draft_caches)
+        both = engine._handoff(pool.tables, jnp.asarray(slot, jnp.int32),
+                               pool.caches + pool.draft_caches)
         dense, draft_kv = both[:len(pool.caches)], both[len(pool.caches):]
     else:
-        dense = _gather(engine, pool.tables,
-                        jnp.asarray(slot, jnp.int32), pool.caches)
+        dense = engine._handoff(pool.tables, jnp.asarray(slot, jnp.int32),
+                                pool.caches)
         draft_kv = None
     keys = engine._req_keys(req)[:req.prompt.size // pool.block_size]
     # point of no return: only after the gather succeeded

@@ -132,7 +132,6 @@ import numpy as np
 
 from .. import faults
 from ..models._generate import _bound, decode_step, resume_step
-from ..obs import attr as obs_attr
 from ..obs import events
 from ..obs import flight as obs_flight
 from ..obs import record as obs_record
@@ -1002,23 +1001,6 @@ class ServeEngine:
         self._incident("serve.spill", type(exc).__name__, f"op:{op}",
                        "degraded", 0, flight_ref=ref)
 
-    #: dispatch site -> the cost model's program key (hlo.FLAGSHIP_
-    #: PROGRAMS) the runtime-attribution ledger accumulates under; the
-    #: handoff gather is timed at its own seam (serve/disagg/handoff.py
-    #: ``_gather`` — it does not ride ``_dispatch``'s retry loop)
-    _ATTR_PROGRAMS = {"serve.prefill": "prefill_chunk",
-                      "serve.decode": "decode",
-                      "serve.verify": "verify"}
-
-    def _attr_program(self, site: str) -> str:
-        """The ledger key one dispatch accumulates under.  An int8
-        arena's decode is a DIFFERENT compiled program with its own
-        cost-model row (the ``decode_int8`` flagship), so its runtime
-        must reconcile against that row, not full-precision decode's."""
-        if site == "serve.decode" and self._kv_dtype == "int8":
-            return "decode_int8"
-        return self._ATTR_PROGRAMS.get(site, site)
-
     def _dispatch(self, site: str, fn, args, **attrs):
         """One guarded jitted dispatch: the injection site fires first
         (host-side, BEFORE the call — the donated arena is still
@@ -1028,25 +1010,12 @@ class ServeEngine:
         before launch, injected faults); a REAL mid-execution
         failure invalidates the donated arena, so retries fail too and
         the error escalates to the caller — quarantine for prefill,
-        arena recovery for decode.
-
-        With a runtime-attribution ledger installed (obs.attr), the
-        SUCCESSFUL call is timed host-side and noted under the site's
-        program key — failed attempts never pollute the distribution
-        (a retried fault is the incident layer's story, not a slow
-        program's).  With no ledger the only cost is one global read."""
+        arena recovery for decode."""
         attempt = 0
         while True:
             try:
                 faults.fire(site, attempt=attempt, **attrs)
-                led = obs_attr.get()
-                if led is None:
-                    return fn(*args)
-                t0 = time.perf_counter()
-                out = fn(*args)
-                led.note(self._attr_program(site),
-                         time.perf_counter() - t0)
-                return out
+                return fn(*args)
             except (RuntimeError, OSError) as e:
                 if isinstance(e, failure.FailureDetected):
                     raise

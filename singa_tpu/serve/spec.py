@@ -1,6 +1,6 @@
 """Speculative decoding over the paged KV arena (ISSUE 13).
 
-The committed hlocost baselines classify decode as MEMORY-bound: every
+Decode is MEMORY-bound: every
 decode dispatch streams the whole weight + KV working set through HBM
 to emit one token per slot.  Speculative decoding raises
 tokens-per-dispatch instead of trying to make the dispatch cheaper: a

@@ -39,7 +39,6 @@ from typing import Any, Callable, Iterable, Optional, Tuple
 
 import numpy as np
 
-from ..obs import attr as obs_attr
 from ..obs import events
 from ..obs import flight as obs_flight
 from ..obs import record as obs_record
@@ -475,17 +474,6 @@ class TrainRunner:
             }
             if flight_ref:
                 payload["flight_ref"] = flight_ref
-            # runtime attribution (ISSUE 16): when a ledger is live,
-            # the run's dispatch count/seconds ride along as numeric
-            # extras — the schema allows extras, and obsq diff can
-            # then put step-time drift next to the outcome fields
-            led = obs_attr.get()
-            if led is not None:
-                snap = led.snapshot()
-                payload["attr_dispatches"] = int(
-                    sum(r["count"] for r in snap.values()))
-                payload["attr_attributed_s"] = round(
-                    sum(r["total_s"] for r in snap.values()), 6)
             entry = obs_record.new_entry(
                 "train_run", platform, platform != "tpu", device_kind,
                 run_id=self.run_id, payload=payload)

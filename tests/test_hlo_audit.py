@@ -5,36 +5,31 @@ lean.
 The invariants under test are the gates' contract:
   * the committed baselines under tools/lint/data/hlo/ (structure) and
     tools/lint/data/hlo/cost/ (cost) are CLEAN against a fresh lowering
-    of all eight flagship programs — so any future change that moves a
-    fusion, collective, donation, flop count, HBM byte, peak-memory
-    byte or wire byte fails CI with a named finding until it is
-    reviewed via ``--update-baselines``;
-  * the three seeded cost regressions from the ISSUE-9 acceptance
-    criteria are each caught with a named COST00x finding and exit 1:
-    a raised CE-chunk count (flops/HBM drift, COST002/COST003), a
-    broken KV-arena donation (peak-memory inflation, COST004), and a
-    changed mesh size (DP wire bytes, COST005) — and
-    ``--update-baselines`` round-trips each with a human-readable
-    metric diff;
-  * the structural seeds from ISSUE 7 still fire (defused CE chunk ->
-    HLO002, moved collective -> HLO004);
+    of all eight flagship programs, one case a program — so a change of
+    ours that moves a collective, a donation, an entry parameter, the
+    fused CE loss, a flop count or a wire byte fails CI with a named
+    finding until it is reviewed via ``--update-baselines``.  What the
+    compiler owns (fusion counts, opcode histograms, ``while`` bodies,
+    HBM and peak bytes) is not compared: it moves with an XLA version
+    and no code of ours;
+  * the seeded regressions are each caught with a named finding and
+    exit 1: a defused CE chunk (HLO008), a moved collective (HLO004), a
+    raised CE-chunk count (COST002), a broken KV-arena donation (HLO005
+    and COST004), a changed mesh size and a silent f32 fallback of the
+    int8 ring (COST005) — and ``--update-baselines`` round-trips with a
+    human-readable metric diff;
   * ``--hlo`` runs BOTH gates off ONE lowering pass per program
     (counted via a stub) — the "lower once, audit twice" contract that
-    keeps the combined lane inside its ~18 s tier-1 budget;
+    keeps the combined lane inside its tier-1 budget;
   * baseline waivers follow the singalint suppression contract in both
     families (reason REQUIRED, unknown codes are findings, the hygiene
     code unwaivable);
-  * the extended ``hlo_audit`` record kind (peak_bytes/flops/hbm_bytes/
-    wire_bytes) roundtrips through the obs schema, and
-    ``cost_features()`` returns the stable documented dict per program.
+  * the ``hlo_audit`` record kind roundtrips through the obs schema.
 
-Budget discipline: ONE module fixture lowers all eight programs
-(~15 s); every other test summarizes texts or diffs summaries in
-memory.  The defused and many-chunk train-step variants are the only
-extra compiles (tiny 1-block config — the cheap lowering).  Per-metric
-sweep variants beyond these seeds are deliberately absent: the three
-seeds plus the in-memory mutations cover every COST code without
-another compile (ROADMAP item 6).
+Budget discipline: ONE module fixture lowers all eight programs; every
+other test summarizes texts or diffs summaries in memory.  The defused
+and many-chunk train-step variants are the only extra compiles (tiny
+1-block config — the cheap lowering).
 """
 
 import json
@@ -90,130 +85,146 @@ def codes_of(findings):
 # the tier-1 gates: committed baselines are clean
 # ---------------------------------------------------------------------------
 
-def test_committed_baselines_are_clean(summaries):
+def _of(findings, program):
+    """The findings filed against one program's baseline file."""
+    return [f for f in findings
+            if os.path.basename(f.path) == f"{program}.json"]
+
+
+@pytest.fixture(scope="module")
+def gate(summaries):
+    return hlo.gate_findings(summaries)
+
+
+@pytest.fixture(scope="module")
+def cost_gate(costs):
+    return cost.cost_gate_findings(costs)
+
+
+@pytest.mark.parametrize("program", hlo.FLAGSHIP_PROGRAMS)
+def test_committed_baselines_are_clean(gate, program):
     """`python -m tools.lint --hlo` structure half exits 0 on this
-    tree: the lowered flagship programs match tools/lint/data/hlo/
-    exactly.  A finding here means a perf-relevant structural change —
-    review it, then re-baseline with `--hlo --update-baselines`
-    (docs/static-analysis.md has the policy)."""
-    findings = hlo.gate_findings(summaries)
+    tree: the lowered flagship program matches tools/lint/data/hlo/
+    exactly.  A finding here means OUR code changed the program's
+    collectives, donation or interface — review it, then re-baseline
+    with `--hlo --update-baselines` (docs/static-analysis.md has the
+    policy)."""
+    findings = _of(gate, program)
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_committed_cost_baselines_are_clean(costs):
-    """The cost half of the same gate: flops/HBM/peak/wire of every
-    flagship program within tolerance of tools/lint/data/hlo/cost/."""
-    findings = cost.cost_gate_findings(costs)
+@pytest.mark.parametrize("program", hlo.FLAGSHIP_PROGRAMS)
+def test_committed_cost_baselines_are_clean(cost_gate, program):
+    """The cost half of the same gate: flops, donated bytes and wire
+    bytes of the program within tolerance of tools/lint/data/hlo/cost/."""
+    findings = _of(cost_gate, program)
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_summaries_encode_the_flagship_invariants(summaries):
+@pytest.mark.parametrize("program", hlo.FLAGSHIP_PROGRAMS)
+def test_summaries_encode_the_flagship_invariants(summaries, program):
     """The metrics the gate protects are non-vacuous in the baselines:
-    the CE-chunk scan IS a while loop, the train step DOES donate
-    params/opt state, the DP step DOES carry collectives, and both
-    serve programs DO donate the KV arena."""
-    for name, s in summaries.items():
-        assert s["schema"] == hlo.SUMMARY_SCHEMA
-        assert s["program"] == name
-        assert s["fusions"]["total"] == sum(s["fusions"]["kinds"].values())
-        assert s["fusions"]["total"] > 0
-        assert s["op_histogram"].get("fusion") == s["fusions"]["total"]
-        assert s["entry_params"] > 0
-    assert summaries["train_step"]["while_loops"] >= 1
-    assert summaries["train_step"]["donated_outputs"] > 0
-    assert summaries["train_step"]["collectives"]["total"] == 0
-    assert summaries["train_step_dp2"]["collectives"]["total"] > 0
-    assert "all-reduce" in \
-        summaries["train_step_dp2"]["collectives"]["by_op"]
-    # the int8-ring DP step's sync IS a ring: collective-permute hops +
-    # the int8 all-gather (plus the absmax-consensus all-reduces), and
-    # the error-feedback residuals ride the donated opt state
-    int8 = summaries["train_step_dp2_int8"]
-    assert "collective-permute" in int8["collectives"]["by_op"]
-    assert "all-gather" in int8["collectives"]["by_op"]
-    assert int8["donated_outputs"] > \
-        summaries["train_step_dp2"]["donated_outputs"]
-    assert summaries["prefill_chunk"]["donated_outputs"] > 0
-    assert summaries["decode"]["donated_outputs"] > 0
-    # the speculative verify round donates BOTH arenas (target + draft
-    # block pools are updated in place) and stays collective-free
-    assert summaries["verify"]["donated_outputs"] > \
-        summaries["decode"]["donated_outputs"]
-    assert summaries["verify"]["collectives"]["total"] == 0
-    # the disagg handoff gather reads the arena without consuming it
-    assert summaries["handoff_gather"]["donated_outputs"] == 0
-    assert summaries["handoff_gather"]["collectives"]["total"] == 0
-    # the int8-arena decode donates MORE outputs than f32 decode — the
-    # QuantKV arena flattens into codes + scale leaves, all in place
-    assert summaries["decode_int8"]["donated_outputs"] > \
-        summaries["decode"]["donated_outputs"]
-    assert summaries["decode_int8"]["collectives"]["total"] == 0
+    the train step DOES donate params/opt state and holds no un-fused
+    logits, the DP step DOES carry collectives, and the serve programs
+    DO donate the KV arena."""
+    s = summaries[program]
+    assert s["schema"] == hlo.SUMMARY_SCHEMA
+    assert s["program"] == program
+    assert s["entry_params"] > 0
+    assert ("unfused_logits" in s) == program.startswith("train_step")
+    decode_donated = summaries["decode"]["donated_outputs"]
+    if program == "train_step":
+        assert s["donated_outputs"] > 0
+        assert s["collective_ops"] == []
+        assert s["unfused_logits"] is False
+    elif program == "train_step_dp2":
+        assert "all-reduce" in s["collective_ops"]
+        assert s["unfused_logits"] is False
+    elif program == "train_step_dp2_int8":
+        # the int8-ring DP step's sync IS a ring: collective-permute
+        # hops + the int8 all-gather (plus the absmax-consensus
+        # all-reduces), and the error-feedback residuals ride the
+        # donated opt state
+        assert "collective-permute" in s["collective_ops"]
+        assert "all-gather" in s["collective_ops"]
+        assert s["donated_outputs"] > \
+            summaries["train_step_dp2"]["donated_outputs"]
+        assert s["unfused_logits"] is False
+    elif program in ("prefill_chunk", "decode"):
+        assert s["donated_outputs"] > 0
+        assert s["collective_ops"] == []
+    elif program == "verify":
+        # the speculative verify round donates BOTH arenas (target +
+        # draft block pools are updated in place), collective-free
+        assert s["donated_outputs"] > decode_donated
+        assert s["collective_ops"] == []
+    elif program == "handoff_gather":
+        # the disagg handoff gather reads the arena without consuming it
+        assert s["donated_outputs"] == 0
+        assert s["collective_ops"] == []
+    else:
+        # the int8-arena decode donates MORE outputs than f32 decode —
+        # the QuantKV arena flattens into codes + scale leaves, all in
+        # place
+        assert program == "decode_int8"
+        assert s["donated_outputs"] > decode_donated
+        assert s["collective_ops"] == []
 
 
-def test_cost_summaries_encode_the_flagship_invariants(costs):
+@pytest.mark.parametrize("program", hlo.FLAGSHIP_PROGRAMS)
+def test_cost_summaries_encode_the_flagship_invariants(costs, program):
     """The cost metrics are non-vacuous and mutually consistent: real
     flops everywhere, per-participant DP flops exactly half the
     single-device step (the batch splits two ways), wire bytes only in
-    the DP program (= the f32 gradient payload under the ring model's
-    2(P-1)/P factor), donated bytes on every donating program, and the
-    tiny configs all memory-bound."""
-    for name, s in costs.items():
-        assert s["schema"] == cost.COST_SCHEMA
-        assert s["program"] == name
-        # handoff_gather is the one legitimately flop-free program: a
-        # pure KV block gather (the disagg handoff source) moves bytes,
-        # not math — its whole cost story is HBM traffic
-        if name == "handoff_gather":
-            assert s["flops"] == 0
-        else:
-            assert s["flops"] > 0
-        assert s["hbm_bytes"] > 0
-        assert s["peak_bytes"] > 0
-        assert s["intensity"] == pytest.approx(
-            s["flops"] / s["hbm_bytes"], rel=1e-3)
-        assert s["roofline"] in ("memory-bound", "compute-bound")
-        total_fusions = sum(s["fusion_classes"].values())
-        assert total_fusions > 0
-    assert costs["handoff_gather"]["roofline"] == "memory-bound"
-    assert costs["handoff_gather"]["wire_bytes"] == 0
-    # the handoff gather must NOT donate: a failed handoff has to
-    # leave the source arena valid for the router to re-route
-    assert costs["handoff_gather"]["donated_bytes"] == 0
-    # one verify dispatch packs k+1 draft steps plus a (k+1)-token
-    # target window: it must be compute-DENSER per dispatch than the
-    # one-token decode program — the whole point of ISSUE 13
-    assert costs["verify"]["flops"] > 2 * costs["decode"]["flops"]
-    assert costs["verify"]["intensity"] > costs["decode"]["intensity"]
-    assert costs["verify"]["wire_bytes"] == 0
-    assert costs["train_step"]["flops"] == \
-        2 * costs["train_step_dp2"]["flops"]
-    assert costs["train_step"]["wire_bytes"] == 0
-    assert costs["train_step_dp2"]["wire_bytes"] > 0
-    # ISSUE-10 acceptance, enforced in tier-1: the int8-ring DP step
-    # moves >= 3x fewer collective wire bytes per participant than the
-    # f32 DP step (committed baselines: 72,288 B vs 279,304 B, 3.86x) —
-    # same matmul flops (quantize is elementwise; the flops model
-    # counts dots), the win is pure wire
-    assert costs["train_step_dp2_int8"]["wire_bytes"] * 3 <= \
-        costs["train_step_dp2"]["wire_bytes"]
-    assert costs["train_step_dp2_int8"]["wire_bytes"] > 0
-    assert costs["train_step_dp2_int8"]["flops"] == \
-        costs["train_step_dp2"]["flops"]
-    # donation is weighed, not just counted: train step (params/opt
-    # state) and both serve programs (KV arena) carry donated bytes
-    assert costs["train_step"]["donated_bytes"] > 0
-    assert costs["decode"]["donated_bytes"] > 0
-    assert costs["prefill_chunk"]["donated_bytes"] > 0
-    # ISSUE-17 acceptance, enforced in tier-1: the int8-KV decode moves
-    # FEWER HBM bytes than the f32-arena decode (committed baselines:
-    # 630,816 B vs 672,794 B at the tiny audited config, where weight
-    # traffic dominates — the gap IS the KV-arena traffic drop), and
-    # its int8 arena donates fewer bytes than the f32 arena it replaces
-    assert costs["decode_int8"]["hbm_bytes"] < \
-        costs["decode"]["hbm_bytes"]
-    assert 0 < costs["decode_int8"]["donated_bytes"] < \
-        costs["decode"]["donated_bytes"]
-    assert costs["decode_int8"]["roofline"] == "memory-bound"
+    the DP programs (= the f32 gradient payload under the ring model's
+    2(P-1)/P factor), donated bytes on every donating program."""
+    s = costs[program]
+    assert s["schema"] == cost.COST_SCHEMA
+    assert s["program"] == program
+    assert set(cost.GATED_FIELDS) <= set(s)
+    assert s["hbm_bytes"] > 0
+    assert s["peak_bytes"] > 0
+    if program == "handoff_gather":
+        # the one legitimately flop-free program: a pure KV block
+        # gather (the disagg handoff source) moves bytes, not math. It
+        # must NOT donate: a failed handoff has to leave the source
+        # arena valid for the router to re-route
+        assert s["flops"] == 0
+        assert s["wire_bytes"] == 0
+        assert s["donated_bytes"] == 0
+        return
+    assert s["flops"] > 0
+    if program == "train_step":
+        assert s["flops"] == 2 * costs["train_step_dp2"]["flops"]
+        assert s["wire_bytes"] == 0
+        # donation is weighed, not just counted
+        assert s["donated_bytes"] > 0
+    elif program == "train_step_dp2":
+        assert s["wire_bytes"] > 0
+    elif program == "train_step_dp2_int8":
+        # ISSUE-10 acceptance, enforced in tier-1: the int8-ring DP
+        # step moves >= 3x fewer collective wire bytes per participant
+        # than the f32 DP step — same matmul flops (quantize is
+        # elementwise; the flops model counts dots), the win is pure
+        # wire
+        assert 0 < s["wire_bytes"] * 3 <= \
+            costs["train_step_dp2"]["wire_bytes"]
+        assert s["flops"] == costs["train_step_dp2"]["flops"]
+    elif program == "verify":
+        # one verify dispatch packs k+1 draft steps plus a (k+1)-token
+        # target window: more math per dispatch than the one-token
+        # decode program — the whole point of ISSUE 13
+        assert s["flops"] > 2 * costs["decode"]["flops"]
+        assert s["wire_bytes"] == 0
+    elif program == "decode_int8":
+        # ISSUE-17 acceptance: the int8-KV decode moves FEWER modeled
+        # HBM bytes than the f32-arena decode, and its int8 arena
+        # donates fewer bytes than the f32 arena it replaces
+        assert s["hbm_bytes"] < costs["decode"]["hbm_bytes"]
+        assert 0 < s["donated_bytes"] < costs["decode"]["donated_bytes"]
+    else:
+        assert program in ("prefill_chunk", "decode")
+        assert s["donated_bytes"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -240,19 +251,18 @@ def test_hlo_and_cost_gates_share_one_lowering(stub_lowering, capsys):
 def test_defused_ce_chunk_is_flagged_with_exit_1(texts, summaries,
                                                  monkeypatch):
     """A train step whose CE-chunk fusion is broken (fused_loss=False —
-    the (B*T, V) logits materialize again) must fail the gate: exit 1
-    and a named HLO002 fusion finding for train_step."""
+    the [B, T, V] logits materialize again) must fail the gate: exit 1
+    and a named HLO008 un-fused-logits finding for train_step."""
     txt = hlo.lower_train_step(fused_loss=False)
     broken = dict(summaries)
     broken["train_step"] = hlo.summarize_hlo(txt, "train_step")
     findings = hlo.gate_findings(broken)
-    assert "HLO002" in codes_of(findings)
-    assert all("[train_step]" in f.message for f in findings)
-    fus = [f for f in findings if f.code == "HLO002"][0]
-    assert "fusion structure drifted" in fus.message
+    assert broken["train_step"]["unfused_logits"] is True
+    assert codes_of(findings) == ["HLO008"]
+    assert "[train_step]" in findings[0].message
+    assert "un-fused logits drifted" in findings[0].message
     # and through the front door: `python -m tools.lint --hlo` exits 1
-    # on the defused TEXT (both gates see it — the cost gate flags the
-    # re-materialized logits too)
+    # on the defused TEXT
     broken_texts = dict(texts, train_step=txt)
     monkeypatch.setattr(hlo, "lower_flagship_texts",
                         lambda programs=None: broken_texts)
@@ -266,9 +276,9 @@ def test_moved_collective_is_flagged_with_exit_1(texts, summaries,
     placement finding."""
     real = summaries["train_step_dp2"]
     moved = dict(summaries)
-    moved["train_step_dp2"] = dict(real, collectives=dict(
-        real["collectives"],
-        in_loop_body=real["collectives"]["total"]))
+    assert real["collectives"] > 0
+    moved["train_step_dp2"] = dict(
+        real, collectives_in_loop=real["collectives"])
     findings = hlo.gate_findings(moved)
     assert codes_of(findings) == ["HLO004"]
     assert "collective placement drifted" in findings[0].message
@@ -286,19 +296,17 @@ def test_moved_collective_is_flagged_with_exit_1(texts, summaries,
 # seeded cost regressions (the ISSUE-9 acceptance scenarios)
 # ---------------------------------------------------------------------------
 
-def test_raised_ce_chunk_count_drifts_flops_and_hbm(texts, costs,
+def test_raised_ce_chunk_count_drifts_flops(texts, costs,
                                                     monkeypatch, capsys):
     """Acceptance seed 1: lowering the train step with 8-row CE chunks
-    (4 scan iterations instead of 1) changes analytic flops AND HBM
-    traffic beyond tolerance — named COST002 + COST003 findings, exit 1
-    through the front door, and --update-baselines round-trips with a
+    (4 scan iterations instead of 1) changes analytic flops beyond
+    tolerance — a named COST002 finding, exit 1 through the front door, and --update-baselines round-trips with a
     human-readable metric diff."""
     txt = hlo.lower_train_step(ce_chunk=8)
     chunked = dict(costs)
     chunked["train_step"] = cost.summarize_cost(txt, "train_step")
     findings = cost.cost_gate_findings(chunked)
-    got = set(codes_of(findings))
-    assert "COST002" in got and "COST003" in got
+    assert codes_of(findings) == ["COST002"]
     flops_f = [f for f in findings if f.code == "COST002"][0]
     assert "analytic flops drifted" in flops_f.message
     assert "%" in flops_f.message and "tolerance" in flops_f.message
@@ -314,18 +322,18 @@ def test_raised_ce_chunk_count_drifts_flops_and_hbm(texts, costs,
         diff = cost.update_cost_baselines(costs, d)
         assert "NEW cost baseline" in diff
         diff2 = cost.update_cost_baselines(chunked, d)
-        assert "COST002" in diff2 and "COST003" in diff2
+        assert "COST002" in diff2
         assert "cost unchanged" in diff2       # the other programs
         # ...and the gate is clean against the accepted numbers
         assert cost.cost_gate_findings(chunked, d) == []
 
 
-def test_broken_kv_arena_donation_inflates_peak(texts, costs):
+def test_broken_kv_arena_donation_inflates_peak(texts, summaries, costs):
     """Acceptance seed 2: stripping the decode program's
     input_output_alias (the KV-arena donation) zeroes its donated
     bytes — the arena now needs a fresh allocation on top of the
     still-live argument every dispatch — and the gate names it COST004
-    with the byte cost."""
+    with the byte cost, and HLO005 with the lost alias entries."""
     stripped = re.sub(r"input_output_alias=\{.*?\},\s*", "",
                       texts["decode"], count=1)
     broken = dict(costs)
@@ -333,10 +341,14 @@ def test_broken_kv_arena_donation_inflates_peak(texts, costs):
     assert broken["decode"]["donated_bytes"] == 0
     assert costs["decode"]["donated_bytes"] > 0
     findings = cost.cost_gate_findings(broken)
-    assert "COST004" in codes_of(findings)
-    msg = [f for f in findings if f.code == "COST004"][0].message
+    assert codes_of(findings) == ["COST004"]
+    msg = findings[0].message
     assert "donation was LOST" in msg
-    assert "peak live memory" in msg
+    assert f"{costs['decode']['donated_bytes']:,} B" in msg
+    lost = dict(summaries, decode=hlo.summarize_hlo(stripped, "decode"))
+    structural = hlo.gate_findings(lost)
+    assert codes_of(structural) == ["HLO005"]
+    assert "LOST" in structural[0].message
     # the train step's params/opt-state donation is big enough that the
     # modeled liveness peak itself inflates too
     tstripped = re.sub(r"input_output_alias=\{.*?\},\s*", "",
@@ -453,11 +465,11 @@ def test_update_preserves_waivers_and_prunes_stale(summaries, tmp_path):
     # hand-add a waiver, then re-update: the waiver survives
     path = os.path.join(d, "decode.json")
     doc = json.load(open(path))
-    doc["suppress"] = {"HLO006": "tracked upstream XLA churn"}
+    doc["suppress"] = {"HLO003": "tracked upstream XLA churn"}
     json.dump(doc, open(path, "w"))
     hlo.update_baselines(summaries, d)
     assert json.load(open(path))["suppress"] == \
-        {"HLO006": "tracked upstream XLA churn"}
+        {"HLO003": "tracked upstream XLA churn"}
     # a program that stops being lowered loses its baseline, loudly
     subset = {p: s for p, s in summaries.items() if p != "decode"}
     diff = hlo.update_baselines(subset, d)
@@ -614,30 +626,6 @@ def test_hlo_audit_record_schema_roundtrip(summaries, costs, tmp_path):
     bad["payload"] = {"programs": 4}
     with pytest.raises(schema.SchemaError, match="drifted|fusions"):
         schema.validate_entry(bad)
-
-
-# ---------------------------------------------------------------------------
-# cost_features(): the autotuner's analytic feature extractor
-# ---------------------------------------------------------------------------
-
-def test_cost_features_stable_documented_dict(texts, costs):
-    """cost_features() (ROADMAP item 4's analytic inputs) returns
-    exactly FEATURE_KEYS per flagship program, numeric except the
-    roofline class, consistent with the gated summaries, and
-    deterministic for fixed texts."""
-    feats = cost.cost_features(texts)
-    assert set(feats) == set(hlo.FLAGSHIP_PROGRAMS)
-    for name, row in feats.items():
-        assert tuple(sorted(row)) == tuple(sorted(cost.FEATURE_KEYS))
-        for k in cost.FEATURE_KEYS:
-            if k == "roofline":
-                assert row[k] in ("memory-bound", "compute-bound")
-            else:
-                assert isinstance(row[k], (int, float))
-                assert not isinstance(row[k], bool)
-        assert row["flops"] == costs[name]["flops"]
-        assert row["peak_bytes"] == costs[name]["peak_bytes"]
-    assert feats == cost.cost_features(texts)
 
 
 # ---------------------------------------------------------------------------

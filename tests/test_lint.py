@@ -137,46 +137,6 @@ class TestJitPurity:
         """, "SGL001")
         assert codes_of(out) == ["SGL001"]
 
-    def test_fires_on_attr_ledger_inside_jit(self):
-        # the runtime-attribution ledger (ISSUE 16) is impure like the
-        # event layer: a timer note migrating inside a jit root would
-        # fire once at trace time and never again
-        out = lint("""
-            import jax
-            from singa_tpu.obs import attr
-
-            @jax.jit
-            def step(x):
-                attr.note("train_step", 0.0)
-                return x + 1
-        """, "SGL001")
-        assert codes_of(out) == ["SGL001"]
-        assert "attr.note" in out[0].message
-
-    def test_clean_attr_ledger_around_jit_dispatch(self):
-        # the instrumented seams' actual shape: ledger read + clock +
-        # note OUTSIDE the jit root, wrapping the dispatch
-        out = lint("""
-            import time
-
-            import jax
-            from singa_tpu.obs import attr
-
-            @jax.jit
-            def step(x):
-                return x + 1
-
-            def run(x):
-                led = attr.get()
-                if led is None:
-                    return step(x)
-                t0 = time.perf_counter()
-                y = step(x)
-                led.note("train_step", time.perf_counter() - t0)
-                return y
-        """, "SGL001")
-        assert out == []
-
     def test_clean_when_effects_are_outside_jit(self):
         out = lint("""
             import jax
@@ -1205,6 +1165,11 @@ class TestOutputAndCli:
             assert code in out
         for code in COST_CODES:
             assert code in out
+        # the compiler-owned metrics and the runtime-attribution gate
+        # are gone, not hidden
+        for gone in ("HLO002", "HLO006", "HLO007", "COST003", "COST006",
+                     "PERF0"):
+            assert gone not in out
         # conclint: the thread-model gate code and the retired alias
         assert "SGL014" in out
         assert "SGL004" in out and "retired" in out

@@ -23,8 +23,6 @@ accept-rate sweep over block_size x kv_dtype runs extra engines and is
 marked ``slow``.
 """
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -362,14 +360,15 @@ class TestSpillTier:
         np.testing.assert_array_equal(ref, np.asarray(h.tokens))
 
     def test_ttft_rehit_beats_reprefill(self, monkeypatch):
-        """THE spill-tier acceptance number: serving a prefix re-hit
-        from the spill store must beat re-prefilling it.  Needs a model
-        whose prefill costs real FLOPs (serve_bench, not tiny — on the
-        tiny model a 48-token re-prefill is cheaper than any restore)
-        and a prefix of several chunks (one block a chunk here, one
-        chunk against seven: under the 256-row chunk a 52-token prompt
-        is one dispatch, hit or miss).  Interleaved trials, medians —
-        single passes on a shared CPU box drift more than the effect."""
+        """THE spill tier's reason to exist, held to its cause: a
+        prefix re-hit served from the spill store is admitted with its
+        prefix restored (``prefetch_hits``) and prefills only the
+        suffix, where the plain engine re-prefills the whole prompt —
+        one chunk against seven at one block a chunk (under the 256-row
+        chunk a 52-token prompt is one dispatch, hit or miss).  Both
+        streams equal ``generate()``.  Whether a restore beats a
+        re-prefill in TIME is a chip cell's question (ROADMAP C6): CPU
+        milliseconds on a shared box say nothing about it."""
         from singa_tpu.serve import engine as engine_mod
         monkeypatch.setattr(engine_mod, "_PREFILL_ROWS", 8)
         tensor.set_seed(0)
@@ -385,34 +384,31 @@ class TestSpillTier:
                             num_blocks=18, spill_blocks=64,
                             programs=plain.programs())
 
-        def ttft_ms(eng, p):
-            t0 = time.perf_counter()
-            h = eng.submit(p, max_new_tokens=2)
-            while not h.tokens:
-                eng.step()
-            dt = (time.perf_counter() - t0) * 1e3
-            eng.run_until_idle()
-            return dt
-
         def cycle(eng):
+            """Three fillers push the shared prefix out of the arena,
+            then one request re-hits it: (prefill chunks that request
+            ran, its stream == generate())."""
             for _ in range(3):
                 eng.submit(rng.randint(0, 1024, (48,)).astype(np.int32),
                            max_new_tokens=4)
             eng.run_until_idle()
-            tail = rng.randint(0, 1024, (4,)).astype(np.int32)
-            return ttft_ms(eng, np.concatenate([shared, tail]))
+            prompt = np.concatenate(
+                [shared, rng.randint(0, 1024, (4,)).astype(np.int32)])
+            before = eng.metrics.snapshot()["prefill_chunks"]
+            h = eng.submit(prompt, max_new_tokens=2)
+            eng.run_until_idle()
+            ref = m.generate(prompt[None], max_new_tokens=2)[0,
+                                                             prompt.size:]
+            np.testing.assert_array_equal(ref, np.asarray(h.tokens))
+            return eng.metrics.snapshot()["prefill_chunks"] - before
 
-        for eng in (plain, spill):      # warm programs + restore path
+        for eng in (plain, spill):      # first sight of the prefix
             cycle(eng)
-            cycle(eng)
-        samples = {plain: [], spill: []}
-        for _ in range(5):              # interleaved: shared-box fair
-            for eng in (plain, spill):
-                samples[eng].append(cycle(eng))
-        med = {e: sorted(s)[len(s) // 2] for e, s in samples.items()}
-        assert spill.metrics.prefetch_hits > 0
-        assert med[spill] < med[plain], \
-            f"re-hit {med[spill]:.2f} ms !< re-prefill {med[plain]:.2f} ms"
+        for _ in range(3):              # interleaved, as a server sees it
+            assert cycle(plain) == 7
+            hits = spill.metrics.prefetch_hits
+            assert cycle(spill) == 1
+            assert spill.metrics.prefetch_hits > hits
 
 
 # ---------------------------------------------------------------------------

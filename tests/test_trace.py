@@ -302,17 +302,25 @@ class TestObsq:
         assert rc == 0
         assert "no events" in capsys.readouterr().out
 
-    def test_diff_builds_the_trajectory_table(self, tmp_path):
+    @staticmethod
+    def _hlo_audit_store(tmp_path, wire_flops):
+        """A store of hlo_audit records, one per (wire_bytes, flops)."""
+        os.makedirs(str(tmp_path), exist_ok=True)
         store = str(tmp_path / "records.jsonl")
         rr = obs_record.RunRecord(store)
-        for i, (wire, flops) in enumerate([(100, 10), (100, 10),
-                                           (50, 11)]):
+        for i, (wire, flops) in enumerate(wire_flops):
             rr.append(obs_record.new_entry(
                 "hlo_audit", "cpu", True, "cpu", run_id=f"a{i}",
                 payload={"programs": 5, "drifted": 0, "fusions": 7,
                          "collectives": 2, "while_loops": 1,
                          "flops": flops, "hbm_bytes": 9,
-                         "peak_bytes": 9, "wire_bytes": wire}))
+                         "peak_bytes": 9, "wire_bytes": wire,
+                         "cost_per_program": {"decode": {"flops": 3}}}))
+        return store
+
+    def test_diff_builds_the_trajectory_table(self, tmp_path):
+        store = self._hlo_audit_store(
+            tmp_path, [(100, 10), (100, 10), (50, 11)])
         header, rows = obsq.diff_rows(store, "hlo_audit", last=2,
                                       fields=["wire_bytes", "flops"])
         assert header == ["run_id", "wire_bytes", "flops"]
@@ -321,6 +329,37 @@ class TestObsq:
         assert rows[2][1] == "-50.0%"       # the wire-bytes move, named
         with pytest.raises(LookupError):
             obsq.diff_rows(store, "serve_load")
+
+    def test_assert_last_green_red_and_trivial(self, tmp_path, capsys):
+        store = self._hlo_audit_store(tmp_path, [(100, 20), (100, 25)])
+        base = ["diff", "hlo_audit", "--records", store]     # flops +25%
+        assert obsq.main(base + ["--assert-last", "flops<=+50%"]) == 0
+        assert obsq.main(base + ["--assert-last", "flops<=+10%"]) == 1
+        assert "ASSERT FAILED" in capsys.readouterr().err
+        assert obsq.main(base + ["--assert-last", "flops>=-10%"]) == 0
+        # fewer than two records: trivially green (fresh trajectory)
+        one = self._hlo_audit_store(tmp_path / "one", [(100, 20)])
+        assert obsq.main(["diff", "hlo_audit", "--records", one,
+                          "--assert-last", "flops<=+1%"]) == 0
+
+    def test_assert_last_rejects_bad_spec_and_missing_field(
+            self, tmp_path):
+        store = self._hlo_audit_store(tmp_path, [(100, 20), (100, 25)])
+        with pytest.raises(ValueError, match="FIELD"):
+            obsq.assert_last(store, "hlo_audit", "flops < 5")
+        # a typo'd field must error, not read as permanently green
+        with pytest.raises(ValueError, match="flopz"):
+            obsq.assert_last(store, "hlo_audit", "flopz<=+5%")
+
+    def test_assert_last_dotted_field(self, tmp_path):
+        store = self._hlo_audit_store(tmp_path, [(100, 20), (100, 25)])
+        # top-level and one-dot fields are the supported surface;
+        # cost_per_program.* is nested two deep and is not reachable
+        assert obsq.assert_last(store, "hlo_audit",
+                                "wire_bytes<=+0%") is None
+        with pytest.raises(ValueError, match="cost_per_program"):
+            obsq.assert_last(store, "hlo_audit",
+                             "cost_per_program.decode.flops<=+0%")
 
     def test_malformed_event_file_fails_loudly(self, tmp_path):
         p = tmp_path / "ev.jsonl"

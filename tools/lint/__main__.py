@@ -26,8 +26,6 @@ Dynamic audits (same checks the old standalone CLIs ran)::
     python -m tools.lint --conc --update-baselines  # reviewed re-model
     python -m tools.lint --proc                   # process-mesh gate
     python -m tools.lint --proc --update-baselines  # reviewed re-model
-    python -m tools.lint --perf PATH              # runtime-attribution
-    python -m tools.lint --perf PATH --update-baselines  # sentinel
 
 ``--select`` filters audit modes too (``--select hlo``,
 ``--select cost``, ``--select conc``, ``--select records``, or mixed
@@ -67,18 +65,13 @@ _AUDIT_MODES = {
             "vs. call sites vs. _OP_TIMEOUTS — also via --proc "
             "(re-baseline with --proc --update-baselines)",
     "hlo": "compiled-program structural gate: lower the flagship train/"
-           "prefill/decode programs and diff fusions, collectives, "
-           "donation vs tools/lint/data/hlo/ — also via --hlo (which "
-           "runs the cost gate too, off ONE shared lowering)",
-    "cost": "compiled-program cost gate (hlocost): flops, HBM traffic, "
-            "peak live memory, collective wire bytes vs "
+           "prefill/decode programs and diff collectives, donation, "
+           "entry parameters, un-fused logits vs tools/lint/data/hlo/ "
+           "— also via --hlo (which runs the cost gate too, off ONE "
+           "shared lowering)",
+    "cost": "compiled-program cost gate (hlocost): flops, lost "
+            "donation bytes, collective wire bytes vs "
             "tools/lint/data/hlo/cost/ — shares the hlo mode's lowering",
-    "perf": "runtime-attribution sentinel (perfattr): box-robust "
-            "invariants of a perf_attr payload (completeness, p50 "
-            "ranking, decode/prefill ratio, achieved-fraction sanity) "
-            "vs tools/lint/data/perf/sentinel.json — via --perf PATH "
-            "only, it needs the payload dump (re-baseline with "
-            "--perf PATH --update-baselines)",
 }
 
 #: the trees the bare full-audit invocation lints (repo-relative) —
@@ -125,12 +118,6 @@ def _list_rules() -> str:
                  "metric; same per-baseline waiver contract):")
     for code, (name, desc) in COST_CODES.items():
         lines.append(f"  {code}  {name:<21} {desc}")
-    from .perf import PERF_CODES
-    lines.append("perf gate finding codes (runtime-attribution "
-                 "sentinel: box-robust invariants, never "
-                 "milliseconds; same waiver contract):")
-    for code, (name, desc) in PERF_CODES.items():
-        lines.append(f"  {code}  {name:<21} {desc}")
     return "\n".join(lines)
 
 
@@ -173,11 +160,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                              "spawn/signal/reap/socket model vs "
                              "tools/lint/data/proc/model.json, plus "
                              "the RPC-protocol cross-check")
-    parser.add_argument("--perf", metavar="PATH", default=None,
-                        help="gate a perf_attr payload dump (bench.py "
-                             "--serve --perf-attr PATH) against the "
-                             "committed runtime-attribution sentinel "
-                             "tools/lint/data/perf/sentinel.json")
     parser.add_argument("--update-baselines", action="store_true",
                         help="rewrite the committed baselines, printing "
                              "a human-readable diff to review: with "
@@ -189,15 +171,13 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.list_rules:
         print(_list_rules())
         return 0
-    if args.update_baselines and not (args.conc or args.perf
-                                      or args.proc):
+    if args.update_baselines and not (args.conc or args.proc):
         args.hlo = True
     mode_flags = [f for f, on in (("--records", args.records is not None),
                                   ("--ckpt", args.ckpt is not None),
                                   ("--hlo", args.hlo),
                                   ("--conc", args.conc),
-                                  ("--proc", args.proc),
-                                  ("--perf", args.perf is not None)) if on]
+                                  ("--proc", args.proc)) if on]
     if len(mode_flags) > 1:
         parser.error(f"{' and '.join(mode_flags)} are separate audit "
                      f"modes")
@@ -225,9 +205,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         if "ckpt" in selected_modes:
             parser.error("the ckpt audit needs its directories — run "
                          "it as --ckpt DIR [DIR ...]")
-        if "perf" in selected_modes:
-            parser.error("the perf sentinel needs its payload dump — "
-                         "run it as --perf PATH")
         if selected_modes and (args.paths or mode_flags):
             parser.error("--select with audit-mode names applies to "
                          "the bare full-audit invocation only")
@@ -238,13 +215,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return audit.records_main(root)
     if args.ckpt is not None:
         return audit.ckpt_main(args.ckpt)
-    if args.perf is not None:
-        from .perf import perf_main
-        try:
-            return perf_main(args.perf, update=args.update_baselines,
-                             json_out=args.json)
-        except RuntimeError as e:
-            parser.error(str(e))
     if args.conc:
         from . import conc
         if args.update_baselines:

@@ -75,39 +75,8 @@ def check_records_root(root: str) -> List[str]:
     if os.path.exists(store):
         errors.extend(obs_record.RunRecord(store).validate())
         errors.extend(_check_flight_refs(store))
-        errors.extend(_check_perf_attr(store))
     errors.extend(_check_incident_dumps(root))
     errors.extend(_check_autotune(root, store))
-    return errors
-
-
-def _check_perf_attr(store: str) -> List[str]:
-    """Every committed ``perf_attr`` entry's program keys must be a
-    subset of ``hlo.FLAGSHIP_PROGRAMS`` (the schema checks row shape;
-    a key the cost model never lowered has no modeled side, so its
-    'achieved fraction' would be a join against nothing — exactly the
-    unfalsifiable number this record kind exists to ban)."""
-    _ensure_repo_on_path()
-    from singa_tpu.obs import record as obs_record
-    from singa_tpu.obs import schema
-
-    from .hlo import FLAGSHIP_PROGRAMS
-
-    errors: List[str] = []
-    try:
-        entries = obs_record.RunRecord(store).entries()
-    except schema.SchemaError:
-        return []          # the store lint above already reported it
-    for e in entries:
-        if e["kind"] != "perf_attr":
-            continue
-        stray = sorted(set((e.get("payload") or {}).get("programs", {}))
-                       - set(FLAGSHIP_PROGRAMS))
-        if stray:
-            errors.append(
-                f"{store}: {e['run_id']}: perf_attr program key(s) "
-                f"{stray} are not flagship programs (known: "
-                f"{list(FLAGSHIP_PROGRAMS)})")
     return errors
 
 

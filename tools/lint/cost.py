@@ -1,13 +1,9 @@
 """hlocost — static cost & memory model over lowered HLO (ISSUE 9).
 
 hloaudit (tools/lint/hlo.py) answers *what* XLA emitted; this module
-answers *how much* it costs.  Memory traffic — not flops — is what
-fusion decisions actually optimize ("Operator Fusion in XLA",
-arXiv:2301.13062), and analytic per-op features (flops, bytes,
-arithmetic intensity) are exactly the inputs a learned TPU performance
-model consumes ("A Learned Performance Model for TPUs",
-arXiv:2008.01040).  Per flagship program, from the SAME optimized-HLO
-text hloaudit lowers (lower once, audit twice), it computes:
+answers *how much* it costs.  Per flagship program, from the SAME
+optimized-HLO text hloaudit lowers (lower once, audit twice), it
+computes:
 
 * **flops** — from ``dot``/``convolution`` shapes and contraction dims,
   weighted by execution multiplicity (fusion call sites, and while-loop
@@ -15,9 +11,7 @@ text hloaudit lowers (lower once, audit twice), it computes:
 * **HBM traffic** — bytes read/written at fusion boundaries: for every
   materializing instruction in a *scheduled* computation (entry, while
   bodies — NOT the interiors of fused computations, which stay in
-  registers/cache), operand bytes + output bytes, trip-weighted.  Plus
-  per-fusion arithmetic intensity and a roofline class (memory- vs
-  compute-bound against :data:`RIDGE_FLOPS_PER_BYTE`);
+  registers/cache), operand bytes + output bytes, trip-weighted;
 * **peak live memory** — a liveness scan over the entry computation's
   instruction schedule (``is_scheduled=true`` HLO: text order IS the
   schedule).  Buffer sizes come from shapes/dtypes; pure-aliasing ops
@@ -28,21 +22,25 @@ text hloaudit lowers (lower once, audit twice), it computes:
 * **collective wire bytes per participant** — ring-algorithm cost per
   collective (all-reduce ``2(P-1)/P``, all-gather/reduce-scatter
   ``(P-1)/P``, permute ``1``) with ``P`` parsed from ``replica_groups``.
-  The committed 2-way-DP train-step number is the f32 baseline ROADMAP
-  item 2's ``compression="int8_ring"`` will be diffed against.
+  The committed 2-way-DP train-step number is the f32 reference the
+  ``compression="int8_ring"`` step's >= 3x win is held against.
 
-Results are gated against committed per-program baselines under
-``tools/lint/data/hlo/cost/`` with a ``COST00x`` finding family —
-RELATIVE tolerances per metric (lowering is deterministic for a fixed
-config; the tolerance absorbs cross-version XLA jitter, not intent
-drift), the same suppression/waiver contract as the HLO gate, and the
-same ``--update-baselines`` flow.  :func:`cost_features` exports the
-per-program feature dict the ROADMAP item-4 autotuner trains on.
+Gated against committed per-program baselines under
+``tools/lint/data/hlo/cost/`` (a ``COST00x`` finding family, the same
+suppression/waiver contract and ``--update-baselines`` flow as the HLO
+gate) are the numbers that move only when this repository's code
+moves: **flops** (dot shapes and trip counts are ours), **donated
+bytes** falling (a lost donation) and **wire bytes** (payload dtype and
+group size are ours).  HBM traffic and peak live bytes depend on where
+the compiler draws its fusion boundaries and how it schedules (the
+train steps' moved 4-10% with one jax upgrade and no code), so they are
+computed for their readers (the ``hlo_audit`` record, tools/autotune.py)
+and not compared.
 
 Scope limits (docs/static-analysis.md "Cost gate"): CPU lowerings with
-tiny configs — the numbers gate *relative* drift and feed feature
-extraction; they are not latency claims, and TPU-specific passes
-(Pallas custom-calls, ICI scheduling) are invisible here.
+tiny configs — the numbers gate *relative* drift; they are not latency
+claims, and TPU-specific passes (Pallas custom-calls, ICI scheduling)
+are invisible here.
 
 Everything is purely textual — importing this module never imports jax.
 """
@@ -57,9 +55,9 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 from .framework import Finding
 
 __all__ = ["COST_CODES", "COST_SCHEMA", "COST_BASELINE_DIR", "TOLERANCES",
-           "RIDGE_FLOPS_PER_BYTE", "parse_module", "summarize_cost",
+           "GATED_FIELDS", "parse_module", "summarize_cost",
            "cost_summaries", "diff_cost", "cost_gate_findings",
-           "update_cost_baselines", "cost_features", "shape_bytes"]
+           "update_cost_baselines", "shape_bytes"]
 
 #: committed per-program cost baselines, next to the structural ones
 COST_BASELINE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -67,7 +65,7 @@ COST_BASELINE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 #: summary format version — a baseline with another version fails the
 #: gate (COST001) instead of diffing garbage
-COST_SCHEMA = 1
+COST_SCHEMA = 2
 
 #: finding codes, one per metric (enumerated by ``--list-rules``)
 COST_CODES = {
@@ -80,17 +78,12 @@ COST_CODES = {
     "COST002": ("flops", "analytic flops (dot/convolution shapes x "
                 "contraction dims, trip-weighted) stay within tolerance "
                 "of the baseline"),
-    "COST003": ("hbm-traffic", "bytes read/written at fusion boundaries "
-                "(trip-weighted) stay within tolerance of the baseline"),
-    "COST004": ("peak-memory", "peak live bytes over the entry schedule "
-                "(donation-aliased outputs excluded) and donated output "
-                "bytes stay within tolerance — a lost donation lands "
-                "here with its byte cost"),
+    "COST004": ("lost-donation", "donated output bytes do not fall — a "
+                "lost donation (KV arena, opt state) lands here with "
+                "its byte cost"),
     "COST005": ("wire-bytes", "collective wire bytes per participant "
                 "(ring model over replica_groups) stay within tolerance "
                 "— the f32 DP baseline for int8-ring comparisons"),
-    "COST006": ("roofline", "the program's roofline class and per-fusion "
-                "memory-/compute-bound split match the baseline"),
 }
 
 #: relative drift tolerance per gated metric.  Lowerings are
@@ -98,17 +91,13 @@ COST_CODES = {
 #: jitter; a config/mesh change moves the numbers far past them.
 TOLERANCES = {
     "COST002": 0.02,   # flops
-    "COST003": 0.02,   # hbm bytes
-    "COST004": 0.02,   # peak bytes
+    "COST004": 0.02,   # donated bytes (falling only)
     "COST005": 0.01,   # wire bytes
 }
 
-#: nominal machine balance (flops per HBM byte) separating memory-bound
-#: from compute-bound — a documented classification constant for the
-#: roofline class, not a measured latency model.  Real accelerators sit
-#: at O(100) flops/byte; the tiny audited configs run far below it, so
-#: a program flipping class means its shape regime genuinely changed.
-RIDGE_FLOPS_PER_BYTE = 16.0
+#: the summary fields a baseline file holds and the gate compares;
+#: ``hbm_bytes`` and ``peak_bytes`` are computed for their readers only
+GATED_FIELDS = ("schema", "program", "flops", "donated_bytes", "wire_bytes")
 
 #: bytes per element for HLO primitive types
 _DTYPE_BYTES = {
@@ -452,7 +441,7 @@ def _computation_flops(mod: Module, comp: str,
 
 
 # ---------------------------------------------------------------------------
-# HBM traffic + per-fusion roofline
+# HBM traffic
 # ---------------------------------------------------------------------------
 
 def _instr_traffic(instr: Instr, defs: Dict[str, Instr]) -> int:
@@ -461,33 +450,6 @@ def _instr_traffic(instr: Instr, defs: Dict[str, Instr]) -> int:
     read = sum(shape_bytes(defs[o].shape)
                for o in dict.fromkeys(instr.operands) if o in defs)
     return read + shape_bytes(instr.shape)
-
-
-def _fusion_rows(mod: Module, mult: Dict[str, int]) -> List[Dict]:
-    """Per-fusion cost rows: boundary bytes, interior flops, intensity,
-    roofline class — trip-weighted by the caller's multiplicity."""
-    rows: List[Dict] = []
-    seen_flops: Dict[str, int] = {}
-    for comp in _scheduled_computations(mod):
-        defs = _def_map(mod.computations.get(comp, []))
-        n = mult.get(comp, 1)
-        for instr in mod.computations.get(comp, []):
-            if instr.opcode != "fusion":
-                continue
-            callee = next((c for a, c in _callees(instr) if a == "calls"),
-                          None)
-            flops = (n * _computation_flops(mod, callee, seen_flops)
-                     if callee else 0)
-            traffic = n * _instr_traffic(instr, defs)
-            intensity = flops / traffic if traffic else 0.0
-            rows.append({
-                "name": instr.name, "bytes": traffic, "flops": flops,
-                "intensity": round(intensity, 4),
-                "class": ("compute-bound"
-                          if intensity >= RIDGE_FLOPS_PER_BYTE
-                          else "memory-bound"),
-            })
-    return rows
 
 
 def _hbm_bytes(mod: Module, mult: Dict[str, int]) -> int:
@@ -654,27 +616,16 @@ def wire_bytes_per_participant(mod: Module, mult: Dict[str, int]) -> int:
 # ---------------------------------------------------------------------------
 
 def summarize_cost(text: str, program: str) -> Dict:
-    """One optimized-HLO module's analytic cost summary — the committed,
-    gated artifact.  Deterministic for a fixed lowering."""
+    """One optimized-HLO module's analytic cost summary.  Deterministic
+    for a fixed lowering; :data:`GATED_FIELDS` of it are committed."""
     mod = parse_module(text)
     mult = computation_multiplicities(mod)
     flops = _computation_flops(mod, mod.entry) if mod.entry else 0
-    hbm = _hbm_bytes(mod, mult)
-    fusions = _fusion_rows(mod, mult)
-    classes = {"memory_bound": 0, "compute_bound": 0}
-    for row in fusions:
-        classes["memory_bound" if row["class"] == "memory-bound"
-                else "compute_bound"] += 1
-    intensity = flops / hbm if hbm else 0.0
     return {
         "schema": COST_SCHEMA,
         "program": program,
         "flops": int(flops),
-        "hbm_bytes": int(hbm),
-        "intensity": round(intensity, 4),
-        "roofline": ("compute-bound" if intensity >= RIDGE_FLOPS_PER_BYTE
-                     else "memory-bound"),
-        "fusion_classes": classes,
+        "hbm_bytes": int(_hbm_bytes(mod, mult)),
         "peak_bytes": int(peak_live_bytes(mod)),
         "donated_bytes": int(donated_bytes(mod)),
         "wire_bytes": wire_bytes_per_participant(mod, mult),
@@ -730,8 +681,6 @@ def diff_cost(program: str, baseline: Dict, current: Dict,
                       f"({pct:+.1f}%, tolerance {tol:.0%})")
 
     rel("COST002", "flops", "analytic flops")
-    rel("COST003", "hbm_bytes", "HBM traffic", " B")
-    rel("COST004", "peak_bytes", "peak live memory", " B")
     b, c = baseline.get("donated_bytes"), current.get("donated_bytes")
     if isinstance(b, (int, float)) and (c or 0) < b and \
             (b - (c or 0)) / max(b, 1.0) > TOLERANCES["COST004"]:
@@ -743,12 +692,6 @@ def diff_cost(program: str, baseline: Dict, current: Dict,
             f"every dispatch")
     rel("COST005", "wire_bytes", "collective wire bytes/participant",
         " B")
-    if baseline.get("roofline") != current.get("roofline") or \
-            baseline.get("fusion_classes") != current.get("fusion_classes"):
-        fnd("COST006",
-            f"roofline drifted: {baseline.get('roofline')} "
-            f"{baseline.get('fusion_classes')} -> "
-            f"{current.get('roofline')} {current.get('fusion_classes')}")
     return findings
 
 
@@ -774,49 +717,6 @@ def update_cost_baselines(summaries: Dict[str, Dict],
     return update_baselines_dir(
         summaries, baseline_dir or COST_BASELINE_DIR, "COST001",
         "cost baseline", diff_cost,
-        lambda s: (f"{s['flops']:,} flops, {s['hbm_bytes']:,} B HBM, "
-                   f"peak {s['peak_bytes']:,} B, wire "
-                   f"{s['wire_bytes']:,} B, {s['roofline']}"),
-        "cost unchanged")
-
-
-# ---------------------------------------------------------------------------
-# feature export (ROADMAP item 4: the autotuner's analytic inputs)
-# ---------------------------------------------------------------------------
-
-#: the stable feature keys :func:`cost_features` guarantees per program
-#: — the analytic half of a learned performance model's input vector
-#: (arXiv:2008.01040 §3: per-kernel flops/bytes/intensity features).
-#: Numeric except ``roofline`` (the class string).
-FEATURE_KEYS = ("flops", "hbm_bytes", "peak_bytes", "donated_bytes",
-                "wire_bytes", "intensity", "roofline",
-                "fusions_memory_bound", "fusions_compute_bound")
-
-
-def cost_features(texts: Optional[Dict[str, str]] = None
-                  ) -> Dict[str, Dict]:
-    """Per-program analytic feature dict for the record-driven autotuner
-    (ROADMAP item 4): exactly :data:`FEATURE_KEYS` per flagship program.
-
-    Pass already-lowered ``texts`` to reuse an audit run's lowering;
-    with no argument, lowers the flagship programs (ONE lowering pass,
-    jax imported only then)."""
-    if texts is None:
-        from .hlo import lower_flagship_texts
-        texts = lower_flagship_texts()
-    out: Dict[str, Dict] = {}
-    for name, summary in cost_summaries(texts).items():
-        out[name] = {
-            "flops": summary["flops"],
-            "hbm_bytes": summary["hbm_bytes"],
-            "peak_bytes": summary["peak_bytes"],
-            "donated_bytes": summary["donated_bytes"],
-            "wire_bytes": summary["wire_bytes"],
-            "intensity": summary["intensity"],
-            "roofline": summary["roofline"],
-            "fusions_memory_bound": summary["fusion_classes"][
-                "memory_bound"],
-            "fusions_compute_bound": summary["fusion_classes"][
-                "compute_bound"],
-        }
-    return out
+        lambda s: (f"{s['flops']:,} flops, donated "
+                   f"{s['donated_bytes']:,} B, wire {s['wire_bytes']:,} B"),
+        "cost unchanged", GATED_FIELDS)

@@ -15,21 +15,24 @@ into a general regression gate:
    *optimized* HLO text on the CPU backend with tiny configs (no chips
    needed; ``ServeEngine.lower_programs()`` and the graph executor's
    ``CapturedGraph.compiled`` are the hooks);
-2. **summarize** each module structurally: fusion count and kinds, op
-   histogram, collective ops and whether they sit inside a loop body
-   (the overlap path), while/remat bodies, entry parameter count, and
-   donation aliasing (``input_output_alias`` — the KV arena and
-   optimizer-state donations);
+2. **summarize** each module by what this repository's code decides:
+   which collective opcodes it carries and whether they sit inside a
+   loop body (the overlap path), the entry parameter count, donation
+   aliasing (``input_output_alias`` — the KV arena and optimizer-state
+   donations), and whether a fused-loss train step materializes the
+   un-fused head's ``[B, T, V]`` logits.  Fusion counts, opcode
+   histograms and ``while`` bodies are the compiler's: they move with
+   an XLA version and no code of ours, so they are not compared;
 3. **diff** the summaries against committed per-program baselines under
    ``tools/lint/data/hlo/``, failing loudly (exit 1) with a named
-   finding per drifted metric — a new op splitting the CE-chunk fusion,
-   a collective migrating out of the loop body, a lost donation.
+   finding per drifted metric — a defused CE chunk, a collective
+   migrating out of the loop body, a lost donation.
 
 Intentional changes are one reviewed command:
 ``python -m tools.lint --hlo --update-baselines`` rewrites the
 baselines and prints a human-readable metric diff for the PR.
 
-A baseline file may carry ``"suppress": {"HLO006": "<reason>"}`` to
+A baseline file may carry ``"suppress": {"HLO005": "<reason>"}`` to
 waive one metric for one program — the reason is REQUIRED (an empty
 one is itself a finding, HLO000), mirroring the singalint suppression
 contract.
@@ -52,7 +55,7 @@ __all__ = ["assert_program_count", "summarize_hlo", "diff_summaries",
            "gate_findings", "lower_flagship_texts", "lower_train_step",
            "update_baselines", "load_baselines", "audit_payload",
            "hlo_main", "BASELINE_DIR", "FLAGSHIP_PROGRAMS", "HLO_CODES",
-           "SUMMARY_SCHEMA"]
+           "SUMMARY_SCHEMA", "GATED_FIELDS"]
 
 _REPO_ROOT = os.path.abspath(
     os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".."))
@@ -62,10 +65,11 @@ BASELINE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "data", "hlo")
 
 #: the audited programs, in lowering order.  train_step is the flagship
-#: decoder's compiled step (fused CE-chunk loss — the lax.scan while
-#: body the gate protects); train_step_dp2 is the same step under a
-#: 2-way 'data' mesh with DistOpt, which is what puts real all-reduce
-#: ops into the module so collective count/placement are non-vacuous;
+#: decoder's compiled step (fused CE-chunk loss — the [B, T, V] logits
+#: the gate keeps out of the module); train_step_dp2 is the same step
+#: under a 2-way 'data' mesh with DistOpt, which is what puts real
+#: all-reduce ops into the module so collective set/placement are
+#: non-vacuous;
 #: train_step_dp2_int8 is that DP step with
 #: ``DistOpt(compression="int8_ring")`` — error-feedback int8 ring
 #: gradient sync, whose committed COST005 wire_bytes baseline proves
@@ -82,9 +86,8 @@ BASELINE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 #: the source arena valid); decode_int8 is the decode step over an
 #: int8 KV arena (serve/mem.py: QuantKV block pools, quantize-on-
 #: scatter / dequantize-on-gather inside the paged primitives) —
-#: its committed COST003 hbm_bytes baseline proves (and permanently
-#: gates) the KV-traffic drop vs decode's f32 arena that is the whole
-#: point of the int8 tier.
+#: its committed donated_bytes baseline weighs the int8 arena against
+#: decode's f32 one.
 FLAGSHIP_PROGRAMS = ("train_step", "train_step_dp2",
                      "train_step_dp2_int8", "prefill_chunk", "decode",
                      "verify", "handoff_gather", "decode_int8")
@@ -92,7 +95,7 @@ FLAGSHIP_PROGRAMS = ("train_step", "train_step_dp2",
 #: summary format version — bump on incompatible metric changes; a
 #: baseline with another version fails the gate (HLO001) instead of
 #: diffing garbage
-SUMMARY_SCHEMA = 1
+SUMMARY_SCHEMA = 2
 
 #: finding codes, one per metric (the "named finding per drifted
 #: metric" contract) — enumerated by ``--list-rules``
@@ -103,23 +106,18 @@ HLO_CODES = {
     "HLO001": ("program-set", "every audited program has a committed, "
                "parseable, same-schema baseline — and every baseline "
                "has a lowered program"),
-    "HLO002": ("fusion", "fusion count and kind histogram match the "
-               "baseline (a new op splitting the CE-chunk fusion lands "
-               "here)"),
-    "HLO003": ("collective", "collective op count and opcode set match "
-               "the baseline"),
+    "HLO003": ("collective", "the set of collective opcodes matches the "
+               "baseline (how many of each is the compiler's combiner's "
+               "business; the wire bytes are COST005's)"),
     "HLO004": ("collective-placement", "collectives inside loop bodies "
                "stay there (a collective migrating off the overlap "
                "path lands here)"),
     "HLO005": ("donation", "input/output buffer aliasing "
                "(donate_argnums: the KV arena, params/opt state) is "
                "not lost"),
-    "HLO006": ("op-histogram", "the module's opcode histogram matches "
-               "the baseline"),
-    "HLO007": ("while-loop", "while/remat body count matches the "
-               "baseline (the CE-chunk scan, remat replays)"),
     "HLO008": ("interface", "entry-computation parameter count matches "
-               "the baseline"),
+               "the baseline, and a fused-loss train step holds no "
+               "[B, T, V] logits array (a defused CE chunk lands here)"),
 }
 
 #: HLO opcodes that are cross-device collectives
@@ -172,8 +170,35 @@ _OPCODE_RE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 _CALLED_RE = re.compile(
     r"(?:calls|body|condition|to_apply|branch_computations|"
     r"true_computation|false_computation)=\{?%?([\w.\-]+)")
-_FUSION_KIND_RE = re.compile(r"\bkind=(\w+)")
 _WHILE_BODY_RE = re.compile(r"\bwhile\(.*\bbody=%?([\w.\-]+)")
+
+#: the audited train step's token ids and vocabulary: [B, T] and V of
+#: ``lower_train_step`` (LlamaConfig.tiny())
+_TRAIN_IDS_SHAPE = (2, 16)
+_TRAIN_VOCAB = 256
+
+
+def _logits_shape(participants: int) -> str:
+    batch, seq = _TRAIN_IDS_SHAPE
+    return f"[{batch // participants},{seq},{_TRAIN_VOCAB}]"
+
+
+#: per train program, the [B, T, V] logits shape the un-fused LM head
+#: materializes (the 2-way DP variants hold B/2 rows per participant).
+#: The fused CE-chunk loss only ever holds a row-flattened [rows, V]
+#: chunk, so the 3-d shape is the tell
+_UNFUSED_LOGITS_SHAPE = {
+    "train_step": _logits_shape(1),
+    "train_step_dp2": _logits_shape(2),
+    "train_step_dp2_int8": _logits_shape(2),
+}
+
+#: the summary fields a baseline file holds and the gate compares.  The
+#: summary also counts fusions, collectives and ``while`` ops for the
+#: ``hlo_audit`` record's drift history (:func:`audit_payload`); those
+#: are the compiler's numbers and are neither committed nor compared
+GATED_FIELDS = ("schema", "program", "entry_params", "donated_outputs",
+                "collective_ops", "collectives_in_loop", "unfused_logits")
 
 
 def _alias_count(text: str) -> int:
@@ -202,7 +227,6 @@ def summarize_hlo(text: str, program: str) -> Dict:
     entry: Optional[str] = None
     cur: Optional[str] = None
     while_bodies: List[str] = []
-    fusion_kinds: Dict[str, int] = {}
 
     for line in text.splitlines():
         if line and not line[0].isspace():
@@ -224,10 +248,6 @@ def summarize_hlo(text: str, program: str) -> Dict:
         comps[cur].append(op)
         for mc in _CALLED_RE.finditer(rhs):
             called.setdefault(cur, []).append(mc.group(1))
-        if op == "fusion":
-            mk = _FUSION_KIND_RE.search(rhs)
-            kind = mk.group(1) if mk else "unknown"
-            fusion_kinds[kind] = fusion_kinds.get(kind, 0) + 1
         if op == "while":
             mw = _WHILE_BODY_RE.search(rhs)
             if mw:
@@ -243,51 +263,38 @@ def summarize_hlo(text: str, program: str) -> Dict:
         in_loop.add(c)
         frontier.extend(called.get(c, []))
 
-    histogram: Dict[str, int] = {}
-    coll_by_op: Dict[str, int] = {}
-    coll_in_loop = 0
+    fusions = collectives = coll_in_loop = 0
+    coll_ops: set = set()
     for comp, ops in comps.items():
         for op in ops:
-            histogram[op] = histogram.get(op, 0) + 1
-            if op in _COLLECTIVE_OPS:
-                coll_by_op[op] = coll_by_op.get(op, 0) + 1
+            if op == "fusion":
+                fusions += 1
+            elif op in _COLLECTIVE_OPS:
+                collectives += 1
+                coll_ops.add(op)
                 if comp in in_loop:
                     coll_in_loop += 1
 
-    entry_params = (comps.get(entry, []).count("parameter")
-                    if entry is not None else 0)
-    return {
+    summary = {
         "schema": SUMMARY_SCHEMA,
         "program": program,
-        "entry_params": entry_params,
+        "entry_params": (comps.get(entry, []).count("parameter")
+                         if entry is not None else 0),
         "donated_outputs": _alias_count(text),
-        "fusions": {"total": sum(fusion_kinds.values()),
-                    "kinds": dict(sorted(fusion_kinds.items()))},
-        "while_loops": histogram.get("while", 0),
-        "collectives": {"total": sum(coll_by_op.values()),
-                        "by_op": dict(sorted(coll_by_op.items())),
-                        "in_loop_body": coll_in_loop},
-        "op_histogram": dict(sorted(histogram.items())),
+        "collective_ops": sorted(coll_ops),
+        "collectives_in_loop": coll_in_loop,
+        "fusions": fusions,
+        "collectives": collectives,
+        "while_loops": len(while_bodies),
     }
+    if program in _UNFUSED_LOGITS_SHAPE:
+        summary["unfused_logits"] = _UNFUSED_LOGITS_SHAPE[program] in text
+    return summary
 
 
 # ---------------------------------------------------------------------------
 # summary diff -> findings
 # ---------------------------------------------------------------------------
-
-def _histogram_drift(base: Dict[str, int],
-                     cur: Dict[str, int]) -> List[str]:
-    """Human fragments for opcode-set and count changes, worst first."""
-    out = []
-    for op in sorted(set(cur) - set(base)):
-        out.append(f"new op {op!r} (x{cur[op]})")
-    for op in sorted(set(base) - set(cur)):
-        out.append(f"op {op!r} vanished (was x{base[op]})")
-    for op in sorted(set(base) & set(cur)):
-        if base[op] != cur[op]:
-            out.append(f"{op}: {base[op]} -> {cur[op]}")
-    return out
-
 
 def _baseline_suppressions(baseline: Dict, path: str, codes: Dict,
                            hygiene_code: str) -> Tuple[set, List[Finding]]:
@@ -341,28 +348,17 @@ def diff_summaries(program: str, baseline: Dict, current: Dict,
             f"--update-baselines"))
         return findings
 
-    bf, cf = baseline.get("fusions", {}), current.get("fusions", {})
-    if bf.get("total") != cf.get("total") or \
-            bf.get("kinds") != cf.get("kinds"):
-        fnd("HLO002",
-            f"fusion structure drifted: {bf.get('total')} fusions "
-            f"{bf.get('kinds')} -> {cf.get('total')} fusions "
-            f"{cf.get('kinds')} (an op falling out of a fusion — e.g. "
-            f"a defused CE chunk — lands here)")
-
-    bc = baseline.get("collectives", {})
-    cc = current.get("collectives", {})
-    if bc.get("total") != cc.get("total") or \
-            bc.get("by_op") != cc.get("by_op"):
-        fnd("HLO003",
-            f"collective ops drifted: {bc.get('by_op')} -> "
-            f"{cc.get('by_op')}")
-    if bc.get("in_loop_body") != cc.get("in_loop_body"):
+    bo, co = baseline.get("collective_ops"), current.get("collective_ops")
+    if bo != co:
+        fnd("HLO003", f"collective opcode set drifted: {bo} -> {co}")
+    bl, cl = (baseline.get("collectives_in_loop"),
+              current.get("collectives_in_loop"))
+    if bl != cl:
         fnd("HLO004",
-            f"collective placement drifted: {bc.get('in_loop_body')} "
-            f"inside loop bodies -> {cc.get('in_loop_body')} (a "
-            f"collective migrated {'out of' if (cc.get('in_loop_body') or 0) < (bc.get('in_loop_body') or 0) else 'into'} "
-            f"the loop/overlap path)")
+            f"collective placement drifted: {bl} inside loop bodies -> "
+            f"{cl} (a collective migrated "
+            f"{'out of' if (cl or 0) < (bl or 0) else 'into'} the "
+            f"loop/overlap path)")
 
     if baseline.get("donated_outputs") != current.get("donated_outputs"):
         b, c = baseline.get("donated_outputs"), current.get("donated_outputs")
@@ -370,26 +366,18 @@ def diff_summaries(program: str, baseline: Dict, current: Dict,
             f"donation aliasing drifted: {b} aliased outputs -> {c}"
             f"{' (a donation was LOST: the arena/state now copies every dispatch)' if (c or 0) < (b or 0) else ''}")
 
-    drift = _histogram_drift(baseline.get("op_histogram", {}),
-                             current.get("op_histogram", {}))
-    if drift:
-        shown = "; ".join(drift[:8])
-        more = len(drift) - 8
-        fnd("HLO006",
-            f"op histogram drifted ({len(drift)} opcode(s)): {shown}"
-            f"{f'; ... {more} more' if more > 0 else ''}")
-
-    if baseline.get("while_loops") != current.get("while_loops"):
-        fnd("HLO007",
-            f"while/remat body count drifted: "
-            f"{baseline.get('while_loops')} -> "
-            f"{current.get('while_loops')}")
-
     if baseline.get("entry_params") != current.get("entry_params"):
         fnd("HLO008",
             f"entry parameter count drifted: "
             f"{baseline.get('entry_params')} -> "
             f"{current.get('entry_params')}")
+    if baseline.get("unfused_logits") != current.get("unfused_logits"):
+        fnd("HLO008",
+            f"un-fused logits drifted: a [B, T, V] array "
+            f"({_UNFUSED_LOGITS_SHAPE[program]}) in the module: "
+            f"{baseline.get('unfused_logits')} -> "
+            f"{current.get('unfused_logits')} (True = the CE-chunk "
+            f"fusion fell apart and the full logits materialize again)")
     return findings
 
 
@@ -456,11 +444,13 @@ def gate_findings_dir(summaries: Dict[str, Dict], baseline_dir: str,
 
 def update_baselines_dir(summaries: Dict[str, Dict], baseline_dir: str,
                          code: str, what: str, diff_fn, describe,
-                         unchanged_label: str) -> str:
-    """The shared ``--update-baselines`` core: write the summaries as
-    the new baselines (preserving each program's ``suppress`` block,
-    pruning stale programs loudly) and return the human-readable metric
-    diff — the reviewed artifact of an intentional change."""
+                         unchanged_label: str,
+                         fields: Tuple[str, ...]) -> str:
+    """The shared ``--update-baselines`` core: write the gated
+    ``fields`` of each summary as the new baseline (preserving each
+    program's ``suppress`` block, pruning stale programs loudly) and
+    return the human-readable metric diff — the reviewed artifact of an
+    intentional change."""
     os.makedirs(baseline_dir, exist_ok=True)
     old, _bad = load_baselines_dir(baseline_dir, code, what)
     lines: List[str] = []
@@ -476,11 +466,11 @@ def update_baselines_dir(summaries: Dict[str, Dict], baseline_dir: str,
                 lines.extend(f"  {f.code} {f.message}" for f in drifted)
             else:
                 lines.append(f"{program}: {unchanged_label}")
-            sup = base.get("suppress")
-            if sup:
-                summary = dict(summary, suppress=sup)
+        gated = {k: summary[k] for k in fields if k in summary}
+        if base is not None and base.get("suppress"):
+            gated["suppress"] = base["suppress"]
         with open(path, "w", encoding="utf-8") as f:
-            json.dump(summary, f, indent=2, sort_keys=True)
+            json.dump(gated, f, indent=2, sort_keys=True)
             f.write("\n")
     for program in sorted(set(old) - set(summaries)):
         os.remove(_baseline_path(program, baseline_dir))
@@ -512,11 +502,10 @@ def update_baselines(summaries: Dict[str, Dict],
     return update_baselines_dir(
         summaries, baseline_dir or BASELINE_DIR, "HLO001", "baseline",
         diff_summaries,
-        lambda s: (f"{s['fusions']['total']} fusions, "
-                   f"{s['collectives']['total']} collectives, "
-                   f"{s['while_loops']} while loops, "
-                   f"{s['donated_outputs']} donated outputs"),
-        "unchanged")
+        lambda s: (f"{s['entry_params']} entry parameters, "
+                   f"{s['donated_outputs']} donated outputs, "
+                   f"collectives {s['collective_ops']}"),
+        "unchanged", GATED_FIELDS)
 
 
 def audit_payload(summaries: Dict[str, Dict],
@@ -531,9 +520,8 @@ def audit_payload(summaries: Dict[str, Dict],
     payload = {
         "programs": len(summaries),
         "drifted": len(list(findings)),
-        "fusions": sum(s["fusions"]["total"] for s in summaries.values()),
-        "collectives": sum(s["collectives"]["total"]
-                           for s in summaries.values()),
+        "fusions": sum(s["fusions"] for s in summaries.values()),
+        "collectives": sum(s["collectives"] for s in summaries.values()),
         "while_loops": sum(s["while_loops"] for s in summaries.values()),
     }
     if cost_summaries is not None:
@@ -592,7 +580,7 @@ def lower_train_step(dp: bool = False, fused_loss: bool = True,
     ``fused_loss=False`` builds the deliberately-defused variant the
     regression tests feed the gate; ``ce_chunk`` overrides
     ``fused_loss_chunk`` (the cost-gate tests lower a many-chunk
-    variant to prove flops/HBM drift is caught)."""
+    variant to prove flops drift is caught)."""
     _ensure_cpu_backend()
     import numpy as np
     from singa_tpu import models, opt, parallel, tensor
@@ -602,13 +590,14 @@ def lower_train_step(dp: bool = False, fused_loss: bool = True,
     # ONE transformer block: XLA compile time scales with instruction
     # count (layer count — measured 3x the gate latency at tiny()'s two
     # blocks), and one block already carries every audited structure:
-    # the fused CE-chunk scan, attention/FFN fusions, params/opt-state
-    # donation, and the DP gradient all-reduces.  The serve programs
+    # the fused CE-chunk loss, params/opt-state donation, and the DP
+    # gradient all-reduces.  The serve programs
     # keep tiny()'s two layers — the repeated per-layer paging pattern
     # is itself an audited structure there.
     cfg = models.LlamaConfig.tiny()
     cfg.num_layers = 1
     cfg.fused_loss = fused_loss
+    assert cfg.vocab_size == _TRAIN_VOCAB, cfg.vocab_size
     if ce_chunk is not None:
         cfg.fused_loss_chunk = ce_chunk
     saved_mesh = parallel.current_mesh()
@@ -622,7 +611,7 @@ def lower_train_step(dp: bool = False, fused_loss: bool = True,
         m.set_optimizer(opt.DistOpt(opt.SGD(lr=0.01, momentum=0.9),
                                     compression=compression)
                         if dp else opt.SGD(lr=0.01, momentum=0.9))
-        ids = tensor.from_numpy(np.zeros((2, 16), np.int32))
+        ids = tensor.from_numpy(np.zeros(_TRAIN_IDS_SHAPE, np.int32))
         m.compile([ids], is_train=True, use_graph=True)
         m.train_step(ids)
         return m.graph.compiled_hlo()
@@ -724,9 +713,9 @@ def hlo_main(update: bool = False, json_out: bool = False,
              structure: bool = True, cost_gate: bool = True,
              cost_baseline_dir: Optional[str] = None,
              static_findings: Optional[List[Finding]] = None) -> int:
-    """Lower ONCE, then audit twice: the structural gate (fusions,
-    collectives, donation — HLO00x) and the cost gate (flops, HBM
-    traffic, peak memory, wire bytes — COST00x, tools/lint/cost.py)
+    """Lower ONCE, then audit twice: the structural gate (collectives,
+    donation, interface — HLO00x) and the cost gate (flops, donated
+    bytes, wire bytes — COST00x, tools/lint/cost.py)
     both summarize the SAME lowered texts.  ``structure``/``cost_gate``
     select the halves (``--select hlo`` / ``--select cost``); with
     ``update``, both baseline families are rewritten with a

@@ -269,8 +269,7 @@ def _reachable_in_jit(root: ast.AST, parents: Dict[ast.AST, ast.AST],
 #: compile, silently skipped on cached executions), which is exactly
 #: the bug class PR 4 pinned to "sites fire host-side OUTSIDE jit"
 _IMPURE_MODULE_PREFIXES = ("obs.events.", "events.", "faults.",
-                           "obs.record.", "record.",
-                           "obs.attr.", "attr.")
+                           "obs.record.", "record.")
 _IMPURE_CALLS = {"time.time", "time.monotonic", "time.perf_counter",
                  "time.sleep", "print", "open", "input"}
 

@@ -270,67 +270,6 @@ def append_record(payload: dict, store: Optional[str] = None,
     return store
 
 
-def _attr_source_engine(target):
-    """The ServeEngine whose lowered programs model ``target``'s
-    dispatches: the engine itself, or — for a disaggregated tier — the
-    first decode worker's engine (decode workers carry the draft, so
-    their program set is the tier's superset)."""
-    if hasattr(target, "lower_programs"):
-        return target
-    router = getattr(target, "_router", target)
-    for pool in (getattr(router, "decode", None),
-                 getattr(router, "prefill", None)):
-        if pool:
-            # a ProcRouter's pools hold WorkerProc handles — the
-            # engines live in other processes, so there is nothing to
-            # attribute against here (each child keeps its own ledger)
-            return getattr(pool[0], "engine", None)
-    return None
-
-
-def _emit_perf_attr(led, target, window_s: float,
-                    dump_path: Optional[str],
-                    store: Optional[str]) -> None:
-    """Join the run's attribution ledger against the cost model of the
-    driven engine's own lowered programs (ISSUE 16); dump to
-    ``dump_path`` when given and append a ``perf_attr`` record when a
-    store is resolved.  Never fatal — attribution must not turn a
-    completed load run into a failure."""
-    if dump_path is None and store is None:
-        return
-    try:
-        import jax
-
-        from singa_tpu.obs import attr as obs_attr
-        from singa_tpu.obs import record as obs_record
-        from tools.lint.perf import engine_features
-
-        src = _attr_source_engine(target)
-        if src is None:
-            raise RuntimeError("no engine exposes lower_programs")
-        payload = obs_attr.attribution_payload(
-            led.snapshot(), engine_features(src), window_s)
-        if dump_path:
-            with open(dump_path, "w", encoding="utf-8") as f:
-                json.dump(payload, f, indent=1, sort_keys=True)
-            print(f"# perf_attr payload written to {dump_path}",
-                  file=sys.stderr)
-        if store is not None:
-            platform = jax.default_backend()
-            dev = jax.devices()[0]
-            entry = obs_record.new_entry(
-                "perf_attr", platform, platform != "tpu",
-                getattr(dev, "device_kind", "") or platform,
-                run_id=obs_record.new_run_id("perfattr"),
-                payload=payload)
-            obs_record.RunRecord(store).append(entry)
-            print(f"# perf_attr entry appended to {store}",
-                  file=sys.stderr)
-    except Exception as e:  # noqa: BLE001
-        print(f"# perf_attr emission failed: {type(e).__name__}: {e}",
-              file=sys.stderr)
-
-
 def _spec_kwargs(spec_k, model):
     """The ServeEngine speculative kwargs for ``--spec-k`` — ONE place
     parameterizes every engine/tier/template builder (self-speculation
@@ -775,12 +714,6 @@ def main(argv=None) -> int:
                     help="run-record store path (default: "
                          "runs/records.jsonl)")
     ap.add_argument("--no-record", action="store_true")
-    ap.add_argument("--perf-attr", default=None, metavar="PATH",
-                    help="dump the runtime-attribution payload "
-                         "(ISSUE 16: per-program dispatch times joined "
-                         "against the analytic cost model) to PATH; "
-                         "a perf_attr record is appended whenever "
-                         "recording is on")
     ap.add_argument("--prefill-workers", type=int, default=0,
                     help="disaggregated tier: prefill pool size "
                          "(with --decode-workers; 0 = single engine)")
@@ -1054,12 +987,8 @@ def main(argv=None) -> int:
                         tenants=args.tenants,
                         shared_len=args.shared_prefix,
                         vocab=m.cfg.vocab_size)
-    # runtime-attribution ledger (ISSUE 16) around the driven window
-    from singa_tpu.obs import attr as obs_attr
-    led = obs_attr.install()
     payload = run_load(eng, wl, deadline_s=args.deadline,
                        pass_tenant=args.tenant_quota is not None)
-    obs_attr.uninstall()
     if args.procs:
         _stamp_mp(payload, eng,
                   args.prefill_workers + args.decode_workers)
@@ -1070,12 +999,6 @@ def main(argv=None) -> int:
                       prefix="mpload" if args.procs else "load",
                       tier=eng if args.procs else None)
         print(f"# serve_load entry appended to {store}", file=sys.stderr)
-    if not args.procs:
-        # attribution is per-process: the supervisor dispatches no XLA
-        # programs of its own, so an mp run's ledger here is empty —
-        # each worker keeps its own
-        _emit_perf_attr(led, eng, payload["detail"]["wall_s"],
-                        args.perf_attr, store)
     return 0
 
 

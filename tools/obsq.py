@@ -28,16 +28,10 @@ JSONL; obsq is the layer that answers questions:
     # autotuner's debugging front door (ISSUE 14)
     python -m tools.obsq diff --sweep atsweep-20260804-...
 
-    # CI trajectory tripwire (ISSUE 16): fail when the newest record's
-    # field moved more than the bound vs its predecessor — no Python
-    # harness needed (trivially green with fewer than two records)
-    python -m tools.obsq diff perf_attr --assert-last "attributed_s<=+75%"
-
-    # the runtime-attribution table of a perf_attr record (or a
-    # payload dump from bench.py --serve --perf-attr PATH): per-program
-    # count, p50/p99, achieved-roofline fraction, measured-vs-modeled
-    python -m tools.obsq attr
-    python -m tools.obsq attr /tmp/perf_attr.json
+    # CI trajectory tripwire: fail when the newest record's field moved
+    # more than the bound vs its predecessor — no Python harness needed
+    # (trivially green with fewer than two records)
+    python -m tools.obsq diff hlo_audit --assert-last "flops<=+2%"
 
 What ``slo`` recomputes, and from what:
 
@@ -242,16 +236,16 @@ def compare_slo(derived: Dict[str, Any], payload: Dict[str, Any], *,
     return errors
 
 
-def _pick_record(store_path: str, run_id: Optional[str],
-                 kind: str = "serve_load") -> Dict[str, Any]:
+def _pick_record(store_path: str, run_id: Optional[str]
+                 ) -> Dict[str, Any]:
     _ensure_repo_on_path()
     from singa_tpu.obs import record as obs_record
     entries = [e for e in obs_record.RunRecord(store_path).entries()
-               if e["kind"] == kind
+               if e["kind"] == "serve_load"
                and (run_id is None or e["run_id"] == run_id)]
     if not entries:
         raise LookupError(
-            f"no {kind} record"
+            f"no serve_load record"
             f"{f' with run_id {run_id!r}' if run_id else ''} in "
             f"{store_path}")
     return entries[-1]            # file order: newest append wins
@@ -350,7 +344,7 @@ _ASSERT_RE = re.compile(
 
 
 def assert_last(store_path: str, kind: str, spec: str) -> Optional[str]:
-    """CI trajectory tripwire (ISSUE 16): check the newest ``kind``
+    """CI trajectory tripwire: check the newest ``kind``
     record's relative change vs its predecessor against ``spec``
     ("field<=+X%" / "field>=-X%").  Returns the violation message or
     None — and None (trivially green) with fewer than two records,
@@ -361,7 +355,7 @@ def assert_last(store_path: str, kind: str, spec: str) -> Optional[str]:
     if not m:
         raise ValueError(
             f"--assert-last spec {spec!r} is not FIELD<=+X% / "
-            f"FIELD>=-X% (e.g. \"attributed_s<=+75%\")")
+            f"FIELD>=-X% (e.g. \"flops<=+2%\")")
     field, op, bound = m.group(1), m.group(2), float(m.group(3))
     _ensure_repo_on_path()
     from singa_tpu.obs import record as obs_record
@@ -386,55 +380,6 @@ def assert_last(store_path: str, kind: str, spec: str) -> Optional[str]:
             f"({old:.6g} -> {new:.6g}) vs bound {op}{bound:+g}% "
             f"(newest {entries[-1]['run_id']} vs "
             f"{entries[-2]['run_id']})")
-
-
-# ---------------------------------------------------------------------------
-# attr — the runtime-attribution table (ISSUE 16)
-# ---------------------------------------------------------------------------
-
-def attr_rows(payload: Dict[str, Any]
-              ) -> Tuple[List[str], List[List[Any]]]:
-    """(header, rows) of one ``perf_attr`` payload: per program the
-    dispatch count, p50/p99 in ms, the achieved-roofline fraction, and
-    the measured-vs-modeled slowdown (mean dispatch over the analytic
-    minimum at the nominal box — the reciprocal of the fraction, which
-    reads naturally as "Nx off the modeled roofline")."""
-    header = ["program", "count", "p50_ms", "p99_ms", "total_s",
-              "achieved_frac", "vs_model"]
-    rows: List[List[Any]] = []
-    for name in sorted(payload.get("programs", {})):
-        row = payload["programs"][name]
-        frac = row.get("achieved_flops_frac")
-        rows.append([
-            name, int(row["count"]),
-            round(float(row["p50_s"]) * 1e3, 3),
-            round(float(row["p99_s"]) * 1e3, 3),
-            round(float(row["total_s"]), 4),
-            round(float(frac), 6) if frac is not None else None,
-            (f"x{1.0 / frac:.1f}" if frac else "-"),
-        ])
-    return header, rows
-
-
-def _load_attr_payload(source: Optional[str],
-                       store_path: str) -> Tuple[str, Dict[str, Any]]:
-    """(label, payload) for the attr table: ``source`` is a payload
-    dump file (bench.py --serve --perf-attr) when it names an existing
-    .json, a run_id into the store otherwise; default is the store's
-    newest perf_attr record."""
-    if source and os.path.exists(source):
-        with open(source, encoding="utf-8") as f:
-            doc = json.load(f)
-        if isinstance(doc, dict) and "programs" not in doc \
-                and isinstance(doc.get("payload"), dict):
-            doc = doc["payload"]
-        if not isinstance(doc, dict) or "programs" not in doc:
-            raise ValueError(f"{source}: not a perf_attr payload")
-        return source, doc
-    entry = _pick_record(store_path, source, kind="perf_attr")
-    return (f"perf_attr {entry['run_id']} "
-            f"({os.path.basename(store_path)})",
-            entry.get("payload", {}))
 
 
 def incidents_rows(store_path: str, last: int = 20
@@ -586,16 +531,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                        default=os.path.join(_REPO, "runs",
                                             "records.jsonl"))
 
-    p_attr = sub.add_parser(
-        "attr", help="runtime-attribution table of a perf_attr record "
-                     "(default: newest in the store) or a payload dump "
-                     "from bench.py --serve --perf-attr")
-    p_attr.add_argument("source", nargs="?", default=None,
-                        help="payload dump .json file, or a run_id in "
-                             "the store (default: newest perf_attr)")
-    p_attr.add_argument("--records",
-                        default=os.path.join(_REPO, "runs",
-                                             "records.jsonl"))
     args = parser.parse_args(argv)
 
     try:
@@ -657,20 +592,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                       f"flight_ref back-link from "
                       f"{os.path.basename(args.records)}",
                       file=sys.stderr)
-            return 0
-        if args.cmd == "attr":
-            label, payload = _load_attr_payload(args.source,
-                                                args.records)
-            print(f"runtime attribution — {label}")
-            header, rows = attr_rows(payload)
-            print(_render_table(header, rows))
-            w = payload.get("window_s")
-            af = payload.get("attributed_frac")
-            if isinstance(w, (int, float)):
-                print(f"  window={w:.3f} s  attributed="
-                      f"{payload.get('attributed_s', 0.0):.3f} s"
-                      + (f"  ({100.0 * af:.1f}% of window)"
-                         if isinstance(af, (int, float)) else ""))
             return 0
     except (OSError, ValueError, LookupError) as e:
         print(f"obsq: {e}", file=sys.stderr)
