@@ -5,7 +5,8 @@ whole serving lifetime runs through exactly two compiled XLA programs.
 
 * :mod:`~singa_tpu.serve.slots` — :class:`BlockPool`, the PAGED
   KV-cache arena built on ``ops/kv_cache``: fixed-size blocks behind
-  per-request device-resident block tables, chain-hashed prefix-cache
+  per-request block tables (host numpy, like the per-slot pos/active
+  vectors: arguments of every dispatch), chain-hashed prefix-cache
   sharing with refcounts, and an evictable LRU of resident prefixes.
   Admit/evict/grow are pure index updates, freed blocks are reused
   without recompilation.  (The PR 2 fixed-slot ``SlotPool`` is gone —

@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 from typing import List, Optional
 
 import jax.numpy as jnp
+import numpy as np
 
 from ...ops import kv_cache as kv_ops
 from ..scheduler import RUNNING, Request
@@ -102,11 +103,11 @@ def extract(engine, slot: int) -> HandoffPackage:
         # per-layer list (target caches + draft caches — a pytree, so
         # the handoff program still has exactly one jit-cache entry),
         # split back host-side
-        both = engine._handoff(pool.tables, jnp.asarray(slot, jnp.int32),
+        both = engine._handoff(pool.tables_snapshot(), np.int32(slot),
                                pool.caches + pool.draft_caches)
         dense, draft_kv = both[:len(pool.caches)], both[len(pool.caches):]
     else:
-        dense = engine._handoff(pool.tables, jnp.asarray(slot, jnp.int32),
+        dense = engine._handoff(pool.tables_snapshot(), np.int32(slot),
                                 pool.caches)
         draft_kv = None
     keys = engine._req_keys(req)[:req.prompt.size // pool.block_size]

@@ -408,7 +408,8 @@ class ServeEngine:
                                     self.pool.max_blocks)
 
         self._running: Dict[int, Request] = {}      # slot -> request
-        # device-resident per-slot last tokens: written by prefill (the
+        # device-resident per-slot last tokens (the one piece of slot
+        # state that is: the programs chain it): written by prefill (the
         # request's first token) and decode (each next token); the host
         # only ever FETCHES this small int vector — tokens are never
         # uploaded, so the decode hot loop is one dispatch + one tiny
@@ -505,7 +506,6 @@ class ServeEngine:
             picked = jnp.argmax(logits.astype(jnp.float32),
                                 axis=-1).astype(jnp.int32)
             new_toks = jnp.where(active, picked, toks)
-            new_pos = jnp.where(active, pos + 1, pos)
             wb = jnp.take_along_axis(tables, (posc // bs)[:, None],
                                      axis=1)[:, 0]
             wb = jnp.where(active, wb, 0)
@@ -520,7 +520,9 @@ class ServeEngine:
                 v_tok = jax.vmap(row_at)(dv, posc)
                 new.append(kv_ops.scatter_token_kv(ck, cv, wb, off,
                                                    k_tok, v_tok))
-            return new_toks, new_pos, new
+            # ``pos`` is the host's, which adds the 1 itself
+            # (BlockPool.advance)
+            return new_toks, new
 
         def handoff_gather(tables, slot, caches):
             # the disaggregated tier's KV handoff source: ONE slot's
@@ -610,11 +612,11 @@ class ServeEngine:
         and the jit caches (:meth:`compiled_counts`) are untouched.
         The traced shapes are exactly the runtime dispatch shapes, so
         the audited modules ARE the serving modules."""
-        zero = jnp.asarray(0, jnp.int32)
+        zero = np.int32(0)
 
         def lower_prefill():
-            staged = (jnp.zeros((1, self._chunk), jnp.int32), zero,
-                      jnp.asarray(self._chunk - 1, jnp.int32), zero, zero)
+            staged = (np.zeros((1, self._chunk), np.int32), zero,
+                      np.int32(self._chunk - 1), zero, zero)
             if self._verify is not None:
                 return self._prefill.lower(
                     self._params, self._buffers, self._dparams,
@@ -1123,6 +1125,8 @@ class ServeEngine:
                 self.metrics.on_prefix_hit(start0)
             C = self._chunk
             view = self.pool.max_blocks * bs
+            # no row changes between this admission's chunks
+            tables = self.pool.tables_snapshot()
             with events.span("serve.prefill", slot=slot, prompt=P,
                              shared=start0, chunks=-(-(P - start0) // C)):
                 for fresh in range(start0, P, C):
@@ -1137,11 +1141,12 @@ class ServeEngine:
                         ids = np.zeros((1, C), np.int32)
                         chunk = replay[start:start + C]
                         ids[0, :chunk.size] = chunk
-                        staged = (jnp.asarray(ids),
-                                  jnp.asarray(start, jnp.int32),
-                                  jnp.asarray(chunk.size - 1, jnp.int32),
-                                  jnp.asarray(slot, jnp.int32),
-                                  jnp.asarray(fresh, jnp.int32))
+                        # host values of the programs' dtypes: the
+                        # dispatch itself transfers them, no eager
+                        # program stages anything
+                        staged = (ids, np.int32(start),
+                                  np.int32(chunk.size - 1),
+                                  np.int32(slot), np.int32(fresh))
                     with events.span("serve.prefill.dispatch"):
                         if self._verify is not None:
                             # spec engine: the ONE prefill program
@@ -1152,15 +1157,14 @@ class ServeEngine:
                                 "serve.prefill", self._prefill,
                                 (self._params, self._buffers,
                                  self._dparams, self._dbuffers, *staged,
-                                 self.pool.tables, self._toks,
+                                 tables, self._toks,
                                  self.pool.caches, self.pool.draft_caches),
                                 rid=req.rid)
                         else:
                             self._toks, self.pool.caches = self._dispatch(
                                 "serve.prefill", self._prefill,
                                 (self._params, self._buffers, *staged,
-                                 self.pool.tables, self._toks,
-                                 self.pool.caches),
+                                 tables, self._toks, self.pool.caches),
                                 rid=req.rid)
                         self.metrics.on_prefill_chunk(
                             start + chunk.size - fresh)
@@ -1270,18 +1274,17 @@ class ServeEngine:
         t0 = time.perf_counter()
         with events.span("serve.decode", active=len(self._running)):
             with events.span("serve.decode.dispatch"):
-                self._toks, new_pos, self.pool.caches = self._dispatch(
+                self._toks, self.pool.caches = self._dispatch(
                     "serve.decode", self._decode,
                     (self._params, self._buffers, self._toks,
-                     self.pool.pos, self.pool.active, self.pool.tables,
-                     self.pool.caches),
+                     *self.pool.snapshot(), self.pool.caches),
                     active=len(self._running))
+                self.pool.advance(1)
                 if self._moe_top_k:
                     self.metrics.on_moe_dispatch(
                         len(self._running) * self._moe_top_k)
             with events.span("serve.decode.fetch"):
                 toks = np.asarray(self._toks)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic
-        self.pool.pos = new_pos
         dt = time.perf_counter() - t0
         delivered = 0
         with events.span("serve.deliver"):

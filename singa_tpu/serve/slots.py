@@ -7,15 +7,28 @@ arena is now PAGED (the vLLM design, expressed as fixed-shape XLA
 gathers): the per-layer cache is a pool of ``num_blocks`` fixed-size
 blocks of ``block_size`` tokens — ``(num_blocks, block_size, K, D)``
 buffers — and each request maps the blocks its length actually needs
-through a device-resident ``(num_slots, max_blocks)`` int32 **block
-table**.  The engine's two compiled programs never see physical block
-identities as shapes: prefill/decode gather a request's dense view with
+through a ``(num_slots, max_blocks)`` int32 **block table**.  The
+engine's two compiled programs never see physical block identities as
+shapes: prefill/decode gather a request's dense view with
 ``ops.kv_cache.gather_block_kv`` (a ``jnp.take`` over the table row)
 and scatter written positions back with ``scatter_block_kv`` /
 ``scatter_token_kv``, so admitting, growing, evicting and re-mapping
 requests are pure index updates — the same single-compiled-module
 discipline the fixed arena had, with memory proportional to live
 tokens instead of live slots.
+
+**Who owns the slot state.**  Only the block pools live on the device.
+The block tables and the per-slot ``pos`` / ``active`` vectors are HOST
+``numpy`` arrays that this class edits in place: every value in them is something the host decided —
+a row is ``_mapped[slot]`` zero-padded, ``pos[slot]`` the slot's valid
+cache positions, ``active[slot]`` whether a request runs there — so an
+update is a store, never a device program.  The compiled programs take
+them as arguments of a fixed shape and dtype, so a step's device work
+is its dispatches and one token fetch.  A dispatch is asynchronous and
+may read a host argument after the call returns (the CPU backend
+aliases an aligned ``numpy`` buffer instead of copying it), so a
+program is handed :meth:`snapshot` / :meth:`tables_snapshot` — copies
+nobody edits — and never these arrays themselves.
 
 **Prefix-cache sharing** rides the block pool: every FULL prompt block
 gets a chain hash key (blake2b over the block's tokens and its
@@ -92,10 +105,11 @@ class BlockPool:
     block-table rows.
 
     Host side: slot free list, block free list, per-block refcounts,
-    the prefix cache (chain key -> block) and the evictable LRU.
-    Device side: the per-layer block pools, the ``(num_slots,
-    max_blocks)`` block tables, and the per-slot ``pos``/``active``
-    vectors the decode program consumes.
+    the prefix cache (chain key -> block), the evictable LRU, and the
+    slot state the programs take as arguments — the ``(num_slots,
+    max_blocks)`` block tables and the per-slot ``pos``/``active``
+    vectors (``numpy``; see the module docstring).  Device side: the
+    per-layer block pools.
     """
 
     def __init__(self, model, num_slots: int, max_len: int, *,
@@ -177,9 +191,10 @@ class BlockPool:
                 lambda: draft_model.init_caches(num_blocks, block_size))
             self.draft_caches = jax.tree.map(
                 lambda s: jnp.zeros(s.shape, dtype), spec)
-        self.tables = jnp.zeros((num_slots, self.max_blocks), jnp.int32)
-        self.pos = jnp.zeros((num_slots,), jnp.int32)
-        self.active = jnp.zeros((num_slots,), bool)
+        # an unmapped slot's row is all null block, its pos 0
+        self.tables = np.zeros((num_slots, self.max_blocks), np.int32)
+        self.pos = np.zeros((num_slots,), np.int32)
+        self.active = np.zeros((num_slots,), bool)
         # LIFO reuse: the most recently freed slot/block is re-used
         # first (hottest in the HBM/cache hierarchy)
         self._free_slots: List[int] = list(range(num_slots - 1, -1, -1))
@@ -459,12 +474,6 @@ class BlockPool:
             self._key_of[block] = key
 
     # -- slot mapping ------------------------------------------------------
-    def _sync_table_row(self, slot: int) -> None:
-        row = np.zeros((self.max_blocks,), np.int32)
-        mapped = self._mapped[slot]
-        row[:len(mapped)] = mapped
-        self.tables = self.tables.at[slot].set(jnp.asarray(row))
-
     def map_slot(self, slot: int, blocks: List[int]) -> None:
         """Install ``blocks`` (shared prefix + freshly allocated, in
         logical order) as the slot's block table.  Shared blocks arrive
@@ -479,23 +488,27 @@ class BlockPool:
         for b in blocks:
             if self.ref[b] == 0:
                 self.ref[b] = 1
-        self._sync_table_row(slot)
+        # the rest of the row is already null: release() left it so
+        self.tables[slot, :len(blocks)] = blocks
 
     def append_block(self, slot: int, block: int) -> None:
         """Decode-time growth: one more block for a slot whose next
         token crosses a block boundary."""
-        if len(self._mapped[slot]) >= self.max_blocks:
+        n = len(self._mapped[slot])
+        if n >= self.max_blocks:
             raise ValueError(f"slot {slot} already at max_blocks")
         self._mapped[slot].append(block)
         self.ref[block] = 1
-        self._sync_table_row(slot)
+        self.tables[slot, n] = block
 
     def release(self, slot: int) -> None:
         """Return the slot row to the free list and drop one reference
         from every block it mapped: keyed blocks park in the evictable
         LRU (content intact for the next prefix hit), unkeyed ones are
-        freed.  Device-side cache rows are never scrubbed — stale
-        blocks are unreachable past every reader's validity window."""
+        freed.  The row goes back to all null block, so an inactive
+        slot's gather reads block 0 and nothing another request owns.
+        Device-side cache rows are never scrubbed — stale blocks are
+        unreachable past every reader's validity window."""
         if slot in self._free_slots:
             raise ValueError(f"slot {slot} double-freed")
         for b in self._mapped[slot]:
@@ -508,18 +521,34 @@ class BlockPool:
                 else:
                     self._free_blocks.append(b)
         self._mapped[slot] = []
-        self.active = self.active.at[slot].set(False)
-        self.pos = self.pos.at[slot].set(0)
+        self.tables[slot] = 0
+        self.active[slot] = False
+        self.pos[slot] = 0
         self._free_slots.append(slot)
 
-    # -- device-side state transitions -----------------------------------
     def activate(self, slot: int, length: int) -> None:
         """Mark ``slot`` live with ``length`` valid cache positions
         (called after its prompt chunks were prefilled into its
         blocks)."""
-        self.pos = self.pos.at[slot].set(length)
-        self.active = self.active.at[slot].set(True)
+        self.pos[slot] = length
+        self.active[slot] = True
 
-    def positions(self):
-        """Host copy of per-slot positions (np.ndarray view)."""
-        return np.asarray(self.pos)
+    def advance(self, written) -> None:
+        """The tick just dispatched wrote ``written`` more positions
+        (1 for a decode tick; a per-slot vector, ``accepted + 1``, for
+        a verify round) into every ACTIVE slot's cache."""
+        self.pos += np.where(self.active, written, 0)
+
+    # -- what a dispatch is handed ----------------------------------------
+    def tables_snapshot(self) -> np.ndarray:
+        """The block tables as ONE dispatch reads them: a copy nobody
+        edits, because the program may read its host arguments after
+        the call returns (module docstring) while the next admission,
+        growth or release already stores into :attr:`tables`."""
+        return self.tables.copy()
+
+    def snapshot(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(pos, active, tables)`` for one decode or verify dispatch,
+        in the programs' argument order — copies, for the reason
+        :meth:`tables_snapshot` gives."""
+        return self.pos.copy(), self.active.copy(), self.tables_snapshot()

@@ -31,7 +31,8 @@ How one verify round works (all of it inside the single compiled
    bitwise identical to ``generate()`` *by construction* — the draft
    can only change HOW MANY target picks one dispatch yields, never
    their values.  Rejected positions are rolled back by TRUNCATING the
-   slot's position/attention limit (``new_pos = pos + a + 1``): the
+   slot's position/attention limit (the host advances its ``pos`` by
+   ``a + 1``, not ``k + 1``): the
    stale KV past the new limit is unreachable (masked by every
    reader's validity window) and is overwritten by the next round —
    no arena reshape, no scrubbing, no per-``k`` program.
@@ -105,8 +106,8 @@ def scatter_chunk(row, pos, fresh, caches, dense, block_size, chunk):
     are written too, whatever ``last_idx`` says: into the slot's own
     later blocks, where decode overwrites each position before any mask
     admits it, or, past the mapped part of the row, into the null block
-    (``BlockPool._sync_table_row`` zeroes the rest of a row).  So only
-    scatter through a row that ``map_slot`` has just installed.  The
+    (``BlockPool`` keeps the rest of a row zero).  So only scatter
+    through a row that ``map_slot`` has just installed.  The
     caller keeps ``[pos, pos + chunk)`` inside the view:
     ``dynamic_slice`` would clamp a crossing chunk silently and send
     K/V to the wrong positions."""
@@ -164,13 +165,14 @@ def make_spec_prefill(model, draft, block_size: int, chunk: int):
 def make_verify(model, draft, spec_k: int, block_size: int):
     """Build the verify program's closure (see the module docstring for
     the three phases).  Returns
-    ``(accepted, cand, new_toks, new_pos, caches, dcaches)`` where
+    ``(accepted, cand, new_toks, caches, dcaches)`` where
     ``accepted`` is the per-slot count of accepted PROPOSALS (0..k) and
     ``cand`` is the (num_slots, k+1) matrix of the target's greedy
-    picks — the host delivers ``cand[slot, :accepted+1]``.  Inactive
+    picks — the host delivers ``cand[slot, :accepted+1]`` and advances
+    the slot's ``pos`` by ``accepted + 1``.  Inactive
     slots are masked exactly like plain decode: positions clamped to 0,
-    every window write redirected to the null block, token entries and
-    positions frozen."""
+    every window write redirected to the null block, token entries
+    frozen."""
     k, bs = spec_k, block_size
     dec_d = decode_step(draft)
     res_t = resume_step(model)
@@ -229,11 +231,8 @@ def make_verify(model, draft, spec_k: int, block_size: int):
         acc = jnp.cumprod(match, axis=1).sum(axis=1)           # (S,) 0..k
         new_tok = jnp.take_along_axis(cand, acc[:, None], axis=1)[:, 0]
         new_toks = jnp.where(active, new_tok, toks)
-        # rollback IS this truncation: rejected positions stay written
-        # but sit past the new limit, unreachable and overwritten next
-        new_pos = jnp.where(active, posc + acc + 1, pos)
         acc = jnp.where(active, acc, 0)
-        return acc, cand, new_toks, new_pos, new_t, new_d
+        return acc, cand, new_toks, new_t, new_d
 
     return verify
 
@@ -253,8 +252,7 @@ def verify_round(engine) -> int:
             out = engine._dispatch(
                 "serve.verify", engine._verify,
                 (engine._params, engine._buffers, engine._dparams,
-                 engine._dbuffers, engine._toks, engine.pool.pos,
-                 engine.pool.active, engine.pool.tables,
+                 engine._dbuffers, engine._toks, *engine.pool.snapshot(),
                  engine.pool.caches, engine.pool.draft_caches),
                 active=len(engine._running))
         except (RuntimeError, OSError) as e:
@@ -265,11 +263,14 @@ def verify_round(engine) -> int:
             # failures must escalate (see VerifyDispatchFailed)
             raise VerifyDispatchFailed(
                 f"{type(e).__name__}: {e}") from e
-        (acc_v, cand_v, engine._toks, new_pos, engine.pool.caches,
+        (acc_v, cand_v, engine._toks, engine.pool.caches,
          engine.pool.draft_caches) = out
         acc = np.asarray(acc_v)    # singalint: disable=SGL008 the designed per-tick sync: one (S,) + one (S, k+1) int fetch commits a whole verify round
         cand = np.asarray(cand_v)
-    engine.pool.pos = new_pos
+    # rollback IS this truncation: the window's k+1 positions are all
+    # written, the slot's limit moves past the accepted ones only, and
+    # the rest sit beyond it, unreachable and overwritten next round
+    engine.pool.advance(acc + 1)
     dt = time.perf_counter() - t0
     delivered = 0
     for slot in list(engine._running):

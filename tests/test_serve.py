@@ -792,6 +792,164 @@ class TestPagedGatherHasNoFill:
         assert {0, eng.pool.num_blocks - 1} <= seen
 
 
+def _engine_of(kind, model, **kw):
+    """A plain engine, or one that speculates on itself (every proposal
+    accepted: a verify round moves a slot by ``spec_k + 1``)."""
+    if kind == "speculative":
+        kw.update(draft_model=model, spec_k=2)
+    return ServeEngine(model, **kw)
+
+
+def _check_slot_state(eng):
+    """The slot state is the host's, and says what the host knows: a
+    row is the slot's mapped blocks over null blocks, ``pos`` the
+    positions its cache holds (the last token delivered is the next
+    dispatch's input, not cached yet), ``active`` whether a request
+    runs there."""
+    pool, running = eng.pool, dict(eng.running_items())
+    for arr, dtype in ((pool.tables, np.int32), (pool.pos, np.int32),
+                       (pool.active, np.bool_)):
+        assert type(arr) is np.ndarray and arr.dtype == dtype
+    for slot in range(pool.num_slots):
+        req = running.get(slot)
+        row = np.zeros((pool.max_blocks,), np.int32)
+        mapped = pool.mapped_blocks(slot)
+        row[:len(mapped)] = mapped
+        np.testing.assert_array_equal(pool.tables[slot], row)
+        assert pool.active[slot] == (req is not None)
+        assert pool.pos[slot] == (
+            0 if req is None else req.prompt.size + len(req.tokens) - 1)
+
+
+class TestSlotStateOnTheHost:
+    """ISSUE 31: `BlockPool.tables` / `pos` / `active` are numpy the
+    host edits and hands to the programs; a step's device work is its
+    dispatches and one fetch."""
+
+    @pytest.mark.parametrize("event", ["preemption", "recover"])
+    @pytest.mark.parametrize("kind", ["plain", "speculative"])
+    def test_state_follows_the_requests_after_every_step(self, llama,
+                                                         kind, event):
+        """Admissions, block growth, finishes of unequal lengths and a
+        preemption (the pool is two blocks short) or a `recover()`
+        mid-run: after each step the three arrays are what the
+        requests say, and the streams are `generate()`'s."""
+        eng = _engine_of(kind, llama, num_slots=2, max_len=32,
+                         block_size=8,
+                         num_blocks=6 if event == "preemption" else None)
+        prompts = _prompts(3, [7, 7, 5], seed=37)
+        new = [16, 14, 9]
+        refs = [llama.generate(p[None], max_new_tokens=n)[0, p.size:]
+                for p, n in zip(prompts, new)]
+        hs = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+        steps = 0
+        while eng.pending:
+            eng.step()
+            _check_slot_state(eng)
+            steps += 1
+            if event == "recover" and steps == 3:
+                eng.recover("test")
+                _check_slot_state(eng)
+                assert not eng.pool.active.any()
+        for ref, h in zip(refs, hs):
+            np.testing.assert_array_equal(ref, np.asarray(h.tokens))
+        if event == "preemption":
+            assert eng.metrics.preempted >= 1
+        else:
+            assert eng.metrics.recoveries == 1
+        assert not eng.pool.tables.any() and not eng.pool.pos.any()
+
+    @pytest.mark.parametrize("kind", ["plain", "speculative"])
+    def test_a_dispatch_in_flight_never_sees_later_edits(self, llama, kind):
+        """A dispatch returns before its program ran, and the CPU
+        backend reads a 64-byte-aligned numpy argument in place: so a
+        program must get arrays nobody edits.  Here every dispatch is
+        followed at once by the worst the next tick could store into
+        the pool's arrays (all of them, aligned for the purpose), and
+        the streams are still `generate()`'s."""
+        import jax
+        eng = _engine_of(kind, llama, num_slots=2, max_len=32, block_size=8)
+        pool = eng.pool
+        live = {}
+        for name in ("tables", "pos", "active"):
+            arr = getattr(pool, name)
+            raw = np.zeros((arr.nbytes + 64,), np.uint8)
+            off = -raw.ctypes.data % 64
+            live[name] = raw[off:off + arr.nbytes].view(arr.dtype) \
+                .reshape(arr.shape)
+            assert live[name].ctypes.data % 64 == 0
+            setattr(pool, name, live[name])
+
+        def edited_behind(program):
+            def dispatch(*args):
+                for a in args:
+                    if isinstance(a, np.ndarray):
+                        assert not any(np.shares_memory(a, b)
+                                       for b in live.values())
+                out = program(*args)
+                kept = {n: a.copy() for n, a in live.items()}
+                live["tables"][:] = pool.num_blocks - 1
+                live["pos"][:] = 3
+                live["active"][:] = ~kept["active"]
+                jax.block_until_ready(out)
+                for n, a in kept.items():
+                    live[n][:] = a
+                return out
+            return dispatch
+
+        eng._prefill = edited_behind(eng._prefill)
+        eng._decode = edited_behind(eng._decode)
+        if eng._verify is not None:
+            eng._verify = edited_behind(eng._verify)
+        prompts = _prompts(3, [7, 12, 5], seed=41)
+        refs = [llama.generate(p[None], max_new_tokens=12)[0, p.size:]
+                for p in prompts]
+        hs = [eng.submit(p, max_new_tokens=12) for p in prompts]
+        while eng.pending:
+            eng.step()      # admits, then grows, right behind a dispatch
+            _check_slot_state(eng)
+        for ref, h in zip(refs, hs):
+            np.testing.assert_array_equal(ref, np.asarray(h.tokens))
+
+    @pytest.mark.parametrize("kind,programs", [
+        ("plain", {"jit(prefill_chunk)", "jit(decode_paged)"}),
+        ("speculative", {"jit(prefill_chunk_spec)", "jit(verify)"})])
+    def test_a_step_compiles_its_programs_and_nothing_else(self, llama,
+                                                           kind, programs):
+        """With every cache of jax cleared, an admission with a prefix
+        hit, ticks across a block boundary and a finish build the
+        engine's programs and no other: no `scatter`,
+        `convert_element_type` or `squeeze` of an eager
+        `x.at[slot].set(...)`, no staging of an argument."""
+        import jax
+        from jax import monitoring
+        eng = _engine_of(kind, llama, num_slots=2, max_len=32, block_size=8)
+        shared = _prompts(1, [8], seed=43)[0]
+        first, second = (np.concatenate([shared, t])
+                         for t in _prompts(2, [3, 5], seed=44))
+        ref = llama.generate(second[None], max_new_tokens=10)[0, second.size:]
+        eng.submit(first, max_new_tokens=2)
+        eng.run_until_idle()            # the shared block is keyed now
+        built = []
+
+        def on_compile(event, secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                built.append(kw.get("fun_name"))
+
+        jax.clear_caches()
+        monitoring.register_event_duration_secs_listener(on_compile)
+        try:
+            hits = eng.metrics.snapshot()["prefix_hit_tokens"]
+            h = eng.submit(second, max_new_tokens=10)
+            eng.run_until_idle()        # positions 13..22 pass 16
+        finally:
+            monitoring.unregister_event_duration_listener(on_compile)
+        assert eng.metrics.snapshot()["prefix_hit_tokens"] == hits + 8
+        np.testing.assert_array_equal(ref, np.asarray(h.tokens))
+        assert h.finish_reason == "length"
+        assert set(built) == programs and len(built) == 2
+
+
 def test_loadgen_quick_run_emits_valid_record(llama, engine, tmp_path):
     """tools/loadgen.py end-to-end against the shared engine: an
     open-loop burst completes, every request is accounted for
