@@ -37,8 +37,8 @@ __all__ = [
     "MaxPool2d", "AvgPool2d", "GlobalAvgPool2d", "Flatten", "ReLU",
     "Sigmoid", "Tanh", "Gelu", "SiLU", "LeakyReLU", "Softmax", "Dropout",
     "Embedding", "LayerNorm", "RMSNorm", "RNN", "LSTM",
-    "MultiHeadAttention", "MoE", "Remat", "PipelineStack", "Sequential",
-    "CrossEntropyLoss", "MSELoss",
+    "MultiHeadAttention", "MoE", "MLPRouter", "Remat", "PipelineStack",
+    "Sequential", "CrossEntropyLoss", "MSELoss",
 ]
 
 _name_counter: Dict[str, int] = {}
@@ -677,17 +677,25 @@ class MultiHeadAttention(Layer):
 
 class _MoEOp(autograd.Operator):
     def __init__(self, cf, top_k=1, swiglu=False, dispatch_mode="auto",
-                 dropless=False):
+                 dropless=False, router_fn=None, n_router=1):
         super().__init__()
         self.cf = cf
         self.top_k = top_k
         self.swiglu = swiglu
         self.dispatch_mode = dispatch_mode
         self.dropless = dropless
+        # None: the one router array is the (D, E) matrix of a linear
+        # router; else the function of (rows, *router arrays) that
+        # yields the logits (a router sub-module's `logits`)
+        self.router_fn = router_fn
+        self.n_router = n_router
 
-    def fwd(self, xa, rw, wi, wo, *wg):
+    def fwd(self, xa, *ws):
         from .ops.moe import moe_forward
-        out, aux = moe_forward(xa, rw, wi, wo, self.cf, return_aux=True,
+        rw, (wi, wo, *wg) = ws[:self.n_router], ws[self.n_router:]
+        router = rw[0] if self.router_fn is None else \
+            (lambda xf: self.router_fn(xf, *rw))
+        out, aux = moe_forward(xa, router, wi, wo, self.cf, return_aux=True,
                                top_k=self.top_k,
                                w_gate=wg[0] if self.swiglu else None,
                                dispatch_mode=self.dispatch_mode,
@@ -695,12 +703,66 @@ class _MoEOp(autograd.Operator):
         return out, aux
 
 
+class _RouterLogitsOp(autograd.Operator):
+    def fwd(self, xa, *ws):
+        return MLPRouter.logits(xa.reshape(-1, xa.shape[-1]), *ws)
+
+
+class MLPRouter(Layer):
+    """The router of a mixture-of-experts layer as a small MLP: the
+    rows projected down to `hidden`, two gelu layers with biases at
+    that width, one logit an expert (ops/moe.py::mlp_router_logits).
+    Hand it to `MoE(router=)`; called on its own it yields the (N, E)
+    f32 logits of its input's rows.  Its weights stay f32 masters, as a
+    linear router's matrix does: routing is computed in f32."""
+
+    def __init__(self, num_experts: int, hidden: int, name=None):
+        super().__init__(name)
+        self.num_experts, self.hidden = num_experts, hidden
+
+    def initialize(self, x: Tensor):
+        d, h, e = x.shape[-1], self.hidden, self.num_experts
+        dev = x.device
+        self.down = self.register_param(
+            "down", _xavier_uniform((d, h), d, h, dev))
+        self.w1 = self.register_param("w1", _xavier_uniform((h, h), h, h, dev))
+        # biases start off non-zero so that the random weights of a test
+        # or a benchmark run exercise them
+        self.b1 = self.register_param(
+            "b1", Tensor((h,), dev, np.float32).gaussian(0.0, 0.02))
+        self.w2 = self.register_param("w2", _xavier_uniform((h, h), h, h, dev))
+        self.b2 = self.register_param(
+            "b2", Tensor((h,), dev, np.float32).gaussian(0.0, 0.02))
+        self.w3 = self.register_param("w3", _xavier_uniform((h, e), h, e, dev))
+
+    def weights(self):
+        """The tensors `logits` takes after the rows."""
+        return (self.down, self.w1, self.b1, self.w2, self.b2, self.w3)
+
+    @staticmethod
+    def logits(xf, *ws):
+        """(N, D) rows and `weights()`' arrays -> (N, E) f32 logits."""
+        from .ops.moe import mlp_router_logits
+        return mlp_router_logits(xf, *ws)
+
+    def forward(self, x: Tensor) -> Tensor:
+        return _RouterLogitsOp()(x, *self.weights())
+
+
 class MoE(Layer):
-    """Top-1 mixture-of-experts FFN (ops/moe.py — GShard/Switch static
-    dispatch).  Stacked expert weights carry a leading E axis; the
-    layer declares SHARD_RULES sharding it over the 'expert' mesh axis
-    (the executor merges sublayer rules, so models need not repeat
-    them) — with EP the dispatch/combine einsums become all-to-alls.
+    """Top-`top_k` mixture-of-experts FFN (ops/moe.py — GShard/Switch
+    static dispatch, or `dropless`: every assignment computed).
+    Stacked expert weights carry a leading E axis; the layer declares
+    SHARD_RULES sharding it over the 'expert' mesh axis (the executor
+    merges sublayer rules, so models need not repeat them) — with EP
+    the dispatch/combine einsums become all-to-alls.
+
+    The router yields the (N, E) logits the gates are taken from.
+    `router=None`: a linear router, the (D, E) parameter `router` of
+    this layer (the path checkpoints and `models/convert.py` know).
+    `router=MLPRouter(...)`: that sub-module, its parameters under
+    `router.*`.  A top-1 gate is the chosen expert's softmax
+    probability, not renormalised; k > 1 renormalises over the k.
 
     The router's load-balance auxiliary losses accumulate across
     *training-mode* calls (eval and compile-time dry runs don't
@@ -719,8 +781,13 @@ class MoE(Layer):
     def __init__(self, num_experts: int, ffn_dim: int,
                  capacity_factor: float = 1.25, top_k: int = 1,
                  act: str = "relu", dispatch_mode: str = "auto",
-                 dropless: bool = False, name=None):
+                 dropless: bool = False, router: Optional[Layer] = None,
+                 name=None):
         super().__init__(name)
+        if router is not None and router.num_experts != num_experts:
+            raise ValueError(
+                f"router yields {router.num_experts} logits for "
+                f"{num_experts} experts")
         if not 1 <= top_k <= num_experts:
             raise ValueError(
                 f"top_k={top_k} outside [1, num_experts={num_experts}]")
@@ -741,14 +808,23 @@ class MoE(Layer):
         # exact top-k with every assignment computed (ops/moe.py): the
         # serving form; capacity_factor and dispatch_mode then do nothing
         self.dropless = dropless
+        self._mlp_router = router is not None
+        if router is not None:
+            self.router = router
         self._aux_losses: List[Tensor] = []
 
     def initialize(self, x: Tensor):
         d = x.shape[-1]
         e, h = self.num_experts, self.ffn_dim
         dev = x.device
-        self.router = self.register_param(
-            "router", _xavier_uniform((d, e), d, e, dev))
+        if self._mlp_router:
+            # the forward hands its weights to the fused op and never
+            # calls it, so it is initialised here
+            self.router.initialize(x)
+            self.router._initialized = True
+        else:
+            self.router = self.register_param(
+                "router", _xavier_uniform((d, e), d, e, dev))
         self.w_in = self.register_param(
             "w_in", Tensor((e, d, h), dev, np.float32).gaussian(
                 0.0, (2.0 / (d + h)) ** 0.5))
@@ -763,10 +839,14 @@ class MoE(Layer):
     def forward(self, x: Tensor) -> Tensor:
         # router stays f32 master: moe_forward computes routing in f32
         extra = (self.w_gate,) if self.act == "swiglu" else ()
+        if self._mlp_router:
+            rw, fn = self.router.weights(), self.router.logits
+        else:
+            rw, fn = (self.router,), None
         out, aux = _MoEOp(self.capacity_factor, self.top_k,
                           self.act == "swiglu", self.dispatch_mode,
-                          self.dropless)(
-            x, self.router, self.w_in, self.w_out, *extra)
+                          self.dropless, fn, len(rw))(
+            x, *rw, self.w_in, self.w_out, *extra)
         # accumulate only in training: eval/compile-time dry runs must
         # not leave stale entries (an init-trace tracer here would crash
         # the first real pop_aux_loss)

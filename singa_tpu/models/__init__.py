@@ -11,6 +11,9 @@ Families:
   * transformer  — GPT-2 and BERT (BASELINE.json:9)
   * llama        — Llama-3 family, the flagship stretch config
                    (BASELINE.json:11): RMSNorm, RoPE, SwiGLU, GQA
+  * zaya         — ZAYA1: attention in a compressed latent with
+                   convolutional mixing (CCA), top-1 experts behind an
+                   MLP router, tied head; inference only
 
 Every model is a singa_tpu.model.Model: imperative forward, trains
 eagerly or as one compiled XLA module, shards over a mesh via the
@@ -23,6 +26,7 @@ from . import resnet
 from . import vgg
 from . import transformer
 from . import llama
+from . import zaya
 
 from .mlp import MLP
 from .cnn import CNN, LeNet5, AlexNet
@@ -31,17 +35,18 @@ from .resnet import (ResNet, resnet18, resnet34, resnet50, resnet101,
 from .vgg import VGG, vgg11, vgg13, vgg16, vgg19
 from .transformer import GPT2, BERT, GPT2Config, BERTConfig
 from .llama import Llama, LlamaConfig
+from .zaya import Zaya, ZayaConfig
 from .convert import (from_hf, from_hf_bert, from_hf_gpt2,
                       from_hf_llama, from_hf_mistral,
                       from_hf_mixtral, to_hf)
 
 __all__ = [
-    "mlp", "cnn", "resnet", "vgg", "transformer", "llama",
+    "mlp", "cnn", "resnet", "vgg", "transformer", "llama", "zaya",
     "MLP", "CNN", "LeNet5", "AlexNet",
     "ResNet", "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
     "VGG", "vgg11", "vgg13", "vgg16", "vgg19",
     "GPT2", "BERT", "GPT2Config", "BERTConfig",
-    "Llama", "LlamaConfig",
+    "Llama", "LlamaConfig", "Zaya", "ZayaConfig",
     "from_hf", "from_hf_bert", "from_hf_gpt2", "from_hf_llama",
     "from_hf_mistral", "from_hf_mixtral",
     "to_hf",

@@ -109,14 +109,20 @@ def resume_step(model, device=None):
     model's).  Its ``default_dtype`` is the dtype the embedding casts
     the activations to, and every layer follows them: a device made
     with ``default_dtype=float32`` runs f32 weights in f32 on a TPU,
-    where the model's own device would compute in bf16."""
+    where the model's own device would compute in bf16.
 
-    def resume(params, buffers, ids, pos, caches):
+    ``state_rows`` (an int32 vector of rows of the chunk), for a model
+    whose layers keep side state beside their KV cache
+    (``models/zaya.py``): the returned caches then hold that state as it
+    stood after each of those rows, not after the chunk's last."""
+
+    def resume(params, buffers, ids, pos, caches, state_rows=None):
+        rows = {} if state_rows is None else {"state_rows": state_rows}
         with _bound(model, params, buffers):
             t = Tensor(data=ids, device=device or _dev(model),
                        requires_grad=False)
             logits, caches = model.forward_cached(t, caches=caches,
-                                                  pos=pos)
+                                                  pos=pos, **rows)
         return logits.data, caches
 
     return resume

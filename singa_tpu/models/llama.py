@@ -3,9 +3,9 @@
 
 Architecture: pre-RMSNorm decoder blocks, rotary position embeddings,
 grouped-query attention (n_kv_heads < n_heads), SwiGLU FFN, untied LM
-head — all expressed through singa_tpu.autograd operators so the whole
-training step (fwd + bwd + optim + collectives) compiles into one XLA
-module.
+head (a head tied to the embedding is `models/zaya.py`'s) — all
+expressed through singa_tpu.autograd operators so the whole training
+step (fwd + bwd + optim + collectives) compiles into one XLA module.
 
 Scaling design (task directive: multi-chip via jax.sharding.Mesh):
 SHARD_RULES gives 2-D parallelism out of the box —

@@ -5,8 +5,10 @@ strategy is DP) — this is the TPU-native extension that completes the
 framework's parallelism axes (dp/tp/sp/pp/ep).  Formulation follows the
 GShard/Switch static-shape recipe, which is what XLA partitions well:
 
-  * router: (N, D) -> (N, E) logits -> top-1 gate with a static expert
-    capacity C = ceil(cf * N / E);
+  * router: (N, D) -> (N, E) logits -> top-k gate with a static expert
+    capacity C = ceil(cf * N / E).  The logits come from the `router`
+    argument: a (D, E) matrix (a linear router) or a function of the
+    rows (`mlp_router_logits` behind `layer.MLPRouter`);
   * dispatch: two equivalent token-movement formulations sharing one
     router (`_route`): gather/SCATTER into the (E, C, D) buffers
     (O(k*N*D) memory ops — the single-chip default; the one-hot
@@ -33,7 +35,34 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["moe_dispatch", "moe_forward", "load_balance_loss"]
+__all__ = ["moe_dispatch", "moe_forward", "load_balance_loss",
+           "mlp_router_logits"]
+
+
+def _router_logits(xf, router, precision=None):
+    """(N, E) f32 routing logits of the rows `xf`.  `router` is the
+    (D, E) matrix of a linear router, or a function of the rows that
+    yields the logits itself (an MLP router)."""
+    if callable(router):
+        return router(xf)
+    return jnp.matmul(xf.astype(jnp.float32), router.astype(jnp.float32),
+                      precision=precision)
+
+
+def mlp_router_logits(xf, w_down, w1, b1, w2, b2, w3):
+    """Routing logits of an MLP router: the rows projected down to the
+    router's width, two gelu layers with biases there, then one logit
+    an expert: `w3 gelu(w2 gelu(w1 (x w_down) + b1) + b2)`.  f32 at
+    "highest", as the linear router's logits are: a top-1 pick between
+    near ties must not hang on a bf16 product."""
+    hi = dict(precision=jax.lax.Precision.HIGHEST)
+    f32 = lambda a: a.astype(jnp.float32)
+    s = jnp.matmul(f32(xf), f32(w_down), **hi)
+    s = jax.nn.gelu(jnp.matmul(s, f32(w1), **hi) + f32(b1),
+                    approximate=False)
+    s = jax.nn.gelu(jnp.matmul(s, f32(w2), **hi) + f32(b2),
+                    approximate=False)
+    return jnp.matmul(s, f32(w3), **hi)
 
 
 def moe_dispatch(logits, capacity: int, k: int = 1):
@@ -69,7 +98,9 @@ def load_balance_loss(probs, onehot):
 
 def _topk_gates(logits, k: int):
     """(probs (N, E) f32, topi (N, k), gates (N, k)): softmax in f32, the
-    k largest, their weights renormalised to sum 1 when k > 1."""
+    k largest, their weights renormalised to sum 1 when k > 1.  A top-1
+    gate is the chosen expert's probability itself (renormalised it
+    would be the constant 1, and the router would get no gradient)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     topv, topi = jax.lax.top_k(probs, k)
     gates = topv if k == 1 else \
@@ -128,7 +159,7 @@ def _expert_ffn(buf, w_in, w_out, w_gate):
     return jnp.einsum("ech,ehd->ecd", h, w_out.astype(buf.dtype))
 
 
-def _moe_dropless(xf, router_w, w_in, w_out, w_gate, top_k):
+def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k):
     """(N, D) rows -> ((N, D) f32, balance loss): exact top-k, every
     assignment computed: every expert over every row, the unrouted ones
     weighted 0.  No buffer, no capacity, row n's result a function of
@@ -141,11 +172,9 @@ def _moe_dropless(xf, router_w, w_in, w_out, w_gate, top_k):
     scatter path at capacity = N and 4.69 ms for a sort and
     `jax.lax.ragged_dot` (PERF.md, PR 28).  At training's row counts it
     multiplies E / k times too many rows: that path keeps capacity."""
-    N, E = xf.shape[0], router_w.shape[-1]
+    N, E = xf.shape[0], w_in.shape[0]
     with jax.named_scope("moe.route"):
-        logits = jnp.matmul(xf.astype(jnp.float32),
-                            router_w.astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST)
+        logits = _router_logits(xf, router, jax.lax.Precision.HIGHEST)
         probs, topi, gates = _topk_gates(logits, top_k)
         w = jnp.zeros((N, E), jnp.float32).at[
             jnp.arange(N)[:, None], topi].set(gates)   # (N, E), k nonzero
@@ -165,12 +194,13 @@ def _moe_dropless(xf, router_w, w_in, w_out, w_gate, top_k):
     return out, load_balance_loss(probs, onehot)
 
 
-def moe_forward(x, router_w, w_in, w_out, capacity_factor: float = 1.25,
+def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
                 return_aux: bool = False, top_k: int = 1, w_gate=None,
                 dispatch_mode: str = "auto", dropless: bool = False):
     """Top-k MoE FFN over flattened tokens (k=1 Switch, k=2 GShard).
 
-    x: (..., D); router_w: (D, E); w_in: (E, D, H); w_out: (E, H, D).
+    x: (..., D); router: (D, E), or a function (N, D) -> (N, E) f32
+    logits (`_router_logits`); w_in: (E, D, H); w_out: (E, H, D).
     Expert e computes relu(x @ w_in[e]) @ w_out[e] — or, with `w_gate`
     (E, D, H) given, the SwiGLU form silu(x @ w_gate[e]) * (x @
     w_in[e]) @ w_out[e] (Mixtral-style experts).  Shard the stacked
@@ -209,15 +239,15 @@ def moe_forward(x, router_w, w_in, w_out, capacity_factor: float = 1.25,
     D = orig_shape[-1]
     xf = x.reshape(-1, D)
     N = xf.shape[0]
-    E = router_w.shape[-1]
+    E = w_in.shape[0]
     # capacity covers the k-fold assignment load at the same factor
     capacity = max(1, math.ceil(capacity_factor * top_k * N / E))
 
     if dropless:
-        out, aux = _moe_dropless(xf, router_w, w_in, w_out, w_gate, top_k)
+        out, aux = _moe_dropless(xf, router, w_in, w_out, w_gate, top_k)
         out = out.astype(xf.dtype).reshape(orig_shape)
         return (out, aux) if return_aux else out
-    logits = xf.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    logits = _router_logits(xf, router)
     if dispatch_mode == "auto":
         from ..parallel import mesh as mesh_mod
         m = mesh_mod.current_mesh()

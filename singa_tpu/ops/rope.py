@@ -99,6 +99,13 @@ def _rope_fn(x, cos, sin, offset=0):
     # table rows [offset[b], offset[b]+T), so every slot rotates at its
     # own position inside ONE compiled step.
     import jax
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        # partial rotary: tables built for the first `rot` dims of each
+        # head rotate those, the rest pass through unrotated
+        return jnp.concatenate(
+            [_rope_fn(x[..., :rot], cos, sin, offset), x[..., rot:]],
+            axis=-1)
     T = x.shape[1]
     if getattr(offset, "ndim", 0):
         idx = offset[:, None] + jnp.arange(T)[None, :]       # (B, T)
@@ -129,6 +136,10 @@ class Rope(autograd.Operator):
 
 
 def apply_rope(x, cos, sin, offset=0):
+    """Rotate `x` (B, T, H, D) by the tables' rows [offset, offset + T).
+    Tables narrower than D / 2 (`rope_frequencies(rot_dim, ...)` with
+    rot_dim = partial_rotary_factor x D) rotate the first rot_dim dims
+    of each head, half-split among themselves, and pass the rest."""
     if isinstance(x, Tensor):
         return Rope(cos, sin, offset)(x)
     return _rope_fn(x, cos, sin, offset)

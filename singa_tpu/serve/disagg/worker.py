@@ -101,6 +101,10 @@ def build_pools(model, n_prefill: int, n_decode: int, *,
         for i in range(n):
             eng = ServeEngine(model, num_slots, max_len,
                               programs=programs, **kw)
+            # a tier hands KV blocks from worker to worker: refused
+            # here, when it is built, for a model whose layers keep
+            # state beside them (serve/slots.py), not at a first handoff
+            eng._refuse_handoff()
             if programs is None:
                 programs = eng.programs()
             pool.append(Worker(f"{role[0]}{i}-{gen}", role, eng))

@@ -464,7 +464,7 @@ class ServeEngine:
         from . import spec as spec_mod
 
         def prefill_chunk(params, buffers, ids, pos, last_idx, slot,
-                          fresh, tables, toks, caches):
+                          fresh, tables, toks, caches, slot_state, tails):
             # one block-aligned chunk of one request's prompt: gather
             # the slot's dense view, run the cached forward at the
             # traced offset, pick the chunk's last valid token
@@ -476,22 +476,46 @@ class ServeEngine:
             # the two prefill programs' semantics cannot drift apart)
             row = jax.lax.dynamic_index_in_dim(tables, slot, axis=0,
                                                keepdims=True)   # (1, MB)
+            entry = state_rows = None
+            if tails is not None:
+                # side state beside the KV blocks (serve/slots.py): the
+                # chunk starts from the tail of the block before it in
+                # the row, zeros at position 0, and reports the state
+                # after each of its blocks' last rows and after its
+                # last valid row, which pad rows must not move
+                before = jnp.take(row[0], jnp.maximum(pos // bs - 1, 0))
+                entry = [tuple(jnp.where(pos > 0, t[before], 0)[None]
+                               for t in tail) for tail in tails]
+                state_rows = jnp.append(
+                    jnp.arange(chunk // bs) * bs + bs - 1, last_idx)
             logits, dense = spec_mod.resume_on_row(
-                resume, params, buffers, ids, pos, row, caches)
+                resume, params, buffers, ids, pos, row, caches, entry,
+                state_rows)
             last = jax.lax.dynamic_slice_in_dim(
                 logits, last_idx, 1, axis=1)[:, 0, :]
             # greedy pick in-program (jnp.argmax — bit-identical to
             # _pick_impl's temperature-0 branch in generate())
             tok = jnp.argmax(last, axis=-1).astype(jnp.int32)[0]
             toks = toks.at[slot].set(tok)
-            new = spec_mod.scatter_chunk(row, pos, fresh, caches, dense,
-                                         bs, chunk)
-            return toks, new
+            new = spec_mod.scatter_chunk(row, pos, fresh, caches,
+                                         [d[:2] for d in dense], bs, chunk)
+            if tails is None:
+                return toks, new, None, None
+            wb = spec_mod.chunk_blocks(row, pos, fresh, bs, chunk)
+            n = chunk // bs
+            for i in range(n):      # in-place writes, as the KV blocks'
+                tails = [tuple(t.at[wb[i]].set(st[0, i])
+                               for t, st in zip(tail, d[2:]))
+                         for tail, d in zip(tails, dense)]
+            slot_state = [tuple(s.at[slot].set(st[0, n])
+                                for s, st in zip(state, d[2:]))
+                          for state, d in zip(slot_state, dense)]
+            return toks, new, slot_state, tails
 
         dec = decode_step(model)
 
         def decode_paged(params, buffers, toks, pos, active, tables,
-                         caches):
+                         caches, slot_state):
             # inactive slots are masked: position clamped to 0 and the
             # write redirected to the null block (their table row may
             # point at blocks now owned by OTHER requests, so —
@@ -501,8 +525,17 @@ class ServeEngine:
             posc = jnp.where(active, pos, 0)
             dense = [kv_ops.gather_block_kv(ck, cv, tables)
                      for ck, cv in caches]
+            if slot_state is not None:
+                # an inactive slot reads zeros and keeps what it holds
+                live = active[:, None]
+                dense = [kv + tuple(jnp.where(live, s, 0) for s in state)
+                         for kv, state in zip(dense, slot_state)]
             logits, dense = dec(params, buffers, toks[:, None], posc,
                                 dense)
+            if slot_state is not None:
+                slot_state = [
+                    tuple(jnp.where(live, n, s) for n, s in zip(d[2:], state))
+                    for d, state in zip(dense, slot_state)]
             picked = jnp.argmax(logits.astype(jnp.float32),
                                 axis=-1).astype(jnp.int32)
             new_toks = jnp.where(active, picked, toks)
@@ -515,14 +548,14 @@ class ServeEngine:
                 return jax.lax.dynamic_slice_in_dim(c, p, 1, axis=0)[0]
 
             new = []
-            for (ck, cv), (dk, dv) in zip(caches, dense):
+            for (ck, cv), (dk, dv, *_) in zip(caches, dense):
                 k_tok = jax.vmap(row_at)(dk, posc)       # (S, K, D)
                 v_tok = jax.vmap(row_at)(dv, posc)
                 new.append(kv_ops.scatter_token_kv(ck, cv, wb, off,
                                                    k_tok, v_tok))
             # ``pos`` is the host's, which adds the 1 itself
             # (BlockPool.advance)
-            return new_toks, new
+            return new_toks, new, slot_state
 
         def handoff_gather(tables, slot, caches):
             # the disaggregated tier's KV handoff source: ONE slot's
@@ -549,9 +582,10 @@ class ServeEngine:
                 spec_mod.make_verify(model, draft_model, self.spec_k, bs),
                 donate_argnums=(8, 9))
         else:
-            self._prefill = jax.jit(prefill_chunk, donate_argnums=(9,))
+            self._prefill = jax.jit(prefill_chunk,
+                                    donate_argnums=(9, 10, 11))
             self._verify = None
-        self._decode = jax.jit(decode_paged, donate_argnums=(6,))
+        self._decode = jax.jit(decode_paged, donate_argnums=(6, 7))
         self._handoff = jax.jit(handoff_gather)
 
     # -- introspection ----------------------------------------------------
@@ -562,6 +596,23 @@ class ServeEngine:
         engine serves with and not the model's masters (a teacher-forced
         pass through ``models._generate.resume_step`` under them)."""
         return self._params, self._buffers
+
+    def slot_cache(self, slot: int):
+        """What the arena holds for the request in ``slot``, per layer
+        ``(k, v, *state)`` as host arrays: its ``pos`` cached positions
+        (n, K, D) gathered through its block-table row, and the slot's
+        side state where the model keeps one.  For a check of what the
+        programs wrote against a reference; it fetches from the device
+        and belongs in no step."""
+        n = int(self.pool.pos[slot])
+        row = self.pool.tables[slot:slot + 1]
+        out = []
+        for i, (ck, cv) in enumerate(self.pool.caches):
+            k, v = kv_ops.gather_block_kv(ck, cv, row)
+            state = () if self.pool.slot_state is None else tuple(
+                np.asarray(s[slot]) for s in self.pool.slot_state[i])
+            out.append((np.asarray(k[0, :n]), np.asarray(v[0, :n]), *state))
+        return out
 
     def compiled_counts(self):
         """(prefill, decode) jit-cache entry counts — the no-recompile
@@ -625,7 +676,8 @@ class ServeEngine:
                     self.pool.draft_caches)
             return self._prefill.lower(
                 self._params, self._buffers, *staged,
-                self.pool.tables, self._toks, self.pool.caches)
+                self.pool.tables, self._toks, self.pool.caches,
+                self.pool.slot_state, self.pool.tails)
 
         def lower_handoff():
             caches = (self.pool.caches + self.pool.draft_caches
@@ -635,7 +687,8 @@ class ServeEngine:
         def lower_decode():
             return self._decode.lower(
                 self._params, self._buffers, self._toks, self.pool.pos,
-                self.pool.active, self.pool.tables, self.pool.caches)
+                self.pool.active, self.pool.tables, self.pool.caches,
+                self.pool.slot_state)
 
         def lower_verify():
             return self._verify.lower(
@@ -691,6 +744,7 @@ class ServeEngine:
         program), then slot and blocks are released here — the
         request now lives in the package until injected elsewhere."""
         from .disagg import handoff as _handoff_mod
+        self._refuse_handoff()
         return _handoff_mod.extract(self, slot)
 
     def inject_handoff(self, pkg) -> bool:
@@ -701,7 +755,19 @@ class ServeEngine:
         request continues decoding here mid-stream.  False when
         capacity is lacking (the router parks the handoff)."""
         from .disagg import handoff as _handoff_mod
+        self._refuse_handoff()
         return _handoff_mod.inject(self, pkg)
+
+    def _refuse_handoff(self) -> None:
+        """A handoff package carries KV blocks and nothing else: for a
+        model with side state beside them (serve/slots.py) the
+        receiving engine would decode from the wrong state.
+        ``disagg.build_pools`` refuses such a model when a tier is
+        built."""
+        if self.pool.tails is not None:
+            raise NotImplementedError(
+                f"{type(self.model).__name__} keeps state beside its KV "
+                f"blocks, which the disaggregated handoff does not carry")
 
     # -- submission --------------------------------------------------------
     def submit(self, prompt_ids, *, max_new_tokens: int,
@@ -1123,6 +1189,8 @@ class ServeEngine:
             start0 = n_shared * bs
             if n_shared:
                 self.metrics.on_prefix_hit(start0)
+                if self.pool.tails is not None:
+                    self.metrics.on_state_resume(self.pool.tail_blocks)
             C = self._chunk
             view = self.pool.max_blocks * bs
             # no row changes between this admission's chunks
@@ -1161,10 +1229,13 @@ class ServeEngine:
                                  self.pool.caches, self.pool.draft_caches),
                                 rid=req.rid)
                         else:
-                            self._toks, self.pool.caches = self._dispatch(
+                            (self._toks, self.pool.caches,
+                             self.pool.slot_state,
+                             self.pool.tails) = self._dispatch(
                                 "serve.prefill", self._prefill,
                                 (self._params, self._buffers, *staged,
-                                 tables, self._toks, self.pool.caches),
+                                 tables, self._toks, self.pool.caches,
+                                 self.pool.slot_state, self.pool.tails),
                                 rid=req.rid)
                         self.metrics.on_prefill_chunk(
                             start + chunk.size - fresh)
@@ -1274,10 +1345,12 @@ class ServeEngine:
         t0 = time.perf_counter()
         with events.span("serve.decode", active=len(self._running)):
             with events.span("serve.decode.dispatch"):
-                self._toks, self.pool.caches = self._dispatch(
+                (self._toks, self.pool.caches,
+                 self.pool.slot_state) = self._dispatch(
                     "serve.decode", self._decode,
                     (self._params, self._buffers, self._toks,
-                     *self.pool.snapshot(), self.pool.caches),
+                     *self.pool.snapshot(), self.pool.caches,
+                     self.pool.slot_state),
                     active=len(self._running))
                 self.pool.advance(1)
                 if self._moe_top_k:

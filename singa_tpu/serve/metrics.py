@@ -115,6 +115,13 @@ name                        kind       meaning
                                        ``snapshot()`` keeps the total
                                        and ``moe_dispatches``, the
                                        number of such dispatches
+``serve.cca_state_resumes`` counter    one admission of a model with side
+                                       state beside its KV blocks
+                                       (serve/slots.py) that started
+                                       from a shared block's tail;
+                                       ``snapshot()`` keeps the total
+``serve.cca_tail_blocks``   gauge      keyed blocks resident then, each
+                                       holding a tail to resume from
 ``serve.token``             counter    one token delivered to a request
                                        (prefill first token, decode
                                        tick, recovery/preemption replay
@@ -201,6 +208,11 @@ class ServeMetrics:
         # the (token, expert) pairs they routed; both 0 for a dense model
         self.moe_dispatches = 0
         self.moe_assignments = 0
+        # models with side state beside the KV blocks (CCA): admissions
+        # that started from a shared block's tail, and the blocks whose
+        # tail can be resumed from (a level, not a total); 0 otherwise
+        self.cca_state_resumes = 0
+        self.cca_tail_blocks = 0
         self._accept = _Hist()
         self._ttft = _Hist()
         self._token = _Hist()
@@ -326,6 +338,16 @@ class ServeMetrics:
         self.moe_assignments += assignments
         events.counter("serve.moe_assignments", assignments)
 
+    def on_state_resume(self, tail_blocks: int) -> None:
+        """One admission of a model with side state beside its KV
+        blocks that started from the tail kept with its last shared
+        block; `tail_blocks` keyed blocks now hold a tail a later
+        request can start from."""
+        self.cca_state_resumes += 1
+        self.cca_tail_blocks = tail_blocks
+        events.counter("serve.cca_state_resumes", 1)
+        events.gauge("serve.cca_tail_blocks", tail_blocks)
+
     @property
     def accept_rate(self) -> Optional[float]:
         """Overall accepted / proposed (None before any verify round)."""
@@ -399,6 +421,8 @@ class ServeMetrics:
             "prefill_chunk_rows": self.prefill_chunk_rows,
             "moe_dispatches": self.moe_dispatches,
             "moe_assignments": self.moe_assignments,
+            "cca_state_resumes": self.cca_state_resumes,
+            "cca_tail_blocks": self.cca_tail_blocks,
             "accept_rate": self.accept_rate,
             "tokens_per_dispatch": self.tokens_per_dispatch,
             "accept_rate_hist": self._accept.summary(),

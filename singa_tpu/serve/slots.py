@@ -53,6 +53,28 @@ positions past its own validity window (``cached_sdpa`` per-row
 ``limit``), so the null block (like any stale table entry) is
 unreachable.
 
+**Side state beside the KV blocks.**  A model whose layers read more
+than their KV cache (``models/zaya.py``: a CCA layer's output at
+position t needs the latents of the two positions before it and the
+previous token's part of the value, which no cache entry holds and
+nothing can recompute) says so through ``init_caches``: its per-layer
+cache is ``(k, v, *state)``, each state array with the batch axis
+leading.  The pool then keeps that state twice, both on the device:
+:attr:`slot_state`, per slot, what the running request's next token
+reads (``decode_paged`` carries it, ``prefill_chunk`` leaves it as it
+stood after the prompt's last valid row); and :attr:`tails`, per
+physical block, the state as it stood after the block's last token,
+written by the prefill chunk that filled the block.  Every prefill
+chunk starts at a block boundary and takes its entry state from the
+tail of the block before it in the slot's row (zeros at position 0),
+so a prefix hit, a prompt's second chunk and the re-prefill after
+``resubmit`` or preemption all resume exactly; shared tails are never
+rewritten, as shared blocks are not (:func:`serve.spec.chunk_blocks`).
+Decode never writes a tail: the only blocks a chunk can start after
+are full blocks of a prompt, and a prefill filled those.  Spill, the
+int8 arena and a draft model's arena know nothing of this state and
+are refused for such a model.
+
 **Memory hierarchy** (ISSUE 17, :mod:`singa_tpu.serve.mem`):
 ``kv_dtype="int8"`` stores either arena as int8 codes + per-position
 f32 scales (:class:`~singa_tpu.ops.kv_cache.QuantKV` — the gather/
@@ -74,12 +96,13 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import mem
 
-__all__ = ["BlockPool"]
+__all__ = ["BlockPool", "has_side_state"]
 
 #: chain-hash seed of the empty prefix
 _ROOT = b"singa-kv-prefix-root"
@@ -98,6 +121,13 @@ def _chain_keys(tokens: np.ndarray, n_blocks: int, block_size: int
         prev = h.digest()
         keys.append(prev)
     return keys
+
+
+def has_side_state(model) -> bool:
+    """Whether ``model``'s layers keep state beside their KV cache: its
+    ``init_caches`` then yields ``(k, v, *state)`` per layer."""
+    spec = jax.eval_shape(lambda: model.init_caches(1, 1))
+    return any(len(layer) > 2 for layer in spec)
 
 
 class BlockPool:
@@ -146,6 +176,17 @@ class BlockPool:
         self.kv_dtype = mem.normalize_kv_dtype(kv_dtype)
         self.draft_kv_dtype = (self.kv_dtype if draft_kv_dtype is None
                                else mem.normalize_kv_dtype(draft_kv_dtype))
+        side = has_side_state(model)
+        for what, on in (("a draft model's arena (speculative decoding)",
+                          draft_model is not None),
+                         ("the int8 arena", self.kv_dtype == "int8"),
+                         ("the spill tier", spill is not None)):
+            if side and on:
+                raise NotImplementedError(
+                    f"{type(model).__name__} keeps state beside its KV "
+                    f"blocks, which {what} does not carry: a request "
+                    f"resumed from such a block would read the wrong "
+                    f"state")
         if self.kv_dtype == "int8":
             # int8 arena: codes + scales replace the float pool (the
             # dtype= serving-precision override is moot — scales are
@@ -157,11 +198,22 @@ class BlockPool:
             # allocate straight in the serving dtype (e.g. bf16 under a
             # param_dtype cast): eval_shape keeps the full-precision
             # arena abstract, so construction never holds two copies
-            import jax
             spec = jax.eval_shape(
                 lambda: model.init_caches(num_blocks, block_size))
             self.caches = jax.tree.map(
                 lambda s: jnp.zeros(s.shape, dtype), spec)
+        # side state beside the KV blocks (module docstring): the pools
+        # keep (k, v) alone, as every program and the memory tiers know
+        # them; what a layer's cache holds beyond them is kept per block
+        # (its leading axis is num_blocks here) and per slot
+        self.tails = self.slot_state = None
+        if side:
+            self.tails = [tuple(c[2:]) for c in self.caches]
+            self.caches = [tuple(c[:2]) for c in self.caches]
+            spec = jax.eval_shape(lambda: model.init_caches(num_slots, 1))
+            self.slot_state = [tuple(jnp.zeros(a.shape, t.dtype)
+                                     for a, t in zip(c[2:], tail))
+                               for c, tail in zip(spec, self.tails)]
         # speculative decoding (serve/spec.py): the DRAFT model's KV
         # blocks ride the SAME block tables — draft caches are a second
         # per-layer pool with identical (num_blocks, block_size) leading
@@ -186,7 +238,6 @@ class BlockPool:
             # draft arena would double the draft's KV traffic (and,
             # under self-speculation, let draft and target argmaxes
             # diverge by reading different-precision KV)
-            import jax
             spec = jax.eval_shape(
                 lambda: draft_model.init_caches(num_blocks, block_size))
             self.draft_caches = jax.tree.map(
@@ -217,7 +268,8 @@ class BlockPool:
         #: (target + draft, codes + scales) — the honest per-block HBM
         #: footprint behind blocks_in_use_bytes
         self.block_bytes = mem.arena_block_bytes(self.caches,
-                                                 self.draft_caches)
+                                                 self.draft_caches) \
+            + (mem.arena_block_bytes(self.tails) if side else 0)
 
     # -- slot bookkeeping -------------------------------------------------
     @property
@@ -251,6 +303,12 @@ class BlockPool:
         draft, int8 codes AND f32 scale tensors) — blocks alone
         under-report a quantized or speculative arena's footprint."""
         return self.blocks_in_use * self.block_bytes
+
+    @property
+    def tail_blocks(self) -> int:
+        """Keyed blocks, each holding a tail a request can resume from
+        (0 for a model without side state)."""
+        return len(self._key_of) if self.tails is not None else 0
 
     def mapped_count(self, slot: int) -> int:
         return len(self._mapped[slot])

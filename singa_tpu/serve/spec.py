@@ -67,7 +67,8 @@ from ..obs import trace as obs_trace
 from ..ops import kv_cache as kv_ops
 
 __all__ = ["make_spec_prefill", "make_verify", "verify_round",
-           "VerifyDispatchFailed", "resume_on_row", "scatter_chunk"]
+           "VerifyDispatchFailed", "resume_on_row", "scatter_chunk",
+           "chunk_blocks"]
 
 
 class VerifyDispatchFailed(RuntimeError):
@@ -82,14 +83,32 @@ class VerifyDispatchFailed(RuntimeError):
     token at a stale position and silently diverge the stream)."""
 
 
-def resume_on_row(resume, params, buffers, ids, pos, row, caches):
+def resume_on_row(resume, params, buffers, ids, pos, row, caches,
+                  entry=None, state_rows=None):
     """Gather ``row``'s dense per-layer view and run ``resume`` (a
     ``models._generate.resume_step`` closure) on it at traced offset
     ``pos`` — the shared first half of every prefill-chunk program
     (plain AND speculative), so the two engines' prefill semantics can
-    never drift apart."""
+    never drift apart.
+
+    ``entry``, for a model with side state beside its KV cache: per
+    layer the tuple of state arrays (1, ...) as they stood before row
+    ``pos``; each layer's cache is then ``(k, v, *state)`` and comes
+    back with the state after each of ``state_rows``."""
     dense = [kv_ops.gather_block_kv(ck, cv, row) for ck, cv in caches]
-    return resume(params, buffers, ids, pos, dense)
+    if entry is None:
+        return resume(params, buffers, ids, pos, dense)
+    dense = [kv + tuple(st) for kv, st in zip(dense, entry)]
+    return resume(params, buffers, ids, pos, dense, state_rows)
+
+
+def chunk_blocks(row, pos, fresh, block_size, chunk):
+    """Physical ids of the ``chunk // block_size`` blocks a prefill
+    chunk at ``pos`` writes through table row ``row``: the null block 0
+    for those below ``fresh``, which the chunk only recomputed."""
+    idx = pos // block_size + jnp.arange(chunk // block_size)
+    return jnp.where(idx * block_size >= fresh,
+                     jnp.take(row[0], idx, mode="clip"), 0)
 
 
 def scatter_chunk(row, pos, fresh, caches, dense, block_size, chunk):
@@ -112,8 +131,7 @@ def scatter_chunk(row, pos, fresh, caches, dense, block_size, chunk):
     ``dynamic_slice`` would clamp a crossing chunk silently and send
     K/V to the wrong positions."""
     bs, n = block_size, chunk // block_size
-    idx = pos // bs + jnp.arange(n)
-    wb = jnp.where(idx * bs >= fresh, jnp.take(row[0], idx, mode="clip"), 0)
+    wb = chunk_blocks(row, pos, fresh, bs, chunk)
     new = []
     for (ck, cv), (dk, dv) in zip(caches, dense):
         kb = jax.lax.dynamic_slice_in_dim(dk[0], pos, chunk, axis=0)
