@@ -24,6 +24,15 @@ scale granularity is per POSITION, not per block, because
 ``scatter_token_kv``/``scatter_tokens_kv`` write partial blocks — a
 single per-block scalar would force requantizing the block's existing
 content whenever a new token's amax grew past it.
+
+**Decode without a view** (ISSUE 33): a decode tick may hand the model
+:class:`PagedKV` caches — a pool as the arena holds it, the block table
+and where this tick's token goes.  ``update_cache`` then writes the
+token into the pool in place and ``cached_sdpa`` reads the slot's
+blocks through the table (``ops.paged_attention``), so the model's two
+calls stay what they are and no ``max_len``-sized view exists.
+:func:`reads_blocks` says when: a plain bf16/f32 pool the kernel tiles,
+on a TPU.  Everything else gathers a view as before.
 """
 
 from __future__ import annotations
@@ -34,10 +43,12 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..device import on_tpu
+
 __all__ = ["init_cache", "update_cache", "cached_sdpa",
            "gather_block_kv", "scatter_block_kv", "scatter_token_kv",
            "scatter_tokens_kv", "QuantKV", "quantize_kv",
-           "dequantize_kv"]
+           "dequantize_kv", "PagedKV", "reads_blocks"]
 
 #: int8 code range: symmetric, -127..127 (the -128 code is unused so
 #: quantization commutes with negation and the scale maps amax -> 127)
@@ -82,6 +93,47 @@ class QuantKV:
         return f"QuantKV(q={self.q.shape}, scale={self.scale.shape})"
 
 
+@jax.tree_util.register_pytree_node_class
+class PagedKV:
+    """One pool of a paged arena as a decode tick's cache: ``pool``
+    (num_blocks, block_size, K, D) as the arena holds it, ``tables``
+    (S, max_blocks) int32 block-table rows, and ``block`` / ``offset``
+    (S,) int32, where this tick's one token a slot is written.
+    :func:`update_cache` and :func:`cached_sdpa` take it where a dense
+    (S, max_len, K, D) cache would go, so a model's cached attention
+    serves from the arena without learning what an arena is."""
+
+    __slots__ = ("pool", "tables", "block", "offset")
+
+    def __init__(self, pool, tables, block, offset):
+        self.pool = pool
+        self.tables = tables
+        self.block = block
+        self.offset = offset
+
+    def tree_flatten(self):
+        return (self.pool, self.tables, self.block, self.offset), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    def __repr__(self):
+        return f"PagedKV(pool={self.pool.shape}, tables={self.tables.shape})"
+
+
+def reads_blocks(pool) -> bool:
+    """Whether one-row decode attention reads this pool's blocks through
+    the block table (``ops.paged_attention``) instead of a gathered
+    view: a plain bf16 or f32 pool of a shape the kernel tiles, on a
+    TPU.  Decided by what the pool is, like ``attention._use_flash``;
+    there is no switch."""
+    from .paged_attention import tiles
+    return (not isinstance(pool, QuantKV)
+            and pool.dtype in (jnp.bfloat16, jnp.float32)
+            and tiles(pool.shape, pool.dtype) and on_tpu())
+
+
 def quantize_kv(x):
     """Quantize ``x`` (..., K, D) to (int8 codes, f32 scales): one
     symmetric absmax scale per leading index (per position), shape
@@ -113,7 +165,15 @@ def update_cache(ck, cv, k_new, v_new, pos):
     `pos` may be a traced scalar — decode steps compile once and slide —
     or a traced (B,) vector (continuous batching, serve.engine): row b's
     new keys land at its own positions [pos[b], pos[b]+T), so slots at
-    different generation depths share ONE compiled decode step."""
+    different generation depths share ONE compiled decode step.
+
+    :class:`PagedKV` caches take row b's one token (T == 1) into the
+    pool at ``[block[b], offset[b]]``, in place."""
+    if isinstance(ck, PagedKV):
+        pk, pv = scatter_token_kv(ck.pool, cv.pool, ck.block, ck.offset,
+                                  k_new[:, 0], v_new[:, 0])
+        return (PagedKV(pk, ck.tables, ck.block, ck.offset),
+                PagedKV(pv, cv.tables, cv.block, cv.offset))
     if getattr(pos, "ndim", 0):
         def row(c, n, p):
             return jax.lax.dynamic_update_slice_in_dim(c, n, p, axis=0)
@@ -245,7 +305,20 @@ def cached_sdpa(q, ck, cv, limit, scale: float = None, mask=None,
     math, two entry points.  `mask`: optional (B, 1|H, 1|T, S) boolean
     padding mask ANDed with the validity window.  `window`:
     Mistral-style sliding window — each query also ignores cache
-    positions more than `window - 1` behind it."""
+    positions more than `window - 1` behind it.
+
+    :class:`PagedKV` caches (one query row a slot, a `limit` a slot, no
+    `mask`): the slot's blocks are read through its table row, only
+    those that hold a position below `limit` and inside `window`."""
+    if isinstance(ck, PagedKV):
+        from .paged_attention import paged_attention
+        if q.shape[1] != 1 or mask is not None:
+            raise ValueError(
+                "PagedKV caches attend one query row a slot under a limit "
+                f"and a window only (got {q.shape[1]} rows, mask "
+                f"{'given' if mask is not None else 'None'})")
+        return paged_attention(q[:, 0], ck.pool, cv.pool, ck.tables, limit,
+                               window=window or 0, scale=scale)[:, None]
     from .attention import _sdpa_reference
     T = q.shape[1]
     S = ck.shape[1]

@@ -523,8 +523,25 @@ class ServeEngine:
             # harmless), and their token entry frozen so nothing
             # downstream reads a garbage argmax
             posc = jnp.where(active, pos, 0)
-            dense = [kv_ops.gather_block_kv(ck, cv, tables)
-                     for ck, cv in caches]
+            wb = jnp.take_along_axis(tables, (posc // bs)[:, None],
+                                     axis=1)[:, 0]
+            wb = jnp.where(active, wb, 0)
+            off = jnp.where(active, posc % bs, 0)
+            # what the pools are decides how attention reads them
+            # (ops.kv_cache.reads_blocks): through the block table, the
+            # token written into the arena first and no view of it made
+            # — or, for an int8 arena and off the TPU, a gathered dense
+            # view that the model writes the token into
+            paged = kv_ops.reads_blocks(caches[0][0])
+            if paged:
+                # an inactive slot reads the null block it writes
+                rows = jnp.where(active[:, None], tables, 0)
+                dense = [(kv_ops.PagedKV(ck, rows, wb, off),
+                          kv_ops.PagedKV(cv, rows, wb, off))
+                         for ck, cv in caches]
+            else:
+                dense = [kv_ops.gather_block_kv(ck, cv, tables)
+                         for ck, cv in caches]
             if slot_state is not None:
                 # an inactive slot reads zeros and keeps what it holds
                 live = active[:, None]
@@ -539,10 +556,11 @@ class ServeEngine:
             picked = jnp.argmax(logits.astype(jnp.float32),
                                 axis=-1).astype(jnp.int32)
             new_toks = jnp.where(active, picked, toks)
-            wb = jnp.take_along_axis(tables, (posc // bs)[:, None],
-                                     axis=1)[:, 0]
-            wb = jnp.where(active, wb, 0)
-            off = jnp.where(active, posc % bs, 0)
+            # ``pos`` is the host's, which adds the 1 itself
+            # (BlockPool.advance)
+            if paged:
+                return (new_toks, [(dk.pool, dv.pool) for dk, dv, *_ in dense],
+                        slot_state)
 
             def row_at(c, p):
                 return jax.lax.dynamic_slice_in_dim(c, p, 1, axis=0)[0]
@@ -553,8 +571,6 @@ class ServeEngine:
                 v_tok = jax.vmap(row_at)(dv, posc)
                 new.append(kv_ops.scatter_token_kv(ck, cv, wb, off,
                                                    k_tok, v_tok))
-            # ``pos`` is the host's, which adds the 1 itself
-            # (BlockPool.advance)
             return new_toks, new, slot_state
 
         def handoff_gather(tables, slot, caches):
@@ -1343,6 +1359,13 @@ class ServeEngine:
 
     def _decode_tick(self) -> int:
         t0 = time.perf_counter()
+        pool = self.pool
+        # what this tick's attention has to read, from the host's own
+        # slot state (no device read): each active slot's blocks up to
+        # the position it writes, against every slot's whole table row
+        self.metrics.on_decode_kv(
+            int((pool.pos[pool.active] // pool.block_size + 1).sum()),
+            pool.num_slots * pool.max_blocks)
         with events.span("serve.decode", active=len(self._running)):
             with events.span("serve.decode.dispatch"):
                 (self._toks, self.pool.caches,

@@ -106,6 +106,18 @@ name                        kind       meaning
                                        and ``prefill_chunks``, the
                                        number of dispatches: rows per
                                        chunk is how full a chunk runs
+``serve.decode_kv_blocks_live``  counter  KV blocks one decode tick's
+                                       attention has to read: over the
+                                       active slots, ``pos //
+                                       block_size + 1`` (host-known)
+``serve.decode_kv_blocks_view``  counter  blocks a dense view of the
+                                       arena spans that tick:
+                                       ``num_slots x max_blocks``.
+                                       live / view is the share of the
+                                       view a tick reads where decode
+                                       reads blocks through the table
+                                       (``ops/paged_attention.py``);
+                                       ``snapshot()`` keeps both totals
 ``serve.moe_assignments``   counter    (token, expert) pairs one prefill
                                        chunk or decode tick of a
                                        mixture-of-experts model routes:
@@ -204,6 +216,10 @@ class ServeMetrics:
         # prefill dispatches, and the prompt tokens they prefilled
         self.prefill_chunks = 0
         self.prefill_chunk_rows = 0
+        # decode ticks: the KV blocks their attention had to read, and
+        # the blocks a dense view of every slot's table row spans
+        self.decode_kv_blocks_live = 0
+        self.decode_kv_blocks_view = 0
         # mixture-of-experts models: dispatches that ran the router and
         # the (token, expert) pairs they routed; both 0 for a dense model
         self.moe_dispatches = 0
@@ -331,6 +347,15 @@ class ServeMetrics:
         self.prefill_chunk_rows += rows
         events.counter("serve.prefill_chunk_rows", rows)
 
+    def on_decode_kv(self, live: int, view: int) -> None:
+        """One decode tick whose active slots hold ``live`` KV blocks
+        up to the positions they write, of the ``view`` blocks that
+        every slot's whole table row spans."""
+        self.decode_kv_blocks_live += live
+        self.decode_kv_blocks_view += view
+        events.counter("serve.decode_kv_blocks_live", live)
+        events.counter("serve.decode_kv_blocks_view", view)
+
     def on_moe_dispatch(self, assignments: int) -> None:
         """One prefill chunk or decode tick of a mixture-of-experts
         model: ``assignments`` = its valid tokens x top-k."""
@@ -419,6 +444,8 @@ class ServeMetrics:
             "slot_dispatch_tokens": self.slot_dispatch_tokens,
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_rows": self.prefill_chunk_rows,
+            "decode_kv_blocks_live": self.decode_kv_blocks_live,
+            "decode_kv_blocks_view": self.decode_kv_blocks_view,
             "moe_dispatches": self.moe_dispatches,
             "moe_assignments": self.moe_assignments,
             "cca_state_resumes": self.cca_state_resumes,

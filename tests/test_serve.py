@@ -18,6 +18,7 @@ The invariants under test are the subsystem's contract:
     histogram primitive's summary semantics hold.
 """
 
+import contextlib
 import json
 
 import numpy as np
@@ -790,6 +791,131 @@ class TestPagedGatherHasNoFill:
         assert eng.metrics.preempted >= 1
         # the run reached the null block and the highest id
         assert {0, eng.pool.num_blocks - 1} <= seen
+
+
+def _read_blocks_model(kind):
+    """A tiny model whose arena the paged kernel tiles (heads of 128,
+    two KV heads): a Llama that mixes sliding and full layers, or a
+    Zaya with its CCA state beside the KV blocks."""
+    import dataclasses
+    tensor.set_seed(0)
+    if kind == "llama":
+        m = models.Llama(dataclasses.replace(
+            models.LlamaConfig.tiny(), num_layers=2, num_heads=4,
+            num_kv_heads=2, head_size=128, sliding_window=12,
+            layer_types=("sliding_attention", "full_attention")))
+    else:
+        m = models.Zaya(dataclasses.replace(models.ZayaConfig.tiny(),
+                                            head_size=128))
+    m.eval()
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32))],
+              is_train=False, use_graph=False)
+    return m
+
+
+@contextlib.contextmanager
+def _as_on_a_tpu():
+    """What a TPU gets: the platform probe of `ops.kv_cache` steered
+    from the test while a decode program traces (the kernel itself
+    still sees the CPU and runs in interpret mode).  The program has no
+    option for it."""
+    from singa_tpu.ops import kv_cache as kv_ops
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kv_ops, "on_tpu", lambda: True)
+        yield
+
+
+@pytest.fixture(scope="module", params=["llama", "zaya"])
+def block_readers(request):
+    """(model, an engine on the gathered view, an engine whose decode
+    reads blocks through the table), each with its two programs traced
+    by a first request: a program is traced once, so the probe is only
+    steered here and where a test lowers the program again."""
+    from singa_tpu.ops import kv_cache as kv_ops
+    model = _read_blocks_model(request.param)
+    kw = dict(num_slots=3, max_len=64, block_size=8)
+    view, paged = ServeEngine(model, **kw), ServeEngine(model, **kw)
+    assert not kv_ops.reads_blocks(paged.pool.caches[0][0])
+    view.submit(_toks(5, 1), max_new_tokens=3)
+    view.run_until_idle()
+    with _as_on_a_tpu():
+        assert kv_ops.reads_blocks(paged.pool.caches[0][0])
+        paged.submit(_toks(5, 1), max_new_tokens=3)
+        paged.run_until_idle()
+    return model, view, paged
+
+
+def _view_shapes(eng):
+    """The dense view of every slot's table row, before and after its
+    reshape, as MLIR tensor types."""
+    pool, ck = eng.pool, eng.pool.caches[0][0]
+    rest = "x".join(map(str, ck.shape[2:]))
+    return (f"tensor<{pool.num_slots * pool.max_blocks}x{ck.shape[1]}x{rest}x",
+            f"tensor<{pool.num_slots}x{pool.max_blocks * ck.shape[1]}x{rest}x")
+
+
+class TestDecodeReadsBlocks:
+    """`decode_paged` hands the model the arena, not a view (ISSUE 33):
+    the token goes into the pool first and `ops.paged_attention` reads
+    each slot's blocks through its table row, up to its length and
+    inside the layer's window."""
+
+    def test_streams_equal_the_view_paths(self, block_readers):
+        """Five requests over three slots, a shared 16-token prefix:
+        prefix hits, tables that grow block by block, slots released
+        and admitted into again; sliding and full layers (or CCA side
+        state) in one program."""
+        _, view, paged = block_readers
+        rng = np.random.RandomState(3)
+        shared = rng.randint(0, 256, (16,)).astype(np.int32)
+        prompts = [np.concatenate(
+            [shared, rng.randint(0, 256, (n,)).astype(np.int32)])
+            for n in (5, 12, 3, 20, 9)]
+        streams = []
+        for eng in (view, paged):
+            hs = [eng.submit(p, max_new_tokens=10 + i)
+                  for i, p in enumerate(prompts)]
+            eng.run_until_idle()
+            assert all(h.finish_reason == "length" for h in hs)
+            assert eng.metrics.snapshot()["prefix_hits"] >= 2
+            assert_program_count(eng, (1, 1))
+            assert (eng.pool.ref == 0).all()
+            streams.append([list(h.tokens) for h in hs])
+        assert streams[0] == streams[1]
+
+    def test_lowered_decode_holds_no_view(self, block_readers):
+        _, view, paged = block_readers
+
+        def text(eng, name):
+            return eng.lower_programs(names=(name,))[name].as_text()
+        with _as_on_a_tpu():
+            lowered = {paged: text(paged, "decode")}
+            prefill = text(paged, "prefill_chunk")
+        lowered[view] = text(view, "decode")
+        for shape in _view_shapes(view):
+            assert shape in lowered[view]       # what is looked for exists
+            assert shape not in lowered[paged]
+        # prefill still gathers one slot's view, on either engine
+        row = _view_shapes(paged)[1].replace(
+            f"<{paged.pool.num_slots}x", "<1x")
+        assert row in prefill
+
+    def test_counters_add_up(self, block_readers):
+        """One request alone: a prompt of 13 and 12 new tokens at block
+        size 8.  Prefill gives the first; the eleven ticks write
+        positions 13..23, and by hand 13-15 lie in the second block,
+        16-23 in the third."""
+        _, _, paged = block_readers
+        before = paged.metrics.snapshot()
+        h = paged.submit(_toks(13, 13), max_new_tokens=12)
+        paged.run_until_idle()
+        assert len(h.tokens) == 12
+        snap = paged.metrics.snapshot()
+        live = snap["decode_kv_blocks_live"] - before["decode_kv_blocks_live"]
+        span = snap["decode_kv_blocks_view"] - before["decode_kv_blocks_view"]
+        assert live == 3 * 2 + 8 * 3
+        assert span == 11 * paged.pool.num_slots * paged.pool.max_blocks
+        assert 0 < live <= span
 
 
 def _engine_of(kind, model, **kw):
