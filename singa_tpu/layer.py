@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import math
 from collections import OrderedDict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -677,8 +677,10 @@ class MultiHeadAttention(Layer):
 
 class _MoEOp(autograd.Operator):
     def __init__(self, cf, top_k=1, swiglu=False, dispatch_mode="auto",
-                 dropless=False, router_fn=None, n_router=1):
+                 dropless=False, router_fn=None, n_router=1,
+                 experts_held=None):
         super().__init__()
+        self.experts_held = experts_held
         self.cf = cf
         self.top_k = top_k
         self.swiglu = swiglu
@@ -699,7 +701,8 @@ class _MoEOp(autograd.Operator):
                                top_k=self.top_k,
                                w_gate=wg[0] if self.swiglu else None,
                                dispatch_mode=self.dispatch_mode,
-                               dropless=self.dropless)
+                               dropless=self.dropless,
+                               experts_held=self.experts_held)
         return out, aux
 
 
@@ -764,6 +767,12 @@ class MoE(Layer):
     `router.*`.  A top-1 gate is the chosen expert's softmax
     probability, not renormalised; k > 1 renormalises over the k.
 
+    `experts_held` (dropless only): the ids of the experts whose
+    weights this layer holds, as one chip's share of a layer that
+    several chips divide: the router keeps its `num_experts` outputs
+    and its top-k, the stacks hold `len(experts_held)` experts, and the
+    output is their part of the sum (ops/moe.py::_moe_dropless).
+
     The router's load-balance auxiliary losses accumulate across
     *training-mode* calls (eval and compile-time dry runs don't
     accumulate — an init-trace entry would leak a dead tracer);
@@ -782,8 +791,19 @@ class MoE(Layer):
                  capacity_factor: float = 1.25, top_k: int = 1,
                  act: str = "relu", dispatch_mode: str = "auto",
                  dropless: bool = False, router: Optional[Layer] = None,
-                 name=None):
+                 experts_held: Optional[Sequence[int]] = None, name=None):
         super().__init__(name)
+        if experts_held is not None:
+            experts_held = tuple(int(e) for e in experts_held)
+            if not dropless:
+                raise ValueError(
+                    "experts_held is the dropless form's: a capacity "
+                    "buffer is sized for all the experts")
+            if not experts_held or len(set(experts_held)) != len(experts_held) \
+                    or not all(0 <= e < num_experts for e in experts_held):
+                raise ValueError(
+                    f"experts_held {experts_held} is not a set of ids "
+                    f"out of {num_experts} experts")
         if router is not None and router.num_experts != num_experts:
             raise ValueError(
                 f"router yields {router.num_experts} logits for "
@@ -808,6 +828,9 @@ class MoE(Layer):
         # exact top-k with every assignment computed (ops/moe.py): the
         # serving form; capacity_factor and dispatch_mode then do nothing
         self.dropless = dropless
+        # the experts whose weights this layer holds, where that is a
+        # share of the `num_experts` it routes over (ops/moe.py)
+        self.experts_held = experts_held
         self._mlp_router = router is not None
         if router is not None:
             self.router = router
@@ -817,6 +840,7 @@ class MoE(Layer):
         d = x.shape[-1]
         e, h = self.num_experts, self.ffn_dim
         dev = x.device
+        held = e if self.experts_held is None else len(self.experts_held)
         if self._mlp_router:
             # the forward hands its weights to the fused op and never
             # calls it, so it is initialised here
@@ -826,14 +850,14 @@ class MoE(Layer):
             self.router = self.register_param(
                 "router", _xavier_uniform((d, e), d, e, dev))
         self.w_in = self.register_param(
-            "w_in", Tensor((e, d, h), dev, np.float32).gaussian(
+            "w_in", Tensor((held, d, h), dev, np.float32).gaussian(
                 0.0, (2.0 / (d + h)) ** 0.5))
         self.w_out = self.register_param(
-            "w_out", Tensor((e, h, d), dev, np.float32).gaussian(
+            "w_out", Tensor((held, h, d), dev, np.float32).gaussian(
                 0.0, (2.0 / (d + h)) ** 0.5))
         if self.act == "swiglu":
             self.w_gate = self.register_param(
-                "w_gate", Tensor((e, d, h), dev, np.float32).gaussian(
+                "w_gate", Tensor((held, d, h), dev, np.float32).gaussian(
                     0.0, (2.0 / (d + h)) ** 0.5))
 
     def forward(self, x: Tensor) -> Tensor:
@@ -845,7 +869,7 @@ class MoE(Layer):
             rw, fn = (self.router,), None
         out, aux = _MoEOp(self.capacity_factor, self.top_k,
                           self.act == "swiglu", self.dispatch_mode,
-                          self.dropless, fn, len(rw))(
+                          self.dropless, fn, len(rw), self.experts_held)(
             x, *rw, self.w_in, self.w_out, *extra)
         # accumulate only in training: eval/compile-time dry runs must
         # not leave stale entries (an init-trace tracer here would crash

@@ -14,6 +14,10 @@ Families:
   * zaya         — ZAYA1: attention in a compressed latent with
                    convolutional mixing (CCA), top-1 experts behind an
                    MLP router, tied head; inference only
+  * granite_hybrid — Granite 4.0-H: Mamba-2 state-space layers to one
+                   attention layer without positional embedding, a
+                   held share of the experts beside a shared MLP, tied
+                   head; inference only
 
 Every model is a singa_tpu.model.Model: imperative forward, trains
 eagerly or as one compiled XLA module, shards over a mesh via the
@@ -27,6 +31,7 @@ from . import vgg
 from . import transformer
 from . import llama
 from . import zaya
+from . import granite_hybrid
 
 from .mlp import MLP
 from .cnn import CNN, LeNet5, AlexNet
@@ -36,17 +41,20 @@ from .vgg import VGG, vgg11, vgg13, vgg16, vgg19
 from .transformer import GPT2, BERT, GPT2Config, BERTConfig
 from .llama import Llama, LlamaConfig
 from .zaya import Zaya, ZayaConfig
+from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .convert import (from_hf, from_hf_bert, from_hf_gpt2,
                       from_hf_llama, from_hf_mistral,
                       from_hf_mixtral, to_hf)
 
 __all__ = [
     "mlp", "cnn", "resnet", "vgg", "transformer", "llama", "zaya",
+    "granite_hybrid",
     "MLP", "CNN", "LeNet5", "AlexNet",
     "ResNet", "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
     "VGG", "vgg11", "vgg13", "vgg16", "vgg19",
     "GPT2", "BERT", "GPT2Config", "BERTConfig",
     "Llama", "LlamaConfig", "Zaya", "ZayaConfig",
+    "GraniteHybrid", "GraniteHybridConfig",
     "from_hf", "from_hf_bert", "from_hf_gpt2", "from_hf_llama",
     "from_hf_mistral", "from_hf_mixtral",
     "to_hf",

@@ -159,11 +159,18 @@ def _expert_ffn(buf, w_in, w_out, w_gate):
     return jnp.einsum("ech,ehd->ecd", h, w_out.astype(buf.dtype))
 
 
-def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k):
+def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k, experts_held=None):
     """(N, D) rows -> ((N, D) f32, balance loss): exact top-k, every
     assignment computed: every expert over every row, the unrouted ones
     weighted 0.  No buffer, no capacity, row n's result a function of
     row n alone.
+
+    `experts_held`: the ids of the experts whose weights the stacks
+    hold, in the stacks' order, where that is a share of the experts
+    the router routes over (one chip's share of a layer that several
+    chips divide).  Routing, top-k and the gates are over all of the
+    router's experts; the result is the held experts' part of the sum,
+    and what the others would have added is left out.
 
     Right where a dispatch holds a few rows an expert (serving: 32
     rows, top 8 of 64), because the matmuls are then weight streaming
@@ -172,12 +179,15 @@ def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k):
     scatter path at capacity = N and 4.69 ms for a sort and
     `jax.lax.ragged_dot` (PERF.md, PR 28).  At training's row counts it
     multiplies E / k times too many rows: that path keeps capacity."""
-    N, E = xf.shape[0], w_in.shape[0]
+    N = xf.shape[0]
     with jax.named_scope("moe.route"):
         logits = _router_logits(xf, router, jax.lax.Precision.HIGHEST)
+        E = logits.shape[-1]
         probs, topi, gates = _topk_gates(logits, top_k)
         w = jnp.zeros((N, E), jnp.float32).at[
             jnp.arange(N)[:, None], topi].set(gates)   # (N, E), k nonzero
+        if experts_held is not None:
+            w = w[:, jnp.asarray(experts_held)]
     f32 = dict(preferred_element_type=jnp.float32)
     with jax.named_scope("moe.experts"):
         h = jnp.einsum("nd,edh->enh", xf, w_in.astype(xf.dtype), **f32)
@@ -196,7 +206,8 @@ def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k):
 
 def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
                 return_aux: bool = False, top_k: int = 1, w_gate=None,
-                dispatch_mode: str = "auto", dropless: bool = False):
+                dispatch_mode: str = "auto", dropless: bool = False,
+                experts_held=None):
     """Top-k MoE FFN over flattened tokens (k=1 Switch, k=2 GShard).
 
     x: (..., D); router: (D, E), or a function (N, D) -> (N, E) f32
@@ -234,7 +245,9 @@ def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
     dropless: exact top-k, every assignment computed, a row's result
     independent of what else the batch holds: what serving needs (a
     capacity drop there silently changes a served token).  It takes the
-    place of capacity and `dispatch_mode`."""
+    place of capacity and `dispatch_mode`.  `experts_held`, dropless
+    only: the stacks hold those of the router's experts and no others
+    (`_moe_dropless`)."""
     orig_shape = x.shape
     D = orig_shape[-1]
     xf = x.reshape(-1, D)
@@ -244,7 +257,8 @@ def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
     capacity = max(1, math.ceil(capacity_factor * top_k * N / E))
 
     if dropless:
-        out, aux = _moe_dropless(xf, router, w_in, w_out, w_gate, top_k)
+        out, aux = _moe_dropless(xf, router, w_in, w_out, w_gate, top_k,
+                                 experts_held)
         out = out.astype(xf.dtype).reshape(orig_shape)
         return (out, aux) if return_aux else out
     logits = _router_logits(xf, router)

@@ -163,6 +163,11 @@ _ENGINE_SEQ = itertools.count()
 _PREFILL_ROWS = 256
 
 
+#: where a prefill chunk of a model with state snapshots enters from,
+#: beside a snapshot's entry (>= 0): the slot's own state, or zeros
+_OWN, _ZEROS = -1, -2
+
+
 def _chunk_tokens(block_size: int, max_blocks: int) -> int:
     """Tokens a prefill dispatch covers: ``_PREFILL_ROWS`` rounded down
     to whole blocks, at least one block, at most the slot's view."""
@@ -351,9 +356,11 @@ class ServeEngine:
             # caches off the bound weights' dtype) — otherwise the
             # block scatter type-mismatches at trace time.  eval_shape
             # under the cast binding reads that dtype without allocating.
+            # Leaf by leaf: a model may pin one (an f32 recurrent state
+            # beside bf16 keys, models/granite_hybrid.py).
             with _bound(model, params, buffers):
                 spec = jax.eval_shape(lambda: model.init_caches(1, 2))
-            arena_dtype = jax.tree.leaves(spec)[0].dtype
+            arena_dtype = jax.tree.map(lambda a: a.dtype, spec)
         self._params, self._buffers = params, buffers
         # draft weights snapshotted the same way (param_dtype applies to
         # the draft too — decode AND verify are weight-read bound)
@@ -464,7 +471,8 @@ class ServeEngine:
         from . import spec as spec_mod
 
         def prefill_chunk(params, buffers, ids, pos, last_idx, slot,
-                          fresh, tables, toks, caches, slot_state, tails):
+                          fresh, tables, toks, caches, slot_state, tails,
+                          snaps, plan):
             # one block-aligned chunk of one request's prompt: gather
             # the slot's dense view, run the cached forward at the
             # traced offset, pick the chunk's last valid token
@@ -488,6 +496,26 @@ class ServeEngine:
                                for t in tail) for tail in tails]
                 state_rows = jnp.append(
                     jnp.arange(chunk // bs) * bs + bs - 1, last_idx)
+            if snaps is not None:
+                # state too heavy for a tail a block (serve/slots.py):
+                # ``plan`` = (where the chunk enters from: a snapshot's
+                # entry, _OWN the slot's own state as the prompt's
+                # chunk before left it, _ZEROS at position 0; the entry
+                # to leave a snapshot in, or -1; the chunk's row it
+                # stands after).  The scan reports those two rows'
+                # states and no others.
+                src, dst, snap_row = plan[0], plan[1], plan[2]
+                entry = [tuple(
+                    jnp.where(src == _ZEROS, 0, jnp.where(
+                        src >= 0, sn[jnp.maximum(src, 0)], own[slot]))[None]
+                    for own, sn in zip(state, snap))
+                    for state, snap in zip(slot_state, snaps)]
+                state_rows = jnp.stack([snap_row, last_idx])
+                # no tail lets such a chunk start early, so it may cross
+                # the view's end: the row grows by a chunk of null
+                # blocks, which take the rows past it
+                row = jnp.concatenate(
+                    [row, jnp.zeros((1, chunk // bs), row.dtype)], axis=1)
             logits, dense = spec_mod.resume_on_row(
                 resume, params, buffers, ids, pos, row, caches, entry,
                 state_rows)
@@ -499,8 +527,18 @@ class ServeEngine:
             toks = toks.at[slot].set(tok)
             new = spec_mod.scatter_chunk(row, pos, fresh, caches,
                                          [d[:2] for d in dense], bs, chunk)
+            if snaps is not None:
+                slot_state = [tuple(s.at[slot].set(st[0, 1])
+                                    for s, st in zip(state, d[2:]))
+                              for state, d in zip(slot_state, dense)]
+                at = jnp.maximum(dst, 0)
+                snaps = [tuple(
+                    sn.at[at].set(jnp.where(dst >= 0, st[0, 0], sn[at]))
+                    for sn, st in zip(snap, d[2:]))
+                    for snap, d in zip(snaps, dense)]
+                return toks, new, slot_state, None, snaps
             if tails is None:
-                return toks, new, None, None
+                return toks, new, None, None, None
             wb = spec_mod.chunk_blocks(row, pos, fresh, bs, chunk)
             n = chunk // bs
             for i in range(n):      # in-place writes, as the KV blocks'
@@ -510,7 +548,7 @@ class ServeEngine:
             slot_state = [tuple(s.at[slot].set(st[0, n])
                                 for s, st in zip(state, d[2:]))
                           for state, d in zip(slot_state, dense)]
-            return toks, new, slot_state, tails
+            return toks, new, slot_state, tails, None
 
         dec = decode_step(model)
 
@@ -532,26 +570,34 @@ class ServeEngine:
             # token written into the arena first and no view of it made
             # — or, for an int8 arena and off the TPU, a gathered dense
             # view that the model writes the token into
-            paged = kv_ops.reads_blocks(caches[0][0])
+            # (a layer without keys has no pool: its (None, None) passes
+            # through every branch)
+            paged = kv_ops.reads_blocks(
+                next(ck for ck, _ in caches if ck is not None))
             if paged:
                 # an inactive slot reads the null block it writes
                 rows = jnp.where(active[:, None], tables, 0)
                 dense = [(kv_ops.PagedKV(ck, rows, wb, off),
                           kv_ops.PagedKV(cv, rows, wb, off))
+                         if ck is not None else (None, None)
                          for ck, cv in caches]
             else:
-                dense = [kv_ops.gather_block_kv(ck, cv, tables)
+                dense = [spec_mod.gather_view(ck, cv, tables)
                          for ck, cv in caches]
             if slot_state is not None:
                 # an inactive slot reads zeros and keeps what it holds
                 live = active[:, None]
-                dense = [kv + tuple(jnp.where(live, s, 0) for s in state)
+                rows_of = lambda a: live.reshape(
+                    live.shape + (1,) * (a.ndim - 2))
+                dense = [kv + tuple(jnp.where(rows_of(s), s, 0)
+                                    for s in state)
                          for kv, state in zip(dense, slot_state)]
             logits, dense = dec(params, buffers, toks[:, None], posc,
                                 dense)
             if slot_state is not None:
                 slot_state = [
-                    tuple(jnp.where(live, n, s) for n, s in zip(d[2:], state))
+                    tuple(jnp.where(rows_of(s), n, s)
+                          for n, s in zip(d[2:], state))
                     for d, state in zip(dense, slot_state)]
             picked = jnp.argmax(logits.astype(jnp.float32),
                                 axis=-1).astype(jnp.int32)
@@ -559,7 +605,9 @@ class ServeEngine:
             # ``pos`` is the host's, which adds the 1 itself
             # (BlockPool.advance)
             if paged:
-                return (new_toks, [(dk.pool, dv.pool) for dk, dv, *_ in dense],
+                return (new_toks,
+                        [(dk.pool, dv.pool) if dk is not None
+                         else (None, None) for dk, dv, *_ in dense],
                         slot_state)
 
             def row_at(c, p):
@@ -567,6 +615,9 @@ class ServeEngine:
 
             new = []
             for (ck, cv), (dk, dv, *_) in zip(caches, dense):
+                if ck is None:
+                    new.append((None, None))
+                    continue
                 k_tok = jax.vmap(row_at)(dk, posc)       # (S, K, D)
                 v_tok = jax.vmap(row_at)(dv, posc)
                 new.append(kv_ops.scatter_token_kv(ck, cv, wb, off,
@@ -581,8 +632,7 @@ class ServeEngine:
             # source caches valid so the router can re-route.
             row = jax.lax.dynamic_index_in_dim(tables, slot, axis=0,
                                                keepdims=True)   # (1, MB)
-            return [kv_ops.gather_block_kv(ck, cv, row)
-                    for ck, cv in caches]
+            return [spec_mod.gather_view(ck, cv, row) for ck, cv in caches]
 
         if draft_model is not None:
             # speculative engine: the prefill program also writes the
@@ -599,7 +649,7 @@ class ServeEngine:
                 donate_argnums=(8, 9))
         else:
             self._prefill = jax.jit(prefill_chunk,
-                                    donate_argnums=(9, 10, 11))
+                                    donate_argnums=(9, 10, 11, 12))
             self._verify = None
         self._decode = jax.jit(decode_paged, donate_argnums=(6, 7))
         self._handoff = jax.jit(handoff_gather)
@@ -617,16 +667,20 @@ class ServeEngine:
         """What the arena holds for the request in ``slot``, per layer
         ``(k, v, *state)`` as host arrays: its ``pos`` cached positions
         (n, K, D) gathered through its block-table row, and the slot's
-        side state where the model keeps one.  For a check of what the
+        side state where the model keeps one (``k`` and ``v`` None for a
+        layer that keeps state alone).  For a check of what the
         programs wrote against a reference; it fetches from the device
         and belongs in no step."""
         n = int(self.pool.pos[slot])
         row = self.pool.tables[slot:slot + 1]
         out = []
         for i, (ck, cv) in enumerate(self.pool.caches):
-            k, v = kv_ops.gather_block_kv(ck, cv, row)
             state = () if self.pool.slot_state is None else tuple(
                 np.asarray(s[slot]) for s in self.pool.slot_state[i])
+            if ck is None:              # a layer with state and no keys
+                out.append((None, None, *state))
+                continue
+            k, v = kv_ops.gather_block_kv(ck, cv, row)
             out.append((np.asarray(k[0, :n]), np.asarray(v[0, :n]), *state))
         return out
 
@@ -693,7 +747,9 @@ class ServeEngine:
             return self._prefill.lower(
                 self._params, self._buffers, *staged,
                 self.pool.tables, self._toks, self.pool.caches,
-                self.pool.slot_state, self.pool.tails)
+                self.pool.slot_state, self.pool.tails, self.pool.snapshots,
+                None if self.pool.snapshots is None
+                else np.zeros((3,), np.int32))
 
         def lower_handoff():
             caches = (self.pool.caches + self.pool.draft_caches
@@ -780,7 +836,7 @@ class ServeEngine:
         receiving engine would decode from the wrong state.
         ``disagg.build_pools`` refuses such a model when a tier is
         built."""
-        if self.pool.tails is not None:
+        if self.pool.slot_state is not None:
             raise NotImplementedError(
                 f"{type(self.model).__name__} keeps state beside its KV "
                 f"blocks, which the disaggregated handoff does not carry")
@@ -1182,6 +1238,8 @@ class ServeEngine:
         owned: List[int] = []
         shared_ids: List[int] = []
         mapped = False
+        by_snapshot = self.pool.snapshots is not None
+        src = dst = snap_key = plan = None
         # the allocation site fires once (no retry loop); only the
         # prefill dispatches below go through _dispatch's backoff —
         # quarantine must attribute the failure to the seam that died
@@ -1202,8 +1260,22 @@ class ServeEngine:
                 fail_attempts = self.max_dispatch_retries + 1
                 self.pool.map_slot(slot, shared_ids + owned)
                 mapped = True
-            start0 = n_shared * bs
-            if n_shared:
+            # blocks below ``keep`` are mapped and stay as they are; the
+            # prefill starts there, or, for a model whose state lives in
+            # snapshots (serve/slots.py), after the deepest block that
+            # has one, and recomputes the rows between
+            keep = start0 = n_shared * bs
+            if by_snapshot:
+                keys = self._req_keys(req)
+                m, src = self.pool.match_snapshot(keys, n_shared)
+                start0 = m * bs
+                if n_shared > m:
+                    snap_key = keys[n_shared - 1]
+                    dst, evicted = self.pool.claim_snapshot(snap_key)
+                    self.metrics.on_snapshot_miss(keep - start0, evicted)
+                if src is not None:
+                    self.metrics.on_snapshot_hit()
+            if start0:
                 self.metrics.on_prefix_hit(start0)
                 if self.pool.tails is not None:
                     self.metrics.on_state_resume(self.pool.tail_blocks)
@@ -1213,7 +1285,7 @@ class ServeEngine:
             tables = self.pool.tables_snapshot()
             with events.span("serve.prefill", slot=slot, prompt=P,
                              shared=start0, chunks=-(-(P - start0) // C)):
-                for fresh in range(start0, P, C):
+                for first_row in range(start0, P, C):
                     with events.span("serve.prefill.stage"):
                         # the program writes all C rows at [start,
                         # start + C) whatever is valid, and
@@ -1221,7 +1293,19 @@ class ServeEngine:
                         # that would cross the view's end starts early
                         # instead and recomputes the tokens below
                         # ``fresh``, whose blocks the scatter leaves be
-                        start = min(fresh, view - C)
+                        # (a chunk entering from a carried state cannot
+                        # start early: its program widens the view)
+                        fresh = max(first_row, keep)
+                        start = first_row if by_snapshot \
+                            else min(first_row, view - C)
+                        if by_snapshot:
+                            here = dst is not None \
+                                and start < keep <= start + C
+                            plan = np.array(
+                                [_OWN if first_row > start0 else
+                                 _ZEROS if src is None else src,
+                                 dst if here else -1,
+                                 keep - 1 - start if here else 0], np.int32)
                         ids = np.zeros((1, C), np.int32)
                         chunk = replay[start:start + C]
                         ids[0, :chunk.size] = chunk
@@ -1246,15 +1330,16 @@ class ServeEngine:
                                 rid=req.rid)
                         else:
                             (self._toks, self.pool.caches,
-                             self.pool.slot_state,
-                             self.pool.tails) = self._dispatch(
+                             self.pool.slot_state, self.pool.tails,
+                             self.pool.snapshots) = self._dispatch(
                                 "serve.prefill", self._prefill,
                                 (self._params, self._buffers, *staged,
                                  tables, self._toks, self.pool.caches,
-                                 self.pool.slot_state, self.pool.tails),
+                                 self.pool.slot_state, self.pool.tails,
+                                 self.pool.snapshots, plan),
                                 rid=req.rid)
                         self.metrics.on_prefill_chunk(
-                            start + chunk.size - fresh)
+                            max(0, start + chunk.size - fresh))
                         if self._moe_top_k:
                             self.metrics.on_moe_dispatch(
                                 chunk.size * self._moe_top_k)
@@ -1273,9 +1358,13 @@ class ServeEngine:
                 self.pool.unref_shared(shared_ids)
                 self.pool.free_blocks(owned)
                 self.pool.release_slot_row(slot)
+            self.pool.settle_snapshots(snap_key, dst, written=False)
             self._quarantine(req, e, fail_site, fail_attempts)
             return 0
         with events.span("serve.admit.finish"):
+            self.pool.settle_snapshots(snap_key, dst, written=True)
+            if dst is not None:
+                self.metrics.on_snapshot_write()
             if self.share_prefix:
                 self.pool.register_prefix(req.prompt, slot,
                                           req.prompt.size // bs,
@@ -1366,6 +1455,11 @@ class ServeEngine:
         self.metrics.on_decode_kv(
             int((pool.pos[pool.active] // pool.block_size + 1).sum()),
             pool.num_slots * pool.max_blocks)
+        if pool.snapshots is not None:
+            # what the tick's state updates read and write: each
+            # running slot's state once each way
+            self.metrics.on_ssm_state(
+                2 * len(self._running) * pool.slot_state_bytes)
         with events.span("serve.decode", active=len(self._running)):
             with events.span("serve.decode.dispatch"):
                 (self._toks, self.pool.caches,

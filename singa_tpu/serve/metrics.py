@@ -134,6 +134,27 @@ name                        kind       meaning
                                        ``snapshot()`` keeps the total
 ``serve.cca_tail_blocks``   gauge      keyed blocks resident then, each
                                        holding a tail to resume from
+``serve.state_snapshot_hits`` counter  one admission of a model whose
+                                       state lives in snapshots
+                                       (serve/slots.py) that entered
+                                       from one
+``serve.state_snapshot_writes`` counter  one admission that left the
+                                       state after its last shared
+                                       block as a new snapshot
+``serve.state_snapshot_evictions`` counter  a snapshot dropped, least
+                                       recently used first, for one
+                                       being written
+``serve.prefix_tokens_recomputed`` counter  rows between the snapshot
+                                       an admission entered from and
+                                       the end of its shared blocks:
+                                       prefilled again, their blocks
+                                       not rewritten
+``serve.ssm_state_bytes``   counter    bytes of per-slot recurrent
+                                       state one decode tick's updates
+                                       read and write (each running
+                                       slot's, once each way); host-
+                                       known.  ``snapshot()`` keeps the
+                                       totals of all five
 ``serve.token``             counter    one token delivered to a request
                                        (prefill first token, decode
                                        tick, recovery/preemption replay
@@ -229,6 +250,13 @@ class ServeMetrics:
         # tail can be resumed from (a level, not a total); 0 otherwise
         self.cca_state_resumes = 0
         self.cca_tail_blocks = 0
+        # models whose state lives per slot and in snapshots (a
+        # state-space layer's): serve/slots.py; 0 otherwise
+        self.state_snapshot_hits = 0
+        self.state_snapshot_writes = 0
+        self.state_snapshot_evictions = 0
+        self.prefix_tokens_recomputed = 0
+        self.ssm_state_bytes = 0
         self._accept = _Hist()
         self._ttft = _Hist()
         self._token = _Hist()
@@ -373,6 +401,33 @@ class ServeMetrics:
         events.counter("serve.cca_state_resumes", 1)
         events.gauge("serve.cca_tail_blocks", tail_blocks)
 
+    def on_snapshot_hit(self) -> None:
+        """One admission that entered from a state snapshot."""
+        self.state_snapshot_hits += 1
+        events.counter("serve.state_snapshot_hits", 1)
+
+    def on_snapshot_miss(self, recomputed: int, evicted: bool) -> None:
+        """One admission whose shared blocks run ``recomputed`` rows past
+        the snapshot it entered from (or past position 0): it prefills
+        them again; ``evicted``: a snapshot was dropped for the one it
+        will leave there."""
+        self.prefix_tokens_recomputed += recomputed
+        events.counter("serve.prefix_tokens_recomputed", recomputed)
+        if evicted:
+            self.state_snapshot_evictions += 1
+            events.counter("serve.state_snapshot_evictions", 1)
+
+    def on_snapshot_write(self) -> None:
+        """One admission that left a new state snapshot."""
+        self.state_snapshot_writes += 1
+        events.counter("serve.state_snapshot_writes", 1)
+
+    def on_ssm_state(self, nbytes: int) -> None:
+        """One decode tick whose state updates read and write ``nbytes``
+        of per-slot recurrent state."""
+        self.ssm_state_bytes += nbytes
+        events.counter("serve.ssm_state_bytes", nbytes)
+
     @property
     def accept_rate(self) -> Optional[float]:
         """Overall accepted / proposed (None before any verify round)."""
@@ -450,6 +505,11 @@ class ServeMetrics:
             "moe_assignments": self.moe_assignments,
             "cca_state_resumes": self.cca_state_resumes,
             "cca_tail_blocks": self.cca_tail_blocks,
+            "state_snapshot_hits": self.state_snapshot_hits,
+            "state_snapshot_writes": self.state_snapshot_writes,
+            "state_snapshot_evictions": self.state_snapshot_evictions,
+            "prefix_tokens_recomputed": self.prefix_tokens_recomputed,
+            "ssm_state_bytes": self.ssm_state_bytes,
             "accept_rate": self.accept_rate,
             "tokens_per_dispatch": self.tokens_per_dispatch,
             "accept_rate_hist": self._accept.summary(),

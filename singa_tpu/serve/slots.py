@@ -75,6 +75,33 @@ are full blocks of a prompt, and a prefill filled those.  Spill, the
 int8 arena and a draft model's arena know nothing of this state and
 are refused for such a model.
 
+**A layer has the cache it needs.**  ``init_caches`` may yield ``(None,
+None, *state)`` for a layer that keeps state and no keys
+(``models/granite_hybrid.py``: a state-space layer) beside ``(k, v)``
+layers: the pools then hold ``(None, None)`` there, no block of such a
+layer exists, and blocks, tables and the paged kernel serve the layers
+that have keys.  Each leaf keeps the dtype the model gives it under
+the served weights (an f32 recurrent state beside a bf16 arena).
+
+**State too heavy for a tail a block.**  Where one block's tail would
+outweigh the block's keys and values (a state-space layer's state is
+heads x head_dim x d_state values whatever the sequence's length: at
+the published sizes 38 MB a slot against 4 KB of keys and values a
+token), a tail a block is out of the question.  The pool then keeps
+the state per slot, as above, and in :attr:`snapshots`: a few entries
+on the device, each the state as it stood after one full block's last
+row, found through a host map from that block's chain key, least
+recently used out first, an entry never evicted while the admission
+that reads it runs.  An admission whose prompt matches resident keyed
+blocks up to block ``n`` maps them as ever; its prefill starts after
+the deepest block ``m <= n`` on that chain that has a snapshot (at
+position 0 from zeros), recomputes the rows of blocks ``m .. n``
+without rewriting them, and leaves the state after block ``n`` as a
+new snapshot: where two prompts part is where the next one will want
+to resume, so a tenant's system prompt is a full hit from its third
+request on, and nothing has to guess which blocks are worth 38 MB.  A
+prompt's later chunks enter from the slot's own state.
+
 **Memory hierarchy** (ISSUE 17, :mod:`singa_tpu.serve.mem`):
 ``kv_dtype="int8"`` stores either arena as int8 codes + per-position
 f32 scales (:class:`~singa_tpu.ops.kv_cache.QuantKV` — the gather/
@@ -121,6 +148,30 @@ def _chain_keys(tokens: np.ndarray, n_blocks: int, block_size: int
         prev = h.digest()
         keys.append(prev)
     return keys
+
+
+def _leaf_dtypes(spec, dtype):
+    """The dtype of every leaf of ``spec`` (``init_caches``' result,
+    abstract) under the ``dtype=`` a pool was given: None, each leaf's
+    own; one dtype, that for every leaf; a pytree of dtypes shaped like
+    ``spec`` (what the model yields under the served weights, where
+    its leaves differ: an f32 state beside bf16 keys), itself."""
+    if isinstance(dtype, (list, tuple)):
+        return dtype
+    return jax.tree.map(lambda a: a.dtype if dtype is None else dtype, spec)
+
+
+def _zeros(spec, types):
+    return jax.tree.map(lambda a, t: jnp.zeros(a.shape, t), spec, types)
+
+
+def _nbytes(tree, types=None) -> int:
+    """Bytes of the arrays (or shapes) of ``tree``, in ``types`` if
+    given."""
+    leaves = jax.tree.leaves(tree)
+    kinds = [a.dtype for a in leaves] if types is None \
+        else jax.tree.leaves(types)
+    return sum(a.size * jnp.dtype(t).itemsize for a, t in zip(leaves, kinds))
 
 
 def has_side_state(model) -> bool:
@@ -176,7 +227,11 @@ class BlockPool:
         self.kv_dtype = mem.normalize_kv_dtype(kv_dtype)
         self.draft_kv_dtype = (self.kv_dtype if draft_kv_dtype is None
                                else mem.normalize_kv_dtype(draft_kv_dtype))
-        side = has_side_state(model)
+        # what the model's layers cache, abstractly (eval_shape: nothing
+        # is allocated, so construction never holds two copies)
+        spec = jax.eval_shape(
+            lambda: model.init_caches(num_blocks, block_size))
+        side = any(len(layer) > 2 for layer in spec)
         for what, on in (("a draft model's arena (speculative decoding)",
                           draft_model is not None),
                          ("the int8 arena", self.kv_dtype == "int8"),
@@ -187,33 +242,48 @@ class BlockPool:
                     f"blocks, which {what} does not carry: a request "
                     f"resumed from such a block would read the wrong "
                     f"state")
+        # the dtype each leaf is served in
+        types = _leaf_dtypes(spec, dtype)
         if self.kv_dtype == "int8":
             # int8 arena: codes + scales replace the float pool (the
             # dtype= serving-precision override is moot — scales are
             # f32 by contract, codes are int8)
             self.caches = mem.quant_arena(model, num_blocks, block_size)
-        elif dtype is None:
+        elif dtype is None and not side:
             self.caches = model.init_caches(num_blocks, block_size)
         else:
-            # allocate straight in the serving dtype (e.g. bf16 under a
-            # param_dtype cast): eval_shape keeps the full-precision
-            # arena abstract, so construction never holds two copies
-            spec = jax.eval_shape(
-                lambda: model.init_caches(num_blocks, block_size))
-            self.caches = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, dtype), spec)
-        # side state beside the KV blocks (module docstring): the pools
-        # keep (k, v) alone, as every program and the memory tiers know
-        # them; what a layer's cache holds beyond them is kept per block
-        # (its leading axis is num_blocks here) and per slot
-        self.tails = self.slot_state = None
+            # straight in the serving dtype (e.g. bf16 under a
+            # param_dtype cast); the pools keep (k, v) alone, as every
+            # program and the memory tiers know them
+            self.caches = _zeros([c[:2] for c in spec],
+                                 [t[:2] for t in types])
+        # side state beside the KV blocks (module docstring): what a
+        # layer's cache holds beyond (k, v) is kept per slot and either
+        # per block (its leading axis is num_blocks in `spec`) or, where
+        # that would outweigh the blocks themselves, in a few snapshots
+        self.tails = self.slot_state = self.snapshots = None
         if side:
-            self.tails = [tuple(c[2:]) for c in self.caches]
-            self.caches = [tuple(c[:2]) for c in self.caches]
-            spec = jax.eval_shape(lambda: model.init_caches(num_slots, 1))
-            self.slot_state = [tuple(jnp.zeros(a.shape, t.dtype)
-                                     for a, t in zip(c[2:], tail))
-                               for c, tail in zip(spec, self.tails)]
+            state, stypes = [c[2:] for c in spec], [t[2:] for t in types]
+            rows = lambda n: jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct((n,) + a.shape[1:], a.dtype),
+                state)
+            self.slot_state = _zeros(rows(num_slots), stypes)
+            if _nbytes(state, stypes) > _nbytes(self.caches):
+                # a quarter as many entries as slots: the pool holds a
+                # slot's state for every slot already, and what is worth
+                # keeping beside them is what several slots share
+                self.snapshots = _zeros(rows(max(2, num_slots // 4)), stypes)
+            else:
+                self.tails = _zeros(state, stypes)
+        #: bytes of side state ONE slot holds across the layers
+        self.slot_state_bytes = _nbytes(self.slot_state) // num_slots \
+            if side else 0
+        # snapshots, host side: chain key -> entry, least recently used
+        # first; free entries; entries the running admission reads or
+        # writes
+        self._snap_of: "OrderedDict[bytes, int]" = OrderedDict()
+        self._snap_free: List[int] = list(range(self.snapshot_entries))
+        self._snap_busy: set = set()
         # speculative decoding (serve/spec.py): the DRAFT model's KV
         # blocks ride the SAME block tables — draft caches are a second
         # per-layer pool with identical (num_blocks, block_size) leading
@@ -238,10 +308,11 @@ class BlockPool:
             # draft arena would double the draft's KV traffic (and,
             # under self-speculation, let draft and target argmaxes
             # diverge by reading different-precision KV)
-            spec = jax.eval_shape(
+            dspec = jax.eval_shape(
                 lambda: draft_model.init_caches(num_blocks, block_size))
             self.draft_caches = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, dtype), spec)
+                lambda s: jnp.zeros(s.shape, jax.tree.leaves(types)[0]),
+                dspec)
         # an unmapped slot's row is all null block, its pos 0
         self.tables = np.zeros((num_slots, self.max_blocks), np.int32)
         self.pos = np.zeros((num_slots,), np.int32)
@@ -269,7 +340,7 @@ class BlockPool:
         #: footprint behind blocks_in_use_bytes
         self.block_bytes = mem.arena_block_bytes(self.caches,
                                                  self.draft_caches) \
-            + (mem.arena_block_bytes(self.tails) if side else 0)
+            + (mem.arena_block_bytes(self.tails) if self.tails else 0)
 
     # -- slot bookkeeping -------------------------------------------------
     @property
@@ -303,6 +374,13 @@ class BlockPool:
         draft, int8 codes AND f32 scale tensors) — blocks alone
         under-report a quantized or speculative arena's footprint."""
         return self.blocks_in_use * self.block_bytes
+
+    @property
+    def snapshot_entries(self) -> int:
+        """Entries of the snapshot pool (0 where the model's state is
+        kept a block, or there is none)."""
+        return jax.tree.leaves(self.snapshots)[0].shape[0] \
+            if self.snapshots is not None else 0
 
     @property
     def tail_blocks(self) -> int:
@@ -530,6 +608,55 @@ class BlockPool:
                     self._free_blocks.append(old)
             self._block_of[key] = block
             self._key_of[block] = key
+
+    # -- state snapshots (module docstring) --------------------------------
+    def match_snapshot(self, keys: List[bytes], n_blocks: int
+                       ) -> Tuple[int, Optional[int]]:
+        """The deepest of the chain's first ``n_blocks`` blocks that has
+        a snapshot: ``(m, entry)``, the state after block ``m``'s last
+        row standing in ``entry``; ``(0, None)`` when none has.  The
+        entry is in use until :meth:`settle_snapshots`."""
+        for m in range(min(n_blocks, len(keys)), 0, -1):
+            entry = self._snap_of.get(keys[m - 1])
+            if entry is not None:
+                self._snap_of.move_to_end(keys[m - 1])
+                self._snap_busy.add(entry)
+                return m, entry
+        return 0, None
+
+    def claim_snapshot(self, key: bytes) -> Tuple[Optional[int], bool]:
+        """An entry to write the state after the block ``key`` names
+        into: a free one, else the least recently used that no running
+        admission reads or writes.  ``(entry, whether one was evicted
+        for it)``; ``(None, False)`` when the key has a snapshot already
+        or every entry is in use.  The key maps to the entry only once
+        :meth:`settle_snapshots` keeps it."""
+        if key in self._snap_of or not self.snapshot_entries:
+            return None, False
+        evicted = not self._snap_free
+        if evicted:
+            old = next((k for k, e in self._snap_of.items()
+                        if e not in self._snap_busy), None)
+            if old is None:
+                return None, False
+            entry = self._snap_of.pop(old)
+        else:
+            entry = self._snap_free.pop()
+        self._snap_busy.add(entry)
+        return entry, evicted
+
+    def settle_snapshots(self, key: Optional[bytes] = None,
+                         entry: Optional[int] = None,
+                         written: bool = False) -> None:
+        """The admission is over: nothing is in use any more, and the
+        claimed ``entry`` holds ``key``'s state if its prefill ``written``
+        it, else it is free again."""
+        if entry is not None:
+            if written:
+                self._snap_of[key] = entry
+            else:
+                self._snap_free.append(entry)
+        self._snap_busy.clear()
 
     # -- slot mapping ------------------------------------------------------
     def map_slot(self, slot: int, blocks: List[int]) -> None:

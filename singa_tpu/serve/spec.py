@@ -68,7 +68,7 @@ from ..ops import kv_cache as kv_ops
 
 __all__ = ["make_spec_prefill", "make_verify", "verify_round",
            "VerifyDispatchFailed", "resume_on_row", "scatter_chunk",
-           "chunk_blocks"]
+           "chunk_blocks", "gather_view"]
 
 
 class VerifyDispatchFailed(RuntimeError):
@@ -83,6 +83,15 @@ class VerifyDispatchFailed(RuntimeError):
     token at a stale position and silently diverge the stream)."""
 
 
+def gather_view(ck, cv, table):
+    """``ops.kv_cache.gather_block_kv`` for one layer of an arena; a
+    layer that keeps no keys (its pools are None: serve/slots.py) has no
+    view either."""
+    if ck is None:
+        return None, None
+    return kv_ops.gather_block_kv(ck, cv, table)
+
+
 def resume_on_row(resume, params, buffers, ids, pos, row, caches,
                   entry=None, state_rows=None):
     """Gather ``row``'s dense per-layer view and run ``resume`` (a
@@ -95,7 +104,7 @@ def resume_on_row(resume, params, buffers, ids, pos, row, caches,
     layer the tuple of state arrays (1, ...) as they stood before row
     ``pos``; each layer's cache is then ``(k, v, *state)`` and comes
     back with the state after each of ``state_rows``."""
-    dense = [kv_ops.gather_block_kv(ck, cv, row) for ck, cv in caches]
+    dense = [gather_view(ck, cv, row) for ck, cv in caches]
     if entry is None:
         return resume(params, buffers, ids, pos, dense)
     dense = [kv + tuple(st) for kv, st in zip(dense, entry)]
@@ -134,6 +143,9 @@ def scatter_chunk(row, pos, fresh, caches, dense, block_size, chunk):
     wb = chunk_blocks(row, pos, fresh, bs, chunk)
     new = []
     for (ck, cv), (dk, dv) in zip(caches, dense):
+        if ck is None:                  # a layer without keys
+            new.append((ck, cv))
+            continue
         kb = jax.lax.dynamic_slice_in_dim(dk[0], pos, chunk, axis=0)
         vb = jax.lax.dynamic_slice_in_dim(dv[0], pos, chunk, axis=0)
         # one in-place block write each, not one scatter at the vector
