@@ -39,6 +39,22 @@ Greedy decode through the engine is token-identical to
 ``GenerateMixin.generate`` (same cached forward, same argmax), which
 anchors the whole subsystem's correctness to existing behavior.
 
+One tick in flight (docs/serving.md): a step DISPATCHES decode tick N
+and only then LANDS tick N-1 — fetches its tokens and delivers them —
+so the launch, the wake-up after the fetch, delivery, the caller's work
+between steps and the next step's admission all run beside a busy
+chip.  A tick's input tokens never leave the device (``decode_paged``
+takes ``toks`` and returns them, not donated), positions are the
+host's, and whether a request ends BY LENGTH is known before its token
+is: such a tick lands in the step that dispatched it, so a finished
+request's successor finds an empty device queue.  Landing skips a
+participant whose slot no longer runs that request (evicted,
+pre-empted, withdrawn, finished by an EOS one landing ago): the token
+is dropped, and a replay regenerates it bit for bit.  Whatever reads a
+request's tokens or moves a slot from outside a step lands first.  The
+speculative engine does not run ahead: its accepted count decides the
+positions.
+
 Admission counts FREE BLOCKS, not slots: a request needs a table row
 AND enough blocks for its prompt (minus the shared prefix), and decode
 grows a slot by one block when its position crosses a block boundary.
@@ -227,7 +243,9 @@ class ServeEngine:
 
     ``step()`` advances the whole arena by one decode tick (evict →
     admit/prefill → decode), delivering one token to every live request
-    and invoking their streaming ``on_token`` callbacks.
+    and invoking their streaming ``on_token`` callbacks: the token of
+    the tick the step BEFORE dispatched, while the chip runs this
+    step's (module docstring, "One tick in flight").
 
     ``num_blocks`` sizes the physical block pool (default: capacity
     parity with a fixed ``(num_slots, max_len)`` arena); a SMALLER pool
@@ -422,6 +440,13 @@ class ServeEngine:
         # uploaded, so the decode hot loop is one dispatch + one tiny
         # fetch per tick
         self._toks = jnp.zeros((num_slots,), jnp.int32)
+        # decode ticks dispatched and not landed yet, oldest first:
+        # (the tick's token array, its (slot, request) pairs, when it
+        # was dispatched).  One between steps at most; two only inside
+        # _decode_tick, between dispatching tick N and landing N-1
+        self._flying: List[Tuple[object, List[Tuple[int, Request]],
+                                 float]] = []
+        self._landed_at = 0.0       # perf_counter of the last landing
 
         # ---- the exactly-two compiled programs --------------------------
         # (plus the optional third: the fixed-shape handoff gather a
@@ -670,7 +695,9 @@ class ServeEngine:
         side state where the model keeps one (``k`` and ``v`` None for a
         layer that keeps state alone).  For a check of what the
         programs wrote against a reference; it fetches from the device
-        and belongs in no step."""
+        and belongs in no step.  Lands the tick in flight first, so the
+        rows are those of the tokens the request has been handed."""
+        self._land()
         n = int(self.pool.pos[slot])
         row = self.pool.tables[slot:slot + 1]
         out = []
@@ -786,7 +813,10 @@ class ServeEngine:
     def running_items(self) -> List[Tuple[int, Request]]:
         """(slot, request) pairs currently occupying slots, slot order —
         the router's per-tick view of what a prefill worker has ready to
-        hand off (a snapshot: handing off mutates ``_running``)."""
+        hand off (a snapshot: handing off mutates ``_running``).  Lands
+        the tick in flight first: the caller reads the requests' tokens
+        and positions."""
+        self._land()
         return sorted(self._running.items())
 
     def withdraw(self, slot: int) -> Request:
@@ -794,7 +824,10 @@ class ServeEngine:
         it: the slot and its blocks are released, the request keeps its
         prompt + tokens-so-far and goes back to QUEUED — the router's
         re-route primitive (greedy decode makes the replay elsewhere
-        reproduce the exact stream, same argument as preemption)."""
+        reproduce the exact stream, same argument as preemption).
+        Lands the tick in flight first (a request that landing ends is
+        gone from its slot: ``KeyError``, as for any empty slot)."""
+        self._land()
         req = self._running.pop(slot)
         self.pool.release(slot)
         req.slot = None
@@ -817,6 +850,7 @@ class ServeEngine:
         request now lives in the package until injected elsewhere."""
         from .disagg import handoff as _handoff_mod
         self._refuse_handoff()
+        self._land()        # the package carries the request's tokens
         return _handoff_mod.extract(self, slot)
 
     def inject_handoff(self, pkg) -> bool:
@@ -944,10 +978,15 @@ class ServeEngine:
         """One continuous-batching tick: recovery (if requested by the
         hang watchdog) → deadline eviction → overload shedding →
         admission (prefill queued requests into free slots while free
-        blocks cover them) → block-table growth → one decode over all
-        active slots.  Returns the number of tokens delivered.
+        blocks cover them) → block-table growth → the dispatch of one
+        decode over all active slots → the LANDING of the decode the
+        step before dispatched (its tokens fetched and delivered:
+        :meth:`_decode_tick`).  A tick that ends a request by length
+        lands in its own step.  Returns the number of tokens delivered,
+        first tokens of admissions included.
 
-        ``decode=False`` stops after admission — the disaggregated
+        ``decode=False`` lands what is in flight and stops after
+        admission — the disaggregated
         tier's PREFILL-WORKER tick: freshly prefilled requests stay in
         their slots (blocks intact) for the router to hand off to a
         decode worker instead of decoding here.  Deadline eviction
@@ -1003,26 +1042,32 @@ class ServeEngine:
                 delivered += self._admit(req)
 
             # 3. block-table growth + one decode tick over the whole
-            #    arena; a decode (or a decode-time block allocation)
-            #    that died past its retry budget escalates to an arena
-            #    rebuild + re-prefill instead of crashing the engine
-            if self._running and decode:
-                try:
+            #    arena, dispatched BEFORE the tick in flight lands; a
+            #    decode (or a decode-time block allocation, or the
+            #    landing's fetch) that died past its retry budget
+            #    escalates to an arena rebuild + re-prefill instead of
+            #    crashing the engine
+            try:
+                if self._running and decode:
                     with events.span("serve.grow"):
                         self._ensure_blocks()
-                    if self._running:
-                        delivered += (self._spec_tick()
-                                      if self._verify is not None
-                                      else self._decode_tick())
-                except (RuntimeError, OSError) as e:
-                    if isinstance(e, failure.FailureDetected):
-                        raise
-                    self._recover(f"decode: {type(e).__name__}: {e}")
+                if self._running and decode:
+                    delivered += (self._spec_tick()
+                                  if self._verify is not None
+                                  else self._decode_tick())
+                else:
+                    # nothing to dispatch behind it
+                    delivered += self._land()
+            except (RuntimeError, OSError) as e:
+                if isinstance(e, failure.FailureDetected):
+                    raise
+                self._recover(f"decode: {type(e).__name__}: {e}")
 
             with events.span("serve.step.tail"):
-                # settle spill payloads onto host numpy AFTER the tick's
-                # token-extraction sync: the D2H copies are already
-                # done, so this collects without waiting, and
+                # settle spill payloads onto host numpy AFTER the
+                # landing's token-extraction sync: the copies this step
+                # queued stand before the tick it dispatched, so this
+                # collects without waiting out that tick, and
                 # device-side spill buffers live at most one tick
                 if self._spill is not None:
                     self._spill.settle()
@@ -1062,7 +1107,7 @@ class ServeEngine:
         tick — a hung decode (dead device, hung collective) aborts cleanly
         instead of wedging the server, or, with ``recover_on_hang``,
         requests an arena rebuild + re-prefill at the next step
-        boundary."""
+        boundary.  Returns with no tick in flight."""
         hb = Heartbeat(timeout=self.heartbeat_timeout_s,
                        on_failure=(self._hb_failure if self.recover_on_hang
                                    else self._on_failure)) \
@@ -1082,6 +1127,8 @@ class ServeEngine:
                         hb.start()
                 if max_steps is not None and n >= max_steps:
                     break
+            # the last tick: nothing is dispatched behind it
+            self._land()
         if not self.pending:
             # a fully drained system is proof the last recovery took —
             # give future incidents a fresh rebuild budget, and drop any
@@ -1422,11 +1469,14 @@ class ServeEngine:
             if req is None:
                 continue
             bs = self.pool.block_size
-            # a verify round writes up to position pos + spec_k (the
-            # full k+1 window), so a speculative slot needs its blocks
-            # mapped spec_k positions ahead of a plain one
-            need = (req.prompt.size + len(req.tokens)
-                    + self.spec_k) // bs + 1
+            # the slot's position is prompt + tokens delivered - 1,
+            # plus 1 with a tick in flight, whose token is not
+            # delivered yet: the tick about to be dispatched writes
+            # there, and the row after it is kept mapped too.  A verify
+            # round writes up to position pos + spec_k (the full k+1
+            # window), so a speculative slot needs its blocks mapped
+            # spec_k positions ahead of a plain one
+            need = (int(self.pool.pos[slot]) + 1 + self.spec_k) // bs + 1
             while slot in self._running and \
                     self.pool.mapped_count(slot) < need:
                 got = self._alloc_blocks(1, req.rid)
@@ -1447,7 +1497,15 @@ class ServeEngine:
             self.metrics.on_preempt()
 
     def _decode_tick(self) -> int:
-        t0 = time.perf_counter()
+        """Dispatch decode tick N, then land tick N-1 (:meth:`_land`):
+        the chip holds N while the host fetches and delivers N-1, ends
+        the step, does the caller's work and starts the next step.  A
+        tick the host already knows to end a request BY LENGTH lands
+        here too, and the step returns with nothing in flight: the
+        successor the caller then submits finds an empty device queue
+        for its prefill, as it did before ticks ran ahead.  So does the
+        plain tick a speculative engine falls back to, whose next
+        verify round reads the positions."""
         pool = self.pool
         # what this tick's attention has to read, from the host's own
         # slot state (no device read): each active slot's blocks up to
@@ -1462,6 +1520,7 @@ class ServeEngine:
                 2 * len(self._running) * pool.slot_state_bytes)
         with events.span("serve.decode", active=len(self._running)):
             with events.span("serve.decode.dispatch"):
+                t0 = time.perf_counter()
                 (self._toks, self.pool.caches,
                  self.pool.slot_state) = self._dispatch(
                     "serve.decode", self._decode,
@@ -1473,28 +1532,62 @@ class ServeEngine:
                 if self._moe_top_k:
                     self.metrics.on_moe_dispatch(
                         len(self._running) * self._moe_top_k)
-            with events.span("serve.decode.fetch"):
-                toks = np.asarray(self._toks)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic
-        dt = time.perf_counter() - t0
+                self.metrics.on_decode_tick(ahead=bool(self._flying))
+                # ``toks`` is not donated: this tick's array stays
+                # readable after the next tick was dispatched on it
+                pairs = list(self._running.items())
+                self._flying.append((self._toks, pairs, t0))
+        delivered = self._land(keep=1)
+        if self._verify is not None or any(
+                self._running.get(slot) is req
+                and len(req.tokens) + 1 >= req.max_new_tokens
+                for slot, req in pairs):
+            delivered += self._land()
+        return delivered
+
+    def _land(self, keep: int = 0) -> int:
+        """Fetch and deliver the ticks in flight, oldest first, but for
+        the newest ``keep``.  A participant whose slot no longer runs
+        that same request gets nothing: it was evicted by deadline,
+        pre-empted, withdrawn or rebuilt since the dispatch (it replays
+        from prompt + tokens delivered, and greedy decode picks the
+        dropped token again), or the landing before this one handed it
+        its EOS (the run-ahead tick wrote one row past it into the
+        request's own block, and nothing is delivered after an EOS).
+        A tick none of whose participants is left is dropped unfetched.
+        Returns the number of tokens delivered."""
         delivered = 0
-        with events.span("serve.deliver"):
-            for slot in list(self._running):
-                req = self._running[slot]
-                tok = int(toks[slot])
-                # one batched decode dispatch delivers to many requests;
-                # the per-request section runs under each request's
-                # trace so its token events attribute correctly
-                with obs_trace.activate(req.trace_id):
-                    done = req.deliver(tok)
-                    self.metrics.on_token(dt)
-                    self.metrics.on_deliver(req.rid, len(req.tokens))
-                    self.metrics.on_slot_dispatch(1)
-                if req.on_token is not None:
-                    req.on_token(tok, req.handle)
-                delivered += 1
-                if done:
-                    self._finalize(slot)
-            self._note_tpt(delivered, delivered)
+        while len(self._flying) > keep:
+            arr, pairs, t0 = self._flying.pop(0)
+            pairs = [(slot, req) for slot, req in pairs
+                     if self._running.get(slot) is req]
+            if not pairs:
+                continue
+            with events.span("serve.decode.fetch"):
+                toks = np.asarray(arr)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic, and the chip holds the next tick while a run-ahead tick's is made
+            # the time between two landings; from its own dispatch for a
+            # tick dispatched with nothing in flight
+            now = time.perf_counter()
+            dt = now - max(self._landed_at, t0)
+            self._landed_at = now
+            with events.span("serve.deliver"):
+                for slot, req in pairs:
+                    tok = int(toks[slot])
+                    # one batched decode dispatch delivers to many
+                    # requests; the per-request section runs under each
+                    # request's trace so its token events attribute
+                    # correctly
+                    with obs_trace.activate(req.trace_id):
+                        done = req.deliver(tok)
+                        self.metrics.on_token(dt)
+                        self.metrics.on_deliver(req.rid, len(req.tokens))
+                        self.metrics.on_slot_dispatch(1)
+                    if req.on_token is not None:
+                        req.on_token(tok, req.handle)
+                    if done:
+                        self._finalize(slot)
+                self._note_tpt(len(pairs), len(pairs))
+            delivered += len(pairs)
         return delivered
 
     def _spec_tick(self) -> int:
@@ -1553,7 +1646,13 @@ class ServeEngine:
         final streams are bit-identical to an uninterrupted run.
         (Chunked prefill has no prompt-length cap below ``max_len``, so
         — unlike the PR 2 fixed arena — every in-flight replay is
-        recoverable.)"""
+        recoverable.)  Lands the tick in flight first, if the device
+        still yields it; the rebuild drops what it does not."""
+        try:
+            self._land()
+        except (RuntimeError, OSError) as e:
+            if isinstance(e, failure.FailureDetected):
+                raise
         self._recover(reason)
 
     def _recover(self, reason: str) -> None:
@@ -1567,6 +1666,9 @@ class ServeEngine:
         with events.span("serve.recover", reason=reason):
             inflight = sorted(self._running.values(), key=lambda r: r.rid)
             self._running.clear()
+            # a tick in flight read the old arena: its tokens are
+            # dropped, and the replays pick them again
+            self._flying.clear()
             # fresh arena + tables + token buffer: same shapes/dtypes,
             # so the two compiled programs are reused — recovery never
             # recompiles.  The prefix cache dies with the old pool

@@ -76,13 +76,25 @@ name                        kind       meaning
                                        and callback
 ``serve.grow``              span       decode-time block-table growth
                                        (and any preemption it triggers)
-``serve.decode``            span       one decode tick: dispatch + fetch
+``serve.decode``            span       the dispatch of one decode tick
 ``serve.decode.dispatch``   span       the guarded dispatch of
                                        ``decode_paged``
-``serve.decode.fetch``      span       the tick's blocking token fetch
+``serve.decode.fetch``      span       a landing's blocking token fetch:
+                                       of the tick dispatched a step
+                                       EARLIER where ticks run ahead, so
+                                       it opens after the next tick's
+                                       ``serve.decode.dispatch`` closed
 ``serve.deliver``           span       the per-slot loop after the fetch:
                                        delivery, metrics, flight notes,
                                        ``on_token`` callbacks, finalize
+``serve.decode_ticks``      counter    one dispatch of ``decode_paged``
+                                       (``ahead`` attr: another tick was
+                                       in flight, so this one's launch
+                                       and the other's landing ran
+                                       beside a busy chip).
+                                       ``snapshot()`` keeps
+                                       ``decode_ticks`` and
+                                       ``decode_ticks_ahead``; host-known
 ``serve.step.tail``         span       spill settle, per-step gauges,
                                        the tick EWMA
 ``serve.verify``            span       one speculative verify round
@@ -161,7 +173,9 @@ name                        kind       meaning
                                        — tokens/s is derivable from the
                                        trace by counting these)
 ``serve.ttft_ms``           histogram  submit → first token
-``serve.token_ms``          histogram  per generated token, decode path
+``serve.token_ms``          histogram  per generated token, decode path:
+                                       the time between two landings
+                                       (``on_token``)
 ==========================  =========  ==================================
 
 Counters/gauges cost one attribute check when no sink is configured;
@@ -241,6 +255,10 @@ class ServeMetrics:
         # the blocks a dense view of every slot's table row spans
         self.decode_kv_blocks_live = 0
         self.decode_kv_blocks_view = 0
+        # dispatches of the decode program, and those made while the
+        # tick before was still in flight (serve/engine.py)
+        self.decode_ticks = 0
+        self.decode_ticks_ahead = 0
         # mixture-of-experts models: dispatches that ran the router and
         # the (token, expert) pairs they routed; both 0 for a dense model
         self.moe_dispatches = 0
@@ -384,6 +402,13 @@ class ServeMetrics:
         events.counter("serve.decode_kv_blocks_live", live)
         events.counter("serve.decode_kv_blocks_view", view)
 
+    def on_decode_tick(self, ahead: bool) -> None:
+        """One dispatch of the decode program; ``ahead``: the tick
+        before it had not landed yet."""
+        self.decode_ticks += 1
+        self.decode_ticks_ahead += ahead
+        events.counter("serve.decode_ticks", 1, ahead=ahead)
+
     def on_moe_dispatch(self, assignments: int) -> None:
         """One prefill chunk or decode tick of a mixture-of-experts
         model: ``assignments`` = its valid tokens x top-k."""
@@ -451,6 +476,12 @@ class ServeMetrics:
         self._note("hist", "serve.ttft_ms", value=ttft_s * 1e3)
 
     def on_token(self, latency_s: float) -> None:
+        """One token of a decode tick, ``latency_s`` after the landing
+        before its own (after its dispatch, for a tick dispatched with
+        nothing in flight): with ticks running ahead that is the pace
+        tokens reach a request at, not a dispatch's round trip.  A
+        verify round's: its dispatch to its fetch, over the tokens it
+        yielded the slot."""
         self._token.observe(latency_s * 1e3)
         events.histogram("serve.token_ms", latency_s * 1e3)
 
@@ -501,6 +532,8 @@ class ServeMetrics:
             "prefill_chunk_rows": self.prefill_chunk_rows,
             "decode_kv_blocks_live": self.decode_kv_blocks_live,
             "decode_kv_blocks_view": self.decode_kv_blocks_view,
+            "decode_ticks": self.decode_ticks,
+            "decode_ticks_ahead": self.decode_ticks_ahead,
             "moe_dispatches": self.moe_dispatches,
             "moe_assignments": self.moe_assignments,
             "cca_state_resumes": self.cca_state_resumes,

@@ -174,7 +174,8 @@ class _WorkerServer:
                     "accept": self.engine.can_accept_handoff(pkg)}, b""
         if direction == "extract":
             slot = int(hdr["slot"])
-            req = self.engine._running.get(slot)
+            # (running_items lands a tick in flight first)
+            req = dict(self.engine.running_items()).get(slot)
             if req is None or req.rid != hdr.get("rid"):
                 return {"ok": False, "err": "slot_moved"}, b""
             t0 = time.perf_counter()
@@ -214,7 +215,7 @@ class _WorkerServer:
         failure recovery: the request replays on another worker, so this
         engine just forgets it."""
         slot = int(hdr["slot"])
-        req = self.engine._running.get(slot)
+        req = dict(self.engine.running_items()).get(slot)
         if req is None or (hdr.get("rid") is not None
                            and req.rid != hdr["rid"]):
             return {"ok": False, "err": "slot_moved"}

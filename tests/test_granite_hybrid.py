@@ -549,6 +549,30 @@ def test_mixed_batch_is_greedy_with_one_program_each(granite):
     assert snap["ssm_state_bytes"] > 0 and snap["moe_assignments"] > 0
 
 
+@pytest.mark.parametrize("at", [1, 4])
+def test_an_eos_under_a_tick_in_flight_leaves_the_slot_clean(granite, at):
+    """ISSUE 35: the request whose EOS lands took part in the tick
+    dispatched before that landing, which moved the slot's recurrent
+    state once more; nothing follows the EOS, and the next request in
+    the slot enters from zeros or a snapshot, not from what was left."""
+    eng = ServeEngine(granite, num_slots=1, max_len=MAX_LEN, block_size=BS)
+    first, second = _ids(20, 70), _ids(12, 71)
+    ref = [int(t) for t in
+           granite.generate(first[None], max_new_tokens=10)[0, 20:]]
+    eos = ref[at]
+    a = eng.submit(first, max_new_tokens=10, eos_id=eos)
+    b = eng.submit(second, max_new_tokens=8)
+    while eng.pending:
+        eng.step()
+    assert a.finish_reason == "eos"
+    assert a.tokens == ref[:ref.index(eos) + 1]
+    np.testing.assert_array_equal(
+        np.asarray(b.result()),
+        granite.generate(second[None], max_new_tokens=8)[0])
+    snap = eng.metrics.snapshot()
+    assert snap["decode_ticks_ahead"] > 0 and eng.compiled_counts() == (1, 1)
+
+
 # -- the pool: what is allocated, and the snapshots' host side ---------------
 
 def test_only_the_attention_layer_has_blocks(granite):

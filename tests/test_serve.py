@@ -1076,6 +1076,276 @@ class TestSlotStateOnTheHost:
         assert set(built) == programs and len(built) == 2
 
 
+def _ref(model, prompt, new):
+    return [int(t) for t in
+            model.generate(prompt[None], max_new_tokens=new)[0, prompt.size:]]
+
+
+def _eos_of(ref, at):
+    """An `eos_id` that stops the greedy stream `ref` at or before index
+    `at`, and the stream it leaves (the EOS kept)."""
+    eos = ref[at]
+    return eos, ref[:ref.index(eos) + 1]
+
+
+def _fly(eng, steps=3):
+    """Step until a decode tick is in flight (no finish in `steps`)."""
+    for _ in range(steps):
+        eng.step()
+    assert len(eng._flying) == 1
+    return eng._flying[0]
+
+
+class TestOneTickInFlight:
+    """ISSUE 35: a step dispatches decode tick N and then lands tick
+    N-1.  The streams stay `generate()`'s through everything that can
+    happen to a request between its tick's dispatch and its landing,
+    and both programs are what they were."""
+
+    @pytest.mark.parametrize("slots,lens,new", [
+        (4, [3, 5, 8, 11], [9, 4, 12, 7, 2, 10]),   # waves of admissions
+        (2, [7, 12, 5], [12, 1, 6, 3, 9]),          # a request of one token
+        (1, [6, 9], [5, 8, 2])])                    # one slot, in turn
+    def test_streams_are_generates(self, llama, slots, lens, new):
+        """Mixed lengths, admissions beside ticks in flight, finishes by
+        length at unequal ticks, driven by bare `step()`s: nothing but
+        the engine itself lands a tick."""
+        eng = ServeEngine(llama, num_slots=slots, max_len=32, block_size=8,
+                          max_queue=len(new))
+        prompts = _prompts(len(new), lens, seed=51)
+        hs = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+        while eng.pending:
+            eng.step()
+        for p, n, h in zip(prompts, new, hs):
+            assert h.tokens == _ref(llama, p, n)
+            assert h.finish_reason == "length"
+        snap = eng.metrics.snapshot()
+        assert 0 < snap["decode_ticks_ahead"] < snap["decode_ticks"]
+        assert not eng._flying
+        assert eng.pool.free_count == slots and (eng.pool.ref == 0).all()
+        assert_program_count(eng, (1, 1))
+
+    @pytest.mark.parametrize("at", [0, 2, 5])
+    def test_nothing_after_an_eos_and_the_successor_is_right(self, llama,
+                                                             at):
+        """The request whose EOS lands took part in the tick dispatched
+        before the landing: that tick's token is dropped, its row went
+        into the request's own block, and the next request in the slot
+        (one slot: the same) reads nothing of it."""
+        eng = ServeEngine(llama, num_slots=1, max_len=32, block_size=8)
+        first, second = _prompts(2, [6, 9], seed=53)
+        eos, want = _eos_of(_ref(llama, first, 10), at)
+        seen = []
+        a = eng.submit(first, max_new_tokens=10, eos_id=eos,
+                       on_token=lambda t, h: seen.append(t))
+        b = eng.submit(second, max_new_tokens=7)
+        while eng.pending:
+            eng.step()
+        assert a.finish_reason == "eos" and a.tokens == want == seen
+        assert b.tokens == _ref(llama, second, 7)
+        assert not eng._flying and (eng.pool.ref == 0).all()
+
+    def test_a_deadline_eviction_with_a_tick_in_flight(self, llama):
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        p0, p1, p2 = _prompts(3, [7, 5, 9], seed=55)
+        gone = eng.submit(p0, max_new_tokens=20, deadline_s=60.0)
+        stays = eng.submit(p1, max_new_tokens=12)
+        _, pairs, _ = _fly(eng)
+        assert len(pairs) == 2
+        gone._req.deadline = 0.0            # passed, as the next step sees
+        n = len(gone.tokens)
+        after = eng.submit(p2, max_new_tokens=6)    # takes the freed slot
+        eng.step()
+        assert gone.finish_reason == "deadline" and len(gone.tokens) == n
+        assert gone.tokens == _ref(llama, p0, 20)[:n]
+        eng.run_until_idle()
+        assert stays.tokens == _ref(llama, p1, 12)
+        assert after.tokens == _ref(llama, p2, 6)
+
+    def test_a_preemption_across_a_block_boundary(self, llama):
+        """Five usable blocks under two requests that each need three:
+        growth pre-empts the youngest while the tick it took part in is
+        in flight; it replays from prompt + tokens delivered."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8,
+                          num_blocks=6)
+        prompts = _prompts(2, [7], seed=37)
+        hs = [eng.submit(p, max_new_tokens=16) for p in prompts]
+        dropped = 0
+        while eng.pending:
+            before = eng.metrics.preempted, list(eng._flying)
+            eng.step()
+            if eng.metrics.preempted > before[0] and before[1]:
+                dropped += 1
+        assert dropped >= 1
+        for p, h in zip(prompts, hs):
+            assert h.tokens == _ref(llama, p, 16)
+        assert_program_count(eng, (1, 1))
+
+    @pytest.mark.parametrize("how", ["recover", "heartbeat", "withdraw",
+                                     "extract_handoff", "running_items",
+                                     "slot_cache", "decode_false"])
+    def test_moved_with_a_tick_in_flight(self, llama, how):
+        """Whatever reads the requests' tokens or moves a slot from
+        outside a step lands first (`recover` as far as the device
+        yields the tick; the rebuild a hang asks for drops it)."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        prompts = _prompts(2, [7, 12], seed=57)
+        hs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        _fly(eng)
+        n = [len(h.tokens) for h in hs]
+        landed = [k + 1 for k in n]
+        if how == "recover":
+            eng.recover("test")
+        elif how == "heartbeat":
+            eng._recover_flag.set()         # the monitor thread's request
+            eng.step()
+            assert eng.metrics.recoveries == 1
+            # dropped; re-prefilled, and the next tick dispatched
+            landed = [k + 1 for k in n]
+            assert [r for _, r in eng._flying[0][1]] == [h._req for h in hs]
+        elif how == "withdraw":
+            req = eng.withdraw(0)
+            assert len(req.tokens) == landed[0]
+            eng.sched.requeue_front([req])
+        elif how == "extract_handoff":
+            other = ServeEngine(llama, num_slots=2, max_len=32,
+                                block_size=8, programs=eng.programs())
+            pkg = eng.extract_handoff(0)
+            assert pkg.pos == pkg.req.replay_ids().size - 1
+            assert len(pkg.req.tokens) == landed[0]
+            assert other.inject_handoff(pkg)
+        elif how == "running_items":
+            assert [s for s, _ in eng.running_items()] == [0, 1]
+        elif how == "slot_cache":
+            k, _ = eng.slot_cache(1)[0]
+            assert k.shape[0] == prompts[1].size + landed[1] - 1
+        else:
+            eng.step(decode=False)
+        assert how == "heartbeat" or not eng._flying
+        assert [len(h.tokens) for h in hs] == landed
+        for e in (eng, other) if how == "extract_handoff" else (eng,):
+            e.run_until_idle()
+        for p, h in zip(prompts, hs):
+            assert h.tokens == _ref(llama, p, 14)
+
+    @pytest.mark.parametrize("how", ["run_until_idle", "cut", "drain",
+                                     "close"])
+    def test_the_last_tick_lands(self, llama, how):
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        prompts = _prompts(2, [7, 12], seed=59)
+        ref = _ref(llama, prompts[0], 12)
+        eos, want = _eos_of(ref, 6)
+        # the EOS ends the run with a tick in flight that nobody is
+        # left to take a token from
+        a = eng.submit(prompts[0], max_new_tokens=12, eos_id=eos)
+        b = eng.submit(prompts[1], max_new_tokens=5)
+        if how == "cut":
+            eng.run_until_idle(max_steps=2)
+            assert eng.pending and not eng._flying
+            assert len(a.tokens) == 3       # prefill's, and both ticks'
+            eng.run_until_idle()
+        else:
+            getattr(eng, how)()
+        assert a.tokens == want and b.tokens == _ref(llama, prompts[1], 5)
+        assert eng._closed or not eng._flying
+
+    def test_a_finish_by_length_leaves_nothing_in_flight(self, llama):
+        """Rule 4: the host knows before the token does that a tick ends
+        a request by length, and lands it in its own step, so that the
+        successor a closed loop submits after `step()` prefills on an
+        empty device queue.  Every other step leaves one tick in
+        flight."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        prompts = _prompts(4, [7, 5, 9, 4], seed=61)
+        new = [6, 9, 4, 7]
+        hs = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+        ended, ahead = 0, 0
+        while eng.pending:
+            done = sum(h.done for h in hs)
+            eng.step()
+            if sum(h.done for h in hs) > done:
+                ended += 1
+                assert not eng._flying
+            else:
+                ahead += 1
+                assert len(eng._flying) == 1
+        assert ended >= 3 and ahead >= 3
+        for p, n, h in zip(prompts, new, hs):
+            assert h.tokens == _ref(llama, p, n)
+
+    def test_each_dispatch_opens_before_the_fetch_of_the_tick_before(
+            self, llama, host_profile, tmp_path):
+        """A run without finishes: on the host's line every
+        `serve.decode.dispatch` but the first closes before the
+        `serve.decode.fetch` of the tick before it opens, and the
+        counter says the same."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        eng.submit(_prompts(1, [5])[0], max_new_tokens=2)
+        eng.run_until_idle()                    # compile outside the session
+        snap0 = eng.metrics.snapshot()
+        for p in _prompts(2, [7, 5], seed=63):
+            eng.submit(p, max_new_tokens=20)
+        with host_profile(tmp_path, ("serve.",)) as lines:
+            for _ in range(8):
+                eng.step()
+        (line,) = lines
+        sent = [(s, e) for n, s, e, _ in line if n == "serve.decode.dispatch"]
+        fetched = [(s, e) for n, s, e, _ in line if n == "serve.decode.fetch"]
+        delivers = [(s, e) for n, s, e, _ in line if n == "serve.deliver"]
+        assert (len(sent), len(fetched), len(delivers)) == (8, 7, 7)
+        for tick in range(1, 8):
+            assert sent[tick][1] <= fetched[tick - 1][0]
+            assert fetched[tick - 1][1] <= delivers[tick - 1][0]
+            if tick < 7:
+                assert delivers[tick - 1][1] <= sent[tick + 1][0]
+        snap = eng.metrics.snapshot()
+        assert snap["decode_ticks"] - snap0["decode_ticks"] == 8
+        assert snap["decode_ticks_ahead"] - snap0["decode_ticks_ahead"] == 7
+        assert snap["token_ms"]["count"] - snap0["token_ms"]["count"] == 14
+        eng.close()
+
+    @pytest.mark.parametrize("fault", [False, True])
+    def test_the_speculative_engine_never_runs_ahead(self, llama, fault):
+        """Its accepted count decides the positions; the plain tick a
+        failed verify falls back to lands in its own step too."""
+        import warnings
+        from singa_tpu import faults
+        from singa_tpu.faults.plan import FaultPlan, FaultSpec
+        eng = _engine_of("speculative", llama, num_slots=2, max_len=32,
+                         block_size=8)
+        prompts = _prompts(2, [7, 5], seed=65)
+        plan = FaultPlan([FaultSpec("serve.verify", "error", every=1,
+                                    times=3)] if fault else [])
+        with faults.active(plan), warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            hs = [eng.submit(p, max_new_tokens=12) for p in prompts]
+            while eng.pending:
+                eng.step()
+                assert not eng._flying
+        for p, h in zip(prompts, hs):
+            assert h.tokens == _ref(llama, p, 12)
+        snap = eng.metrics.snapshot()
+        assert snap["decode_ticks_ahead"] == 0
+        assert snap["decode_ticks"] == snap["spec_fallbacks"] == int(fault)
+
+    @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+    def test_the_programs_are_what_they_were(self, llama, program):
+        """The order of a step is the host's: an engine that has run
+        ahead lowers the text of one that has not stepped, and the text
+        names one token vector in and one out."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        text = eng.lower_programs(names=(program,))[program].as_text()
+        hs = [eng.submit(p, max_new_tokens=9)
+              for p in _prompts(3, [7, 12, 5], seed=67)]
+        while eng.pending:
+            eng.step()
+        assert all(h.finish_reason == "length" for h in hs)
+        assert eng.metrics.snapshot()["decode_ticks_ahead"] > 0
+        assert eng.compiled_counts() == (1, 1)
+        assert eng.lower_programs(names=(program,))[program].as_text() == text
+        assert text.count("tensor<2xi32>") >= 2
+
+
 def test_loadgen_quick_run_emits_valid_record(llama, engine, tmp_path):
     """tools/loadgen.py end-to-end against the shared engine: an
     open-loop burst completes, every request is accounted for

@@ -417,6 +417,30 @@ def test_mixed_batch_is_greedy_with_one_program_each(zaya):
     assert snap["moe_assignments"] > 0
 
 
+@pytest.mark.parametrize("at", [1, 4])
+def test_an_eos_under_a_tick_in_flight_leaves_the_slot_clean(zaya, at):
+    """ISSUE 35: the request whose EOS lands took part in the tick
+    dispatched before that landing, which moved the slot's side state
+    once more and wrote one row past the EOS; nothing follows the EOS,
+    and the next request in the slot starts from its own state."""
+    eng = ServeEngine(zaya, num_slots=1, max_len=MAX_LEN, block_size=BS)
+    first, second = _ids(20, 70), _ids(12, 71)
+    ref = [int(t) for t in
+           zaya.generate(first[None], max_new_tokens=10)[0, 20:]]
+    eos = ref[at]
+    a = eng.submit(first, max_new_tokens=10, eos_id=eos)
+    b = eng.submit(second, max_new_tokens=8)
+    while eng.pending:
+        eng.step()
+    assert a.finish_reason == "eos"
+    assert a.tokens == ref[:ref.index(eos) + 1]
+    np.testing.assert_array_equal(
+        np.asarray(b.result()),
+        zaya.generate(second[None], max_new_tokens=8)[0])
+    snap = eng.metrics.snapshot()
+    assert snap["decode_ticks_ahead"] > 0 and eng.compiled_counts() == (1, 1)
+
+
 def test_engine_keeps_host_slot_state_and_device_side_state(zaya):
     """What PR 31 made of the slot state stays: tables, pos and active
     are host numpy; the side state is on the device, donated with the
