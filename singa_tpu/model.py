@@ -345,6 +345,9 @@ class _StepExecutor:
         return ex
 
     def __init__(self, model: Model, tag: str, body, example_arrays):
+        # a full collector pass in a step loop shows in its profile
+        # (``py.gc``) and in ``py.gc_pause_ms``
+        obs_events.watch_gc()
         self.model = model
         self.tag = tag
         self.body = body

@@ -37,11 +37,13 @@ Semantics worth knowing before reading the numbers:
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
 import warnings
+from collections import deque
 from typing import Any, Dict, Optional
 
 from jax.profiler import TraceAnnotation
@@ -50,7 +52,7 @@ from . import trace
 
 __all__ = ["JsonlSink", "configure", "enabled", "get_sink", "span",
            "counter", "gauge", "histogram", "histogram_summary",
-           "reset_histograms"]
+           "reset_histograms", "watch_gc", "gc_pause_ms"]
 
 
 class JsonlSink:
@@ -306,6 +308,8 @@ def histogram_summary(name: str) -> Optional[Dict[str, Any]]:
     None when nothing was observed.  count/sum/min/max are exact over
     every observation; percentiles come from the retained ring (the
     most recent ``_HIST_CAP`` samples)."""
+    if _gc_pending:
+        _observe_gc_pauses()
     with _hist_lock:
         h = _hists.get(name)
         return h.summary() if h is not None else None
@@ -380,6 +384,81 @@ def span(name: str, **attrs):
     if _sink is None:
         return TraceAnnotation(name, **attrs)
     return _Span(name, attrs)
+
+
+#: a collector pause shorter than this is neither observed nor charged
+#: to a turn: a young-generation pass takes tens of microseconds and
+#: runs many times a step, a pass worth knowing of takes milliseconds
+_GC_PAUSE_MIN_S = 1e-3
+
+#: thread ident -> (when the pass now running on it started, the
+#: annotation it runs under or None)
+_gc_open: Dict[int, tuple] = {}
+#: thread ident -> the milliseconds of pauses charged to it so far
+_gc_ms: Dict[int, float] = {}
+#: (pause in ms, generation) of pauses not yet observed: the call-back
+#: only notes them, the next reader observes them (bounded: a process
+#: that never reads keeps the newest)
+_gc_pending: deque = deque(maxlen=1024)
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """The ``gc.callbacks`` hook: the collector calls it on the thread
+    that tripped the pass, before (``"start"``) and after (``"stop"``),
+    from inside whatever allocation that was.  So it reads the clock
+    and notes the pause, takes no lock and writes nothing: the
+    observation is the next reader's (:func:`_observe_gc_pauses`)."""
+    me = threading.get_ident()
+    if phase == "start":
+        ann = None
+        if info["generation"] == 2:
+            ann = TraceAnnotation("py.gc", generation=2)
+            ann.__enter__()
+        _gc_open[me] = (time.perf_counter(), ann)
+        return
+    t0, ann = _gc_open.pop(me, (None, None))
+    if t0 is None:              # installed while a pass was running
+        return
+    pause = time.perf_counter() - t0
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    if pause >= _GC_PAUSE_MIN_S:
+        _gc_ms[me] = _gc_ms.get(me, 0.0) + pause * 1e3
+        _gc_pending.append((pause * 1e3, info["generation"]))
+
+
+def _observe_gc_pauses() -> None:
+    """One ``py.gc_pause_ms`` observation for each pause the call-back
+    noted since the last reader came by; outside the collector."""
+    while _gc_pending:
+        try:
+            pause_ms, generation = _gc_pending.popleft()
+        except IndexError:      # another reader took the last
+            return
+        histogram("py.gc_pause_ms", pause_ms, generation=generation)
+
+
+def watch_gc() -> None:
+    """Measure the collector's pauses, from now on and for the life of
+    the process: every pause of a millisecond or more is added to the
+    calling thread's :func:`gc_pause_ms` and becomes one observation of
+    ``py.gc_pause_ms`` when that, or :func:`histogram_summary`, is next
+    read; a full (generation-2) pass runs under a ``py.gc`` annotation,
+    so a profiler session shows it on the thread's line and a device
+    gap it causes carries its name.  Idempotent: one call-back in
+    ``gc.callbacks`` however often it is called.  It changes nothing
+    about when the collector runs."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_pause_ms() -> float:
+    """Milliseconds the collector has paused THIS thread for since
+    :func:`watch_gc`, in pauses of a millisecond or more: a step loop
+    reads it at two instants and charges itself the difference."""
+    if _gc_pending:
+        _observe_gc_pauses()
+    return _gc_ms.get(threading.get_ident(), 0.0)
 
 
 _init_from_env()

@@ -319,6 +319,8 @@ class ServeEngine:
         # for the incident evidence to live
         self.flight = obs_flight.register(obs_flight.FlightRecorder())
         self.metrics = ServeMetrics(flight=self.flight)
+        # the collector's pauses are charged to the turn they fall in
+        events.watch_gc()
         self.heartbeat_timeout_s = heartbeat_timeout_s
         self._on_failure = on_failure
         self.max_dispatch_retries = int(max_dispatch_retries)
@@ -731,11 +733,15 @@ class ServeEngine:
         engine's whole serving lifetime compiles exactly the asserted
         set and nothing else.  ``decode`` is 0 until a ``serve.verify``
         fault forces a plain-decode fallback tick, ``handoff`` is 0
-        outside a disaggregated tier; no entry ever exceeds 1."""
-        return (self._prefill._cache_size(), self._decode._cache_size(),
-                self._verify._cache_size() if self._verify is not None
-                else 0,
-                self._handoff._cache_size())
+        outside a disaggregated tier; no entry ever exceeds 1.  Read
+        every step by the host's account (metrics.HostAccount), which
+        tells by it a turn in which a program compiled: so a lent or
+        substituted program that keeps no count is not asked for one
+        and reads 0, as unchanged."""
+        return tuple(
+            getattr(p, "_cache_size", int)() if p is not None else 0
+            for p in (self._prefill, self._decode, self._verify,
+                      self._handoff))
 
     def programs(self) -> SharedPrograms:
         """The engine's compiled-program bundle, lendable to another
@@ -995,6 +1001,12 @@ class ServeEngine:
         other overload."""
         if self._closed:
             raise EngineClosed("step() on a closed engine")
+        host = self.metrics.host
+        # an idle engine that its loop polls opens no turn, and reads
+        # neither a clock nor a count for the account
+        counted = self.pending or not host.resting
+        if counted:
+            host.step_in(self.spec_compiled_counts())
         with events.span("serve.step"):
             now = time.monotonic()
             delivered = 0
@@ -1079,6 +1091,9 @@ class ServeEngine:
                 dt = time.monotonic() - now
                 self._tick_ewma = dt if self._tick_ewma is None else \
                     0.8 * self._tick_ewma + 0.2 * dt
+        if counted:
+            host.step_out(None if self.pending
+                          else self.spec_compiled_counts())
         return delivered
 
     def _eta_first_token(self, position: int) -> float:
@@ -1113,6 +1128,9 @@ class ServeEngine:
                                    else self._on_failure)) \
             if self.heartbeat_timeout_s else None
         n = 0
+        # a loop of its own: what the caller did since the last step is
+        # no turn of it (metrics.HostAccount)
+        self.metrics.host.rest()
         with hb if hb is not None else nullcontext():
             while self.pending:
                 self.step()
@@ -1199,20 +1217,29 @@ class ServeEngine:
         the error escalates to the caller — quarantine for prefill,
         arena recovery for decode."""
         attempt = 0
-        while True:
-            try:
-                faults.fire(site, attempt=attempt, **attrs)
-                return fn(*args)
-            except (RuntimeError, OSError) as e:
-                if isinstance(e, failure.FailureDetected):
-                    raise
-                if attempt >= self.max_dispatch_retries:
-                    raise
-                delay = min(self.backoff_max,
-                            self.backoff_base * (2 ** attempt))
-                attempt += 1
-                self.metrics.on_retry(site)
-                self._sleep(delay)
+        # one of the engine's two kinds of runtime call, retries and
+        # their backoff included (metrics.HostAccount): the site
+        # "serve.decode" is the call "decode.dispatch"
+        call = site.removeprefix("serve.") + ".dispatch"
+        host = self.metrics.host
+        host.call_in(call)
+        try:
+            while True:
+                try:
+                    faults.fire(site, attempt=attempt, **attrs)
+                    return fn(*args)
+                except (RuntimeError, OSError) as e:
+                    if isinstance(e, failure.FailureDetected):
+                        raise
+                    if attempt >= self.max_dispatch_retries:
+                        raise
+                    delay = min(self.backoff_max,
+                                self.backoff_base * (2 ** attempt))
+                    attempt += 1
+                    self.metrics.on_retry(site)
+                    self._sleep(delay)
+        finally:
+            host.call_out(call)
 
     # -- paged-arena bookkeeping -------------------------------------------
     def _share_limit(self, req: Request) -> int:
@@ -1391,7 +1418,13 @@ class ServeEngine:
                             self.metrics.on_moe_dispatch(
                                 chunk.size * self._moe_top_k)
                 with events.span("serve.prefill.fetch"):
-                    tok = int(np.asarray(self._toks)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token
+                    # the newest program's result: the device holds
+                    # nothing once it is here, a tick in flight included
+                    self.metrics.host.call_in("prefill.fetch")
+                    try:
+                        tok = int(np.asarray(self._toks)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token
+                    finally:
+                        self.metrics.host.call_out("prefill.fetch", "admit")
         except (RuntimeError, OSError) as e:
             if isinstance(e, failure.FailureDetected):
                 raise
@@ -1538,16 +1571,19 @@ class ServeEngine:
                 pairs = list(self._running.items())
                 self._flying.append((self._toks, pairs, t0))
         delivered = self._land(keep=1)
-        if self._verify is not None or any(
-                self._running.get(slot) is req
-                and len(req.tokens) + 1 >= req.max_new_tokens
-                for slot, req in pairs):
+        if self._verify is not None:
             delivered += self._land()
+        elif any(self._running.get(slot) is req
+                 and len(req.tokens) + 1 >= req.max_new_tokens
+                 for slot, req in pairs):
+            delivered += self._land(cause="finish")
         return delivered
 
-    def _land(self, keep: int = 0) -> int:
+    def _land(self, keep: int = 0, cause: str = "other") -> int:
         """Fetch and deliver the ticks in flight, oldest first, but for
-        the newest ``keep``.  A participant whose slot no longer runs
+        the newest ``keep``; ``cause``: what the host's account
+        (metrics.HostAccount) is to call the empty device queue that the
+        last of them leaves.  A participant whose slot no longer runs
         that same request gets nothing: it was evicted by deadline,
         pre-empted, withdrawn or rebuilt since the dispatch (it replays
         from prompt + tokens delivered, and greedy decode picks the
@@ -1557,6 +1593,7 @@ class ServeEngine:
         A tick none of whose participants is left is dropped unfetched.
         Returns the number of tokens delivered."""
         delivered = 0
+        host = self.metrics.host
         while len(self._flying) > keep:
             arr, pairs, t0 = self._flying.pop(0)
             pairs = [(slot, req) for slot, req in pairs
@@ -1564,7 +1601,12 @@ class ServeEngine:
             if not pairs:
                 continue
             with events.span("serve.decode.fetch"):
-                toks = np.asarray(arr)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic, and the chip holds the next tick while a run-ahead tick's is made
+                host.call_in("decode.fetch")
+                try:
+                    toks = np.asarray(arr)    # singalint: disable=SGL008 the designed per-tick sync: ONE num_slots-int fetch per decode dispatch is the engine's hot-loop host traffic, and the chip holds the next tick while a run-ahead tick's is made
+                finally:
+                    host.call_out("decode.fetch",
+                                  None if self._flying else cause)
             # the time between two landings; from its own dispatch for a
             # tick dispatched with nothing in flight
             now = time.perf_counter()
@@ -1669,6 +1711,8 @@ class ServeEngine:
             # a tick in flight read the old arena: its tokens are
             # dropped, and the replays pick them again
             self._flying.clear()
+            # the device holds nothing the engine will fetch
+            self.metrics.host.call_out("recover", "other")
             # fresh arena + tables + token buffer: same shapes/dtypes,
             # so the two compiled programs are reused — recovery never
             # recompiles.  The prefix cache dies with the old pool

@@ -172,6 +172,33 @@ name                        kind       meaning
                                        tick, recovery/preemption replay
                                        — tokens/s is derivable from the
                                        trace by counting these)
+``serve.exposed_ms.finish``  histogram  one Python stretch between two
+``serve.exposed_ms.admit``             of the engine's runtime calls
+``serve.exposed_ms.other``             under an EMPTY device queue, by
+                                       what emptied it
+                                       (:class:`HostAccount`; ``cause``,
+                                       ``chunks``, ``by`` attrs)
+``serve.covered_ms``        histogram  per turn whose tick ran ahead:
+                                       its Python time beside a busy
+                                       chip
+``serve.dispatch_ms``       histogram  per turn whose tick ran ahead:
+                                       its time inside dispatch calls,
+                                       which is the runtime's and no
+                                       Python of the engine's
+``serve.turn_ms``           histogram  wall of one turn of the step loop
+                                       (``step()``'s entry to its next);
+                                       ``serve.turn_ms.admitting`` of
+                                       one that admitted a request
+``serve.tick_ahead``        histogram  1.0 / 0.0 a decode tick: ahead of
+                                       the tick before or not
+``serve.turn_gc_ms``        histogram  per turn with one: collector
+                                       pauses that fell in it, ms
+``serve.slow_turn_ms``      histogram  a turn whose Python time passed
+                                       50 ms: the excess.  Its story is
+                                       the ``serve.slow_turn`` gauge,
+                                       a flight-ring note and
+                                       ``snapshot()["host"]
+                                       ["slow_turns"]``
 ``serve.ttft_ms``           histogram  submit → first token
 ``serve.token_ms``          histogram  per generated token, decode path:
                                        the time between two landings
@@ -185,7 +212,9 @@ inside one the spans above land on the stepping thread's line of the
 trace, on the device ops' clock, which is how the benchmark's
 ``idle_unattributed.serve`` / ``idle_engine_python.serve`` name the
 phase the chip was waiting on).  Code added to ``step()`` goes inside
-one of the phase spans, or under a new ``serve.*`` one.
+one of the phase spans, or under a new ``serve.*`` one; its time is
+accounted either way, by :class:`HostAccount`, which knows only the
+runtime calls around it.
 Latency aggregation is PER ENGINE: each ServeMetrics owns its own
 histogram state (``snapshot()`` reads it), so two engines in one
 process never reset or pollute each other's percentiles; the emitted
@@ -203,7 +232,9 @@ dump's timeline is made of.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+import time
+from collections import deque
+from typing import Any, Dict, List, Optional, Tuple
 
 from ..obs import events
 from ..obs import flight as obs_flight
@@ -211,7 +242,271 @@ from ..obs import flight as obs_flight
 # implementation (exact totals + bounded deterministic sample ring)
 from ..obs.events import _Hist
 
-__all__ = ["ServeMetrics"]
+__all__ = ["ServeMetrics", "HostAccount"]
+
+#: a turn of the step loop whose Python time (its wall outside runtime
+#: calls) passes this is a slow turn, and its story is kept.  Python
+#: stretches are 1-5 ms in every cell of the benchmark (PERF.md section
+#: 5) and a turn is 9-14 ms in all, so ten times the longest stretch is
+#: far from any turn that went as usual and well under the 110-145 ms
+#: stalls the list exists to explain.  A constant, not a knob.
+_SLOW_TURN_MS = 50.0
+#: slow turns kept (the flight ring is washed out by per-token notes in
+#: a few ticks, hence a list of its own)
+_SLOW_TURNS_KEPT = 32
+
+_CAUSES = ("finish", "admit", "other")
+
+
+class _Turn:
+    """One turn of the step loop: ``step()``'s entry to its next."""
+
+    __slots__ = ("t0", "cpu0", "gc0", "sizes", "calls", "in_dispatch",
+                 "in_fetch", "covered", "chunks", "admitting", "ahead",
+                 "longest", "phase", "stretches")
+
+    def __init__(self, t0: float, cpu0: float, gc0: float, sizes):
+        self.t0, self.cpu0, self.gc0, self.sizes = t0, cpu0, gc0, sizes
+        self.calls = 0              # runtime calls entered
+        self.in_dispatch = self.in_fetch = 0.0  # seconds inside them
+        self.covered = 0.0          # Python seconds beside a busy chip
+        self.chunks = 0
+        self.admitting = False
+        self.ahead: Optional[bool] = None   # its decode tick's, if any
+        self.longest = 0.0          # the longest Python segment, and the
+        self.phase = ("", "")       # boundaries it lay between
+        # exposed stretches that a runtime call of this turn closed:
+        # (cause, seconds, the caller's seconds of them, closed by)
+        self.stretches: List[Tuple[str, float, float, str]] = []
+
+
+class HostAccount:
+    """What the host did between the engine's runtime calls.
+
+    ``ServeEngine`` makes two kinds of runtime call, a dispatch of a
+    compiled program and a blocking fetch of one's result, and tells
+    this account of the entry (:meth:`call_in`) and the return
+    (:meth:`call_out`) of each, and of the entry and return of
+    ``step()``.  Everything between a return and the next entry is a
+    Python STRETCH, whatever code ran in it and across the end of a
+    step and the caller's code into the next: **exposed** where the
+    call that returned left nothing on the device (its ``cause`` says
+    what emptied the queue), **covered** otherwise.  A TURN is one
+    entry of ``step()`` to the next.  So code added to the engine
+    between two runtime calls is accounted with nothing added to it.
+
+    An engine with nothing queued or running when a step returns is
+    idle: the turn ends there and the clock stops, so what an arrival
+    waited for is nobody's Python; a driver that takes the engine over
+    starts the clock anew (:meth:`rest`).  A turn that made no runtime call,
+    or in which a program compiled, is in no total and no histogram,
+    nor is a stretch that reaches into one.  Observations are made
+    once a turn, at the next step's entry.
+
+    One stepping thread: the account takes no lock."""
+
+    def __init__(self, flight: Optional[obs_flight.FlightRecorder] = None):
+        self.flight = flight
+        self.exposed_s = dict.fromkeys(_CAUSES, 0.0)
+        self.exposed_n = dict.fromkeys(_CAUSES, 0)
+        self.exposed_caller_s = 0.0
+        self.exposed_by_decode = 0
+        self.covered_s = 0.0
+        self.dispatch_s = 0.0       # inside dispatch calls: the runtime's
+        self.turn_s = 0.0
+        self.turns = 0
+        self.turns_admitting = 0
+        self.gc_ms = 0.0
+        self.slow_turns_total = 0
+        self.slow_turn_ms = 0.0
+        self.slow_turn_cpu_ms = 0.0
+        self.slow_turns: deque = deque(maxlen=_SLOW_TURNS_KEPT)
+        self._turn: Optional[_Turn] = None
+        self._t: Optional[float] = None     # the last boundary, and
+        self._at = ""                       # its name
+        self._busy = False                  # inside a runtime call,
+        self._fetching = False              # a blocking fetch
+        # the Python stretch now running: since when (None: the clock
+        # is stopped), what emptied the queue before it (None: covered),
+        # the caller's seconds of it, when step() last returned in it,
+        # and whether it reaches into a turn that is left out
+        self._s0: Optional[float] = None
+        self._cause: Optional[str] = None
+        self._caller = 0.0
+        self._ret: Optional[float] = None
+        self._tainted = False
+
+    def _mark(self, name: str, now: float) -> None:
+        """A boundary: the segment since the last one goes to the open
+        turn, as time in a call or as Python of the running stretch."""
+        turn = self._turn
+        if turn is not None and self._t is not None:
+            seg = now - self._t
+            if self._busy:
+                if self._fetching:
+                    turn.in_fetch += seg
+                else:
+                    turn.in_dispatch += seg
+            else:
+                if self._s0 is not None and self._cause is None:
+                    turn.covered += seg
+                if seg > turn.longest:
+                    turn.longest, turn.phase = seg, (self._at, name)
+        self._t, self._at = now, name
+
+    def _leave_caller(self, now: float) -> None:
+        if self._ret is not None:
+            if self._cause is not None:
+                self._caller += now - self._ret
+            self._ret = None
+
+    def call_in(self, name: str) -> None:
+        """The engine is about to make the runtime call ``name``
+        (``prefill.dispatch``, ``decode.fetch``, ...)."""
+        now = time.perf_counter()
+        self._mark(name, now)
+        self._leave_caller(now)
+        turn = self._turn
+        if turn is not None:
+            turn.calls += 1
+            if name == "prefill.dispatch":
+                turn.chunks += 1
+            if self._s0 is not None and self._cause is not None \
+                    and not self._tainted:
+                turn.stretches.append(
+                    (self._cause, now - self._s0, self._caller, name))
+        self._s0, self._busy = None, True
+        self._fetching = name.endswith("fetch")
+
+    def call_out(self, name: str, cause: Optional[str] = None) -> None:
+        """The runtime call ``name`` returned (or raised).  ``cause``:
+        why nothing is left on the device now (``finish``: the landing
+        of a tick that ended a request by length, ``admit``: an
+        admission's token fetch, ``other``), or None while something
+        the engine dispatched is still to be fetched."""
+        now = time.perf_counter()
+        self._mark(name, now)
+        self._busy = False
+        self._s0, self._cause = now, cause
+        self._caller, self._ret, self._tainted = 0.0, None, False
+        if name == "prefill.fetch" and self._turn is not None:
+            self._turn.admitting = True
+
+    def tick(self, ahead: bool) -> None:
+        """This turn dispatched its decode tick, ``ahead`` of the one
+        before or not."""
+        if self._turn is not None:
+            self._turn.ahead = ahead
+
+    def step_in(self, sizes) -> None:
+        """``step()`` was entered: the turn before ends, one begins.
+        ``sizes``: the jit caches' entry counts, by which a turn that
+        compiled is told."""
+        now = time.perf_counter()
+        self._mark("step", now)
+        self._leave_caller(now)
+        cpu, gc_ms = time.thread_time(), events.gc_pause_ms()
+        self._end_turn(now, cpu, gc_ms, sizes)
+        self._turn = _Turn(now, cpu, gc_ms, sizes)
+
+    def step_out(self, idle_sizes=None) -> None:
+        """``step()`` returns.  ``idle_sizes``: the jit caches' entry
+        counts where nothing is queued or running any more, and the
+        turn ends here and the clock stops; None otherwise."""
+        now = time.perf_counter()
+        self._mark("return", now)
+        self._ret = now
+        if idle_sizes is not None:
+            self._end_turn(now, time.thread_time(), events.gc_pause_ms(),
+                           idle_sizes)
+            self.rest()
+
+    @property
+    def resting(self) -> bool:
+        """The clock is stopped: no turn is open and no stretch runs."""
+        return self._t is None
+
+    def rest(self) -> None:
+        """Stop the clock: the open turn and the running stretch are
+        dropped.  For an engine gone idle, and for a driver that takes
+        the engine over (``run_until_idle``, hence ``drain`` and
+        ``close``): whatever its caller did since the last step, taking
+        a profile apart or doing sums over a window, belongs to no loop
+        and would read as one turn of seconds."""
+        self._turn = self._t = self._s0 = self._ret = None
+
+    def _end_turn(self, now: float, cpu: float, gc_now: float,
+                  sizes) -> None:
+        turn = self._turn
+        if turn is None:
+            return
+        if not turn.calls or sizes != turn.sizes:
+            self._tainted = True
+            return
+        wall = now - turn.t0
+        gc_ms = gc_now - turn.gc0
+        self.turns += 1
+        self.turn_s += wall
+        self.covered_s += turn.covered
+        self.dispatch_s += turn.in_dispatch
+        self.gc_ms += gc_ms
+        for cause, sec, caller, by in turn.stretches:
+            self.exposed_s[cause] += sec
+            self.exposed_n[cause] += 1
+            self.exposed_caller_s += caller
+            self.exposed_by_decode += by == "decode.dispatch"
+            events.histogram("serve.exposed_ms." + cause, sec * 1e3,
+                             cause=cause, chunks=turn.chunks, by=by)
+        events.histogram("serve.turn_ms", wall * 1e3, chunks=turn.chunks)
+        if turn.admitting:
+            self.turns_admitting += 1
+            events.histogram("serve.turn_ms.admitting", wall * 1e3,
+                             chunks=turn.chunks)
+        if turn.ahead is not None:
+            events.histogram("serve.tick_ahead", float(turn.ahead))
+            if turn.ahead:
+                events.histogram("serve.covered_ms", turn.covered * 1e3,
+                                 chunks=turn.chunks)
+                events.histogram("serve.dispatch_ms",
+                                 turn.in_dispatch * 1e3, chunks=turn.chunks)
+        if gc_ms:
+            events.histogram("serve.turn_gc_ms", gc_ms)
+        python_ms = (wall - turn.in_dispatch - turn.in_fetch) * 1e3
+        if python_ms > _SLOW_TURN_MS:
+            self._slow_turn(turn, wall * 1e3, python_ms,
+                            (cpu - turn.cpu0) * 1e3, gc_ms)
+
+    def _slow_turn(self, turn: _Turn, wall_ms: float, python_ms: float,
+                   cpu_ms: float, gc_ms: float) -> None:
+        story = {"t": time.time(),  # singalint: disable=SGL005 read beside the sink's event timestamps, which are wall-clock; every duration here is from the monotonic clocks
+                 "wall_ms": wall_ms, "python_ms": python_ms,
+                 "cpu_ms": cpu_ms, "gc_ms": gc_ms, "chunks": turn.chunks,
+                 "phase": ">".join(turn.phase)}
+        self.slow_turns.append(story)
+        self.slow_turns_total += 1
+        self.slow_turn_ms += python_ms - _SLOW_TURN_MS
+        self.slow_turn_cpu_ms += cpu_ms
+        events.histogram("serve.slow_turn_ms", python_ms - _SLOW_TURN_MS)
+        attrs = {k: round(v, 3) if isinstance(v, float) else v
+                 for k, v in story.items() if k != "t"}
+        events.gauge("serve.slow_turn", attrs["python_ms"], **attrs)
+        if self.flight is not None:
+            self.flight.note("gauge", "serve.slow_turn", **attrs)
+
+    def snapshot(self) -> Dict[str, Any]:
+        return {"exposed_s": dict(self.exposed_s),
+                "exposed_n": dict(self.exposed_n),
+                "exposed_caller_s": self.exposed_caller_s,
+                "exposed_by_decode": self.exposed_by_decode,
+                "covered_s": self.covered_s,
+                "dispatch_s": self.dispatch_s,
+                "turn_s": self.turn_s, "turns": self.turns,
+                "turns_admitting": self.turns_admitting,
+                "gc_ms": self.gc_ms,
+                "slow_turns": list(self.slow_turns),
+                "slow_turns_total": self.slow_turns_total,
+                "slow_turn_ms": self.slow_turn_ms,
+                "slow_turn_cpu_ms": self.slow_turn_cpu_ms}
 
 
 class ServeMetrics:
@@ -221,6 +516,8 @@ class ServeMetrics:
 
     def __init__(self, flight: Optional[obs_flight.FlightRecorder] = None):
         self.flight = flight
+        # the host's own time between runtime calls (HostAccount)
+        self.host = HostAccount(flight)
         self.submitted = 0
         self.admitted = 0
         self.rejected = 0
@@ -275,7 +572,6 @@ class ServeMetrics:
         self.state_snapshot_evictions = 0
         self.prefix_tokens_recomputed = 0
         self.ssm_state_bytes = 0
-        self._accept = _Hist()
         self._ttft = _Hist()
         self._token = _Hist()
 
@@ -365,7 +661,6 @@ class ServeMetrics:
         self.spec_proposed += proposed
         self.spec_accepted += accepted
         rate = accepted / proposed if proposed else 0.0
-        self._accept.observe(rate)
         events.counter("serve.spec_proposed", proposed)
         events.counter("serve.spec_accepted", accepted)
         events.histogram("serve.accept_rate", rate)
@@ -407,6 +702,7 @@ class ServeMetrics:
         before it had not landed yet."""
         self.decode_ticks += 1
         self.decode_ticks_ahead += ahead
+        self.host.tick(ahead)
         events.counter("serve.decode_ticks", 1, ahead=ahead)
 
     def on_moe_dispatch(self, assignments: int) -> None:
@@ -545,7 +841,7 @@ class ServeMetrics:
             "ssm_state_bytes": self.ssm_state_bytes,
             "accept_rate": self.accept_rate,
             "tokens_per_dispatch": self.tokens_per_dispatch,
-            "accept_rate_hist": self._accept.summary(),
             "ttft_ms": self._ttft.summary(),
             "token_ms": self._token.summary(),
+            "host": self.host.snapshot(),
         }

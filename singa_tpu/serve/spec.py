@@ -295,8 +295,14 @@ def verify_round(engine) -> int:
                 f"{type(e).__name__}: {e}") from e
         (acc_v, cand_v, engine._toks, engine.pool.caches,
          engine.pool.draft_caches) = out
-        acc = np.asarray(acc_v)    # singalint: disable=SGL008 the designed per-tick sync: one (S,) + one (S, k+1) int fetch commits a whole verify round
-        cand = np.asarray(cand_v)
+        # a verify round does not run ahead: its fetch leaves the
+        # device with nothing to do (metrics.HostAccount)
+        engine.metrics.host.call_in("verify.fetch")
+        try:
+            acc = np.asarray(acc_v)    # singalint: disable=SGL008 the designed per-tick sync: one (S,) + one (S, k+1) int fetch commits a whole verify round
+            cand = np.asarray(cand_v)
+        finally:
+            engine.metrics.host.call_out("verify.fetch", "other")
     # rollback IS this truncation: the window's k+1 positions are all
     # written, the slot's limit moves past the accepted ones only, and
     # the rest sit beyond it, unreachable and overwritten next round
