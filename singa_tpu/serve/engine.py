@@ -55,6 +55,18 @@ request's tokens or moves a slot from outside a step lands first.  The
 speculative engine does not run ahead: its accepted count decides the
 positions.
 
+An admission's first token lands behind the tick: ``prefill_chunk``
+writes the picked token into ``toks[slot]`` on the device, where the
+tick reads it, so the host needs it only to DELIVER it.  A step that
+admits dispatches the chunks, ends the admission's bookkeeping,
+dispatches its decode tick over the new slot too, and only then fetches
+the chunk's token, while the chip holds the tick.  The token still
+lands at once where the step dispatches no plain tick
+(``step(decode=False)``, the speculative engine) and where it is known
+to end the request by length.  A pending first token is dropped like a
+tick's: by a pre-emption, a rebuild, whatever took the request out of
+its slot before the landing.
+
 Admission counts FREE BLOCKS, not slots: a request needs a table row
 AND enough blocks for its prompt (minus the shared prefix), and decode
 grows a slot by one block when its position crosses a block boundary.
@@ -449,6 +461,12 @@ class ServeEngine:
         self._flying: List[Tuple[object, List[Tuple[int, Request]],
                                  float]] = []
         self._landed_at = 0.0       # perf_counter of the last landing
+        # admissions of this step whose chunks are dispatched and whose
+        # first token is not fetched yet, oldest first: (the token array
+        # its last chunk returned, slot, request, ``decode_ticks`` then).
+        # One array an admission, so that an earlier one's token does
+        # not wait for a later one's chunks.  Empty between steps
+        self._first: List[Tuple[object, int, Request, int]] = []
 
         # ---- the exactly-two compiled programs --------------------------
         # (plus the optional third: the fixed-shape handoff gather a
@@ -984,15 +1002,18 @@ class ServeEngine:
         """One continuous-batching tick: recovery (if requested by the
         hang watchdog) → deadline eviction → overload shedding →
         admission (prefill queued requests into free slots while free
-        blocks cover them) → block-table growth → the dispatch of one
-        decode over all active slots → the LANDING of the decode the
-        step before dispatched (its tokens fetched and delivered:
+        blocks cover them; the chunks are dispatched, their token is
+        not fetched yet) → block-table growth → the dispatch of one
+        decode over all active slots, the admitted ones included → the
+        LANDING of the admissions' first tokens and of the decode the
+        step before dispatched (fetched and delivered:
         :meth:`_decode_tick`).  A tick that ends a request by length
         lands in its own step.  Returns the number of tokens delivered,
         first tokens of admissions included.
 
         ``decode=False`` lands what is in flight and stops after
-        admission — the disaggregated
+        admission, each admission's first token landed at once — the
+        disaggregated
         tier's PREFILL-WORKER tick: freshly prefilled requests stay in
         their slots (blocks intact) for the router to hand off to a
         decode worker instead of decoding here.  Deadline eviction
@@ -1044,21 +1065,24 @@ class ServeEngine:
             #    steps.  A slot row is not enough: the head-of-queue
             #    request must also be coverable by free + evictable
             #    blocks (FIFO: a too-big head blocks the line rather
-            #    than being overtaken)
+            #    than being overtaken).  The first token lands behind
+            #    the plain tick this step dispatches, if it does
+            behind = decode and self._verify is None
             while self.pool.free_count:
                 with events.span("serve.admit.probe"):
                     req = self.sched.peek()
                     if req is None or not self._admittable(req):
                         break
                     self.sched.pop_for_admission()
-                delivered += self._admit(req)
+                delivered += self._admit(req, behind)
 
             # 3. block-table growth + one decode tick over the whole
-            #    arena, dispatched BEFORE the tick in flight lands; a
-            #    decode (or a decode-time block allocation, or the
-            #    landing's fetch) that died past its retry budget
-            #    escalates to an arena rebuild + re-prefill instead of
-            #    crashing the engine
+            #    arena, dispatched BEFORE the admissions' first tokens
+            #    and the tick in flight land; a decode (or a decode-time
+            #    block allocation, or a landing's fetch: a chunk or a
+            #    tick that died on the device surfaces there) that died
+            #    past its retry budget escalates to an arena rebuild +
+            #    re-prefill instead of crashing the engine
             try:
                 if self._running and decode:
                     with events.span("serve.grow"):
@@ -1291,14 +1315,22 @@ class ServeEngine:
         faults.fire("serve.block_alloc", n=n, rid=rid)
         return self.pool.alloc_blocks(n)
 
-    def _admit(self, req: Request) -> int:
+    def _admit(self, req: Request, behind: bool) -> int:
         # the whole admission — block claim, prefix hit, prefill chunks,
-        # first-token delivery, quarantine on failure — runs under the
-        # request's trace, so each of those events carries its id
+        # quarantine on failure, the first token where it lands at once
+        # — runs under the request's trace, so each of those events
+        # carries its id
         with obs_trace.activate(req.trace_id), events.span("serve.admit"):
-            return self._admit_traced(req)
+            return self._admit_traced(req, behind)
 
-    def _admit_traced(self, req: Request) -> int:
+    def _admit_traced(self, req: Request, behind: bool) -> int:
+        """Claim, prefill and activate ``req``.  ``behind``: this step
+        dispatches a plain decode tick after its admissions, and the
+        first token is fetched behind it (:meth:`_land_first`) unless
+        it is known to end the request by length: such a request takes
+        part in no tick and its slot is free for the next admission of
+        this step.  Returns the tokens delivered: 1 at once, 0 pending
+        or quarantined."""
         slot = self.pool.alloc_slot()
         assert slot is not None, "admission with no free slot"
         # replay_ids == prompt for a fresh request; for a request
@@ -1308,7 +1340,8 @@ class ServeEngine:
         replay = req.replay_ids()
         P = replay.size
         bs = self.pool.block_size
-        first = not req.tokens
+        at_once = not behind or req.max_new_tokens - len(req.tokens) <= 1
+        tok = None
         owned: List[int] = []
         shared_ids: List[int] = []
         mapped = False
@@ -1417,14 +1450,8 @@ class ServeEngine:
                         if self._moe_top_k:
                             self.metrics.on_moe_dispatch(
                                 chunk.size * self._moe_top_k)
-                with events.span("serve.prefill.fetch"):
-                    # the newest program's result: the device holds
-                    # nothing once it is here, a tick in flight included
-                    self.metrics.host.call_in("prefill.fetch")
-                    try:
-                        tok = int(np.asarray(self._toks)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token
-                    finally:
-                        self.metrics.host.call_out("prefill.fetch", "admit")
+                if at_once:
+                    tok = self._fetch_first(self._toks, slot, behind=False)
         except (RuntimeError, OSError) as e:
             if isinstance(e, failure.FailureDetected):
                 raise
@@ -1453,20 +1480,75 @@ class ServeEngine:
             req.slot = slot
             req.state = RUNNING
             self._running[slot] = req
-            if first:
-                # preemption/recovery re-prefills count under their own
-                # counters, not here — ``admitted`` stays comparable to
-                # ``submitted``
-                self.metrics.on_admit()
-            done = req.deliver(tok)   # prefill yields the (next) token
-            self.metrics.on_deliver(req.rid, len(req.tokens))
-            if first:
-                self.metrics.on_first_token(req.ttft_s)
-            if req.on_token is not None:
-                req.on_token(tok, req.handle)
-            if done:
-                self._finalize(slot)
-        return 1
+            if at_once:
+                self._deliver_first(slot, req, tok)
+                return 1
+        # the tick reads the token where the chunk wrote it, on the
+        # device: the host fetches this array once the tick is dispatched
+        self._first.append((self._toks, slot, req,
+                            self.metrics.decode_ticks))
+        return 0
+
+    def _fetch_first(self, arr, slot: int, behind: bool) -> int:
+        """The blocking fetch of the token an admission's last chunk
+        picked, out of the token array that chunk returned.  ``behind``:
+        a decode tick was dispatched after the chunk and is not fetched
+        yet, so the chip has work while the host waits and after;
+        otherwise the chunk was the newest program and the device holds
+        nothing once its token is here, a tick dispatched before it
+        included (metrics.HostAccount)."""
+        host = self.metrics.host
+        with events.span("serve.prefill.fetch"):
+            host.call_in("prefill.fetch")
+            try:
+                return int(np.asarray(arr)[slot])  # singalint: disable=SGL008 the designed per-admission sync: one num_slots-int fetch delivers the prefill token, behind the step's tick where there is one
+            finally:
+                host.call_out("prefill.fetch", None if behind else "admit")
+
+    def _deliver_first(self, slot: int, req: Request, tok: int) -> None:
+        """Hand a request the token its prefill picked: its first, or
+        for a request re-admitted by pre-emption or recovery the next of
+        its stream."""
+        first = not req.tokens
+        if first:
+            # preemption/recovery re-prefills count under their own
+            # counters, not here — ``admitted`` stays comparable to
+            # ``submitted``, also for a request pre-empted or rebuilt
+            # before its first token landed, which is why it is counted
+            # with the token and not with the chunks
+            self.metrics.on_admit()
+        done = req.deliver(tok)
+        self.metrics.on_deliver(req.rid, len(req.tokens))
+        if first:
+            self.metrics.on_first_token(req.ttft_s)
+        if req.on_token is not None:
+            req.on_token(tok, req.handle)
+        if done:
+            self._finalize(slot)
+
+    def _land_first(self) -> int:
+        """Fetch and deliver the first tokens that this step's
+        admissions left pending, oldest admission first.  An admission
+        whose slot no longer runs that request gets nothing (as a tick's
+        participant, :meth:`_land`): ``_ensure_blocks`` pre-empted it,
+        the youngest, or it was withdrawn or rebuilt; it replays from
+        its prompt.  An EOS here behaves as one from a run-ahead tick:
+        the tick already dispatched wrote one row into the request's own
+        block and picked a token nobody gets."""
+        delivered = 0
+        while self._first:
+            arr, slot, req, ticks = self._first.pop(0)
+            if self._running.get(slot) is not req:
+                continue
+            behind = self.metrics.decode_ticks > ticks
+            with obs_trace.activate(req.trace_id):
+                tok = self._fetch_first(arr, slot, behind)
+                if behind:
+                    self.metrics.on_first_token_behind_tick()
+                with events.span("serve.deliver"):
+                    self._deliver_first(slot, req, tok)
+            delivered += 1
+        return delivered
 
     def _quarantine(self, req: Request, err: Exception,
                     site: str = "serve.prefill",
@@ -1530,10 +1612,12 @@ class ServeEngine:
             self.metrics.on_preempt()
 
     def _decode_tick(self) -> int:
-        """Dispatch decode tick N, then land tick N-1 (:meth:`_land`):
-        the chip holds N while the host fetches and delivers N-1, ends
-        the step, does the caller's work and starts the next step.  A
-        tick the host already knows to end a request BY LENGTH lands
+        """Dispatch decode tick N, then land the first tokens of this
+        step's admissions and tick N-1 (:meth:`_land`): the chip holds N
+        while the host fetches and delivers them, ends the step, does
+        the caller's work and starts the next step.  A tick the host
+        already knows to end a request BY LENGTH (the first token just
+        landed counted) lands
         here too, and the step returns with nothing in flight: the
         successor the caller then submits finds an empty device queue
         for its prefill, as it did before ticks ran ahead.  So does the
@@ -1580,7 +1664,10 @@ class ServeEngine:
         return delivered
 
     def _land(self, keep: int = 0, cause: str = "other") -> int:
-        """Fetch and deliver the ticks in flight, oldest first, but for
+        """Fetch and deliver the first tokens pending
+        (:meth:`_land_first`: they are older than the tick dispatched
+        behind them, and a tick's test of a finish by length counts
+        them), then the ticks in flight, oldest first, but for
         the newest ``keep``; ``cause``: what the host's account
         (metrics.HostAccount) is to call the empty device queue that the
         last of them leaves.  A participant whose slot no longer runs
@@ -1592,7 +1679,7 @@ class ServeEngine:
         request's own block, and nothing is delivered after an EOS).
         A tick none of whose participants is left is dropped unfetched.
         Returns the number of tokens delivered."""
-        delivered = 0
+        delivered = self._land_first()
         host = self.metrics.host
         while len(self._flying) > keep:
             arr, pairs, t0 = self._flying.pop(0)
@@ -1708,9 +1795,11 @@ class ServeEngine:
         with events.span("serve.recover", reason=reason):
             inflight = sorted(self._running.values(), key=lambda r: r.rid)
             self._running.clear()
-            # a tick in flight read the old arena: its tokens are
-            # dropped, and the replays pick them again
+            # a tick in flight read the old arena, a pending first
+            # token was written into it: they are dropped, and the
+            # replays pick them again
             self._flying.clear()
+            self._first.clear()
             # the device holds nothing the engine will fetch
             self.metrics.host.call_out("recover", "other")
             # fresh arena + tables + token buffer: same shapes/dtypes,

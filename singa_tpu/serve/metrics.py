@@ -57,23 +57,39 @@ name                        kind       meaning
 ``serve.admit.probe``       span       head-of-queue peek + block
                                        feasibility probe, once per turn
                                        of the admission loop
-``serve.admit``             span       one admission, whole (under the
-                                       request's trace id)
+``serve.admit``             span       one admission, whole but for the
+                                       landing of a first token that
+                                       comes behind the step's tick
+                                       (under the request's trace id)
 ``serve.admit.claim``       span       prefix match, block allocation,
                                        slot table mapping
-``serve.prefill``           span       all chunks of one admission and
-                                       the token fetch (``slot``,
-                                       ``prompt``, ``shared``,
-                                       ``chunks`` attrs)
+``serve.prefill``           span       all chunks of one admission, and
+                                       the token fetch where it is made
+                                       at once (``slot``, ``prompt``,
+                                       ``shared``, ``chunks`` attrs)
 ``serve.prefill.stage``     span       per chunk: host staging of the
                                        ids and scalar arguments
 ``serve.prefill.dispatch``  span       per chunk: the guarded dispatch
                                        of ``prefill_chunk``
-``serve.prefill.fetch``     span       the blocking token fetch after
-                                       the last chunk
+``serve.prefill.fetch``     span       the blocking fetch of the token
+                                       the last chunk picked: after the
+                                       step's ``serve.decode.dispatch``
+                                       and outside ``serve.admit``
+                                       (under the request's trace id),
+                                       or at once inside
+                                       ``serve.prefill`` where the step
+                                       dispatches no plain tick or the
+                                       token ends the request by length
 ``serve.admit.finish``      span       prefix registration, slot
-                                       activation, first-token delivery
-                                       and callback
+                                       activation; delivery and
+                                       callback of a first token that
+                                       landed at once
+``serve.first_tokens_behind_tick``  counter  one prefill's token fetched
+                                       with the decode tick that reads
+                                       it already dispatched.
+                                       ``snapshot()`` keeps the total,
+                                       beside ``admitted`` and the
+                                       re-prefills' counters
 ``serve.grow``              span       decode-time block-table growth
                                        (and any preemption it triggers)
 ``serve.decode``            span       the dispatch of one decode tick
@@ -84,9 +100,11 @@ name                        kind       meaning
                                        EARLIER where ticks run ahead, so
                                        it opens after the next tick's
                                        ``serve.decode.dispatch`` closed
-``serve.deliver``           span       the per-slot loop after the fetch:
-                                       delivery, metrics, flight notes,
-                                       ``on_token`` callbacks, finalize
+``serve.deliver``           span       the per-slot loop after a tick's
+                                       fetch: delivery, metrics, flight
+                                       notes, ``on_token`` callbacks,
+                                       finalize; the same for one first
+                                       token landed behind the tick
 ``serve.decode_ticks``      counter    one dispatch of ``decode_paged``
                                        (``ahead`` attr: another tick was
                                        in flight, so this one's launch
@@ -382,8 +400,9 @@ class HostAccount:
         """The runtime call ``name`` returned (or raised).  ``cause``:
         why nothing is left on the device now (``finish``: the landing
         of a tick that ended a request by length, ``admit``: an
-        admission's token fetch, ``other``), or None while something
-        the engine dispatched is still to be fetched."""
+        admission's token fetch with no tick dispatched behind the
+        chunk, ``other``), or None while something the engine
+        dispatched after it is still to be fetched."""
         now = time.perf_counter()
         self._mark(name, now)
         self._busy = False
@@ -556,6 +575,9 @@ class ServeMetrics:
         # tick before was still in flight (serve/engine.py)
         self.decode_ticks = 0
         self.decode_ticks_ahead = 0
+        # tokens of a prefill (an admission's first, a re-prefill's
+        # next) fetched after the step's decode tick was dispatched
+        self.first_tokens_behind_tick = 0
         # mixture-of-experts models: dispatches that ran the router and
         # the (token, expert) pairs they routed; both 0 for a dense model
         self.moe_dispatches = 0
@@ -705,6 +727,12 @@ class ServeMetrics:
         self.host.tick(ahead)
         events.counter("serve.decode_ticks", 1, ahead=ahead)
 
+    def on_first_token_behind_tick(self) -> None:
+        """One prefill's token fetched with the decode tick that reads
+        it already dispatched: the chip had work under the fetch."""
+        self.first_tokens_behind_tick += 1
+        events.counter("serve.first_tokens_behind_tick", 1)
+
     def on_moe_dispatch(self, assignments: int) -> None:
         """One prefill chunk or decode tick of a mixture-of-experts
         model: ``assignments`` = its valid tokens x top-k."""
@@ -830,6 +858,7 @@ class ServeMetrics:
             "decode_kv_blocks_view": self.decode_kv_blocks_view,
             "decode_ticks": self.decode_ticks,
             "decode_ticks_ahead": self.decode_ticks_ahead,
+            "first_tokens_behind_tick": self.first_tokens_behind_tick,
             "moe_dispatches": self.moe_dispatches,
             "moe_assignments": self.moe_assignments,
             "cca_state_resumes": self.cca_state_resumes,

@@ -75,3 +75,31 @@ def _host_profile(out_dir, prefixes):
 @pytest.fixture(scope="session")
 def host_profile():
     return _host_profile
+
+
+def _tiny_model(kind):
+    """An eval-mode model at its class's `tiny()` size, weights from
+    seed 0: `llama`, `moe` (Llama with four dropless experts at top 2),
+    `zaya` or `granite`."""
+    import dataclasses
+    from singa_tpu import models, tensor
+    tensor.set_seed(0)
+    if kind == "llama":
+        m = models.Llama(models.LlamaConfig.tiny())
+    elif kind == "moe":
+        m = models.Llama(dataclasses.replace(
+            models.LlamaConfig.tiny(), num_experts=4, moe_top_k=2,
+            moe_dropless=True))
+    elif kind == "zaya":
+        m = models.Zaya(models.ZayaConfig.tiny())
+    else:
+        m = models.GraniteHybrid(models.GraniteHybridConfig.tiny())
+    m.eval()
+    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32))],
+              is_train=False, use_graph=False)
+    return m
+
+
+@pytest.fixture(scope="session")
+def tiny_model():
+    return _tiny_model

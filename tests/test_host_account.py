@@ -6,8 +6,10 @@ What is held here:
   * every Python stretch between two runtime calls is exposed (the
     device queue stood empty, with the cause that emptied it) or
     covered; exposed stretches closed by a decode dispatch are the
-    ticks that did not run ahead; an admission followed by a dispatch
-    leaves one `admit` stretch; exposed + covered fit inside the turns;
+    ticks that neither ran ahead nor were dispatched behind an
+    admission's chunk; a first token that lands behind its tick leaves
+    no `admit` stretch, one that lands at once does (ISSUE 37); exposed
+    + covered fit inside the turns;
   * an idle engine and a turn that compiled count nothing;
   * a slow turn keeps its wall, Python, CPU and collector time and the
     boundaries its longest stretch lay between, the last 32 of them;
@@ -15,7 +17,6 @@ What is held here:
   * with no sink and no record store nothing is written.
 """
 
-import dataclasses
 import gc
 import json
 import os
@@ -24,7 +25,6 @@ import time
 import numpy as np
 import pytest
 
-from singa_tpu import models, tensor
 from singa_tpu.obs import events
 from singa_tpu.serve import ServeEngine
 from singa_tpu.serve import metrics as serve_metrics
@@ -35,24 +35,6 @@ HISTS = ("serve.exposed_ms.finish", "serve.exposed_ms.admit",
          "serve.dispatch_ms", "serve.turn_ms",
          "serve.turn_ms.admitting", "serve.tick_ahead",
          "serve.turn_gc_ms", "serve.slow_turn_ms", "py.gc_pause_ms")
-
-
-def _model(kind):
-    tensor.set_seed(0)
-    if kind == "llama":
-        m = models.Llama(models.LlamaConfig.tiny())
-    elif kind == "moe":
-        m = models.Llama(dataclasses.replace(
-            models.LlamaConfig.tiny(), num_experts=4, moe_top_k=2,
-            moe_dropless=True))
-    elif kind == "zaya":
-        m = models.Zaya(models.ZayaConfig.tiny())
-    else:
-        m = models.GraniteHybrid(models.GraniteHybridConfig.tiny())
-    m.eval()
-    m.compile([tensor.from_numpy(np.zeros((1, 4), np.int32))],
-              is_train=False, use_graph=False)
-    return m
 
 
 def _prompts(n, lens, vocab, seed=7):
@@ -75,11 +57,11 @@ def _warm(model, **kw):
 
 
 @pytest.fixture(scope="module", params=["llama", "moe", "zaya", "granite"])
-def served(request):
+def served(request, tiny_model):
     """(engine, the account's totals and the histograms' counts before
     and after six requests of mixed lengths, all ended by length,
     through three slots, the counters' deltas)."""
-    eng = _warm(_model(request.param))
+    eng = _warm(tiny_model(request.param))
     vocab = eng.model.cfg.vocab_size
     s0, c0 = eng.metrics.snapshot(), _counts()
     hs = [eng.submit(p, max_new_tokens=3 + 2 * i) for i, p in
@@ -100,19 +82,27 @@ class TestStretches:
     def test_exposed_by_a_decode_dispatch_are_the_ticks_not_ahead(
             self, served):
         _, s0, s1, _, _ = served
+        """A tick dispatched behind an admission's chunk closes a
+        covered stretch (the chunk is on the device), ahead or not.
+        Here every request ends by length, so every admitting turn finds
+        nothing in flight and its tick is not ahead."""
+        _, s0, s1, _, _ = served
         ticks = s1["decode_ticks"] - s0["decode_ticks"]
         ahead = s1["decode_ticks_ahead"] - s0["decode_ticks_ahead"]
         assert 0 < ahead < ticks
-        assert _delta(s0, s1, "exposed_by_decode") == ticks - ahead
+        behind = _delta(s0, s1, "turns_admitting")
+        assert 0 < behind < ticks - ahead
+        assert _delta(s0, s1, "exposed_by_decode") == ticks - ahead - behind
 
-    def test_one_admit_stretch_an_admission_followed_by_a_dispatch(
+    def test_no_admit_stretch_a_first_token_lands_behind_its_tick(
             self, served):
         _, s0, s1, c0, c1 = served
         admitted = s1["admitted"] - s0["admitted"]
         assert admitted == 6
-        assert _delta(s0, s1, "exposed_n")["admit"] == admitted
-        assert c1["serve.exposed_ms.admit"] - c0["serve.exposed_ms.admit"] \
-            == admitted
+        assert s1["first_tokens_behind_tick"] \
+            - s0["first_tokens_behind_tick"] == admitted
+        assert _delta(s0, s1, "exposed_n")["admit"] == 0
+        assert c1["serve.exposed_ms.admit"] == c0["serve.exposed_ms.admit"]
 
     def test_a_finish_stretch_each_time_a_finishing_tick_landed(
             self, served):
@@ -187,8 +177,8 @@ class TestStretches:
 
 
 @pytest.fixture(scope="module")
-def llama():
-    return _model("llama")
+def llama(tiny_model):
+    return tiny_model("llama")
 
 
 def test_a_turn_that_compiled_is_in_no_total(llama):
@@ -213,14 +203,13 @@ def test_a_turn_that_compiled_is_in_no_total(llama):
     assert _counts()["serve.tick_ahead"] - c0["serve.tick_ahead"] == 2
 
 
-def test_an_admission_beside_a_tick_in_flight_is_exposed(llama):
+def test_an_admission_beside_a_tick_in_flight_is_covered(llama):
     """A request that ends by EOS frees its slot a landing late, so its
-    successor is admitted with a tick in flight.  The admission's fetch
-    returns the newest program's result: the device holds nothing after
-    it whatever is unfetched, the stretch after it is exposed, and the
-    tick dispatched then still counts as ahead of the unlanded one.  So
-    exposed stretches closed by a decode dispatch are no fewer than the
-    ticks not ahead, and equal where no request ends so."""
+    successor is admitted with a tick in flight.  Its chunk, the tick
+    behind it (ahead of the unlanded one) and the fetch of its first
+    token all meet a device with work: no `admit` stretch, and the
+    exposed stretches closed by a decode dispatch are the ticks not
+    ahead less those dispatched behind an admission's chunk."""
     eng = _warm(llama)
     p, q = _prompts(2, [6, 9], 64, seed=3)
     ref = eng.submit(p, max_new_tokens=8)
@@ -234,15 +223,24 @@ def test_an_admission_beside_a_tick_in_flight_is_exposed(llama):
     late = eng.submit(q[:5], max_new_tokens=3)
     for _ in range(3):
         eng.submit(q[:7], max_new_tokens=2)     # a queue behind the slots
+    beside = behind_not_ahead = 0
     while eng.pending:          # one loop: `run_until_idle` starts anew
+        flying, n = bool(eng._flying), eng.metrics.admitted
         eng.step()
+        if eng.metrics.admitted > n:
+            beside += flying
+            behind_not_ahead += not flying
     assert h.finish_reason == "eos" and keep.done and late.done
+    assert beside >= 1 and behind_not_ahead >= 1
     s1 = eng.metrics.snapshot()
     ticks = s1["decode_ticks"] - s0["decode_ticks"]
     ahead = s1["decode_ticks_ahead"] - s0["decode_ticks_ahead"]
-    assert _delta(s0, s1, "exposed_by_decode") >= ticks - ahead
-    assert _delta(s0, s1, "exposed_n")["admit"] == \
-        s1["admitted"] - s0["admitted"]
+    # the two steps before the loop: the first admitted, nothing flying
+    assert _delta(s0, s1, "exposed_by_decode") == \
+        ticks - ahead - behind_not_ahead - 1
+    assert _delta(s0, s1, "exposed_n")["admit"] == 0
+    assert s1["first_tokens_behind_tick"] - s0["first_tokens_behind_tick"] \
+        == s1["admitted"] - s0["admitted"] == 6
 
 
 def test_a_driver_that_takes_over_starts_the_clock_anew(llama):
@@ -533,6 +531,9 @@ def test_sink_lines_carry_cause_and_chunks_after_the_dispatch(
 
         for i, p in enumerate(_prompts(3, [5, 20], 64)):
             eng.submit(p, max_new_tokens=4 + 2 * i, on_token=cb)
+        # a first token that ends its request lands at once, behind no
+        # tick: the one `admit` stretch left (ISSUE 37)
+        eng.submit(_prompts(1, [9], 64, seed=11)[0], max_new_tokens=1)
         eng.run_until_idle()
     finally:
         events.configure()
@@ -540,6 +541,7 @@ def test_sink_lines_carry_cause_and_chunks_after_the_dispatch(
     exposed = [e for e in evs if e["name"].startswith("serve.exposed_ms.")]
     assert {e["name"] for e in exposed} == {"serve.exposed_ms.finish",
                                             "serve.exposed_ms.admit"}
+    assert [e["name"] for e in exposed].count("serve.exposed_ms.admit") == 1
     for e in exposed:
         assert e["kind"] == "hist" and e["value"] > 0
         assert e["name"].endswith("." + e["cause"])

@@ -316,7 +316,9 @@ class TestStreamingAndMetrics:
                                           tmp_path):
         """With a sink every phase span of a step is a JSONL line with
         `dur_ms`; the spans of one admission carry the request's trace
-        id and nest under its `serve.admit` by `span`/`parent`."""
+        id and nest under its `serve.admit` by `span`/`parent`, but for
+        the fetch of its first token, which comes behind the step's
+        tick (ISSUE 37): under the request's trace, under no admission."""
         from singa_tpu.serve import engine as engine_mod
         # one block a chunk, so that a prompt under max_len is three
         monkeypatch.setattr(engine_mod, "_PREFILL_ROWS", 8)
@@ -343,12 +345,21 @@ class TestStreamingAndMetrics:
                 top = e
                 while "parent" in top:
                     top = by_id[top["parent"]]
-                assert top is admit, e
+                if e["name"] != "serve.prefill.fetch":
+                    assert top is admit, e
         # a 20-token prompt in chunks of 8: three chunks, one fetch
         names = [e["name"] for e in spans]
         assert names.count("serve.prefill.stage") == 3
         assert names.count("serve.prefill.dispatch") == 3
         assert names.count("serve.prefill.fetch") == 1
+        # a span's line is written when it closes: the tick's dispatch
+        # closed before the fetch of the first token opened, and its
+        # delivery has a `serve.deliver` of its own under its trace
+        assert names.index("serve.decode.dispatch") \
+            < names.index("serve.prefill.fetch") \
+            < names.index("serve.decode.fetch")
+        assert [e["trace"] for e in spans if e["name"] == "serve.deliver"
+                and "trace" in e] == [h.trace_id]
         (prefill,) = [e for e in spans if e["name"] == "serve.prefill"]
         assert (prefill["prompt"], prefill["shared"],
                 prefill["chunks"]) == (20, 0, 3)
@@ -1292,6 +1303,13 @@ class TestOneTickInFlight:
         sent = [(s, e) for n, s, e, _ in line if n == "serve.decode.dispatch"]
         fetched = [(s, e) for n, s, e, _ in line if n == "serve.decode.fetch"]
         delivers = [(s, e) for n, s, e, _ in line if n == "serve.deliver"]
+        first = [(s, e) for n, s, e, _ in line if n == "serve.prefill.fetch"]
+        # the two admissions' first tokens land behind the first tick
+        # (ISSUE 37), each with a fetch and a delivery of its own
+        assert len(first) == 2 and len(delivers) == 9
+        for (fs, fe), (ds, de) in zip(first, delivers[:2]):
+            assert sent[0][1] <= fs and fe <= ds and de <= sent[1][0]
+        delivers = delivers[2:]
         assert (len(sent), len(fetched), len(delivers)) == (8, 7, 7)
         for tick in range(1, 8):
             assert sent[tick][1] <= fetched[tick - 1][0]
@@ -1344,6 +1362,271 @@ class TestOneTickInFlight:
         assert eng.compiled_counts() == (1, 1)
         assert eng.lower_programs(names=(program,))[program].as_text() == text
         assert text.count("tensor<2xi32>") >= 2
+
+
+def _pend(eng, prompt, new):
+    """An admission whose chunks are dispatched and whose first token is
+    not fetched, as between a step's admissions and its tick."""
+    h = eng.submit(prompt, max_new_tokens=new)
+    assert eng._admit(eng.sched.pop_for_admission(), True) == 0
+    assert len(eng._first) == 1 and not h.tokens
+    return h
+
+
+class TestFirstTokenBehindTheTick:
+    """ISSUE 37: a step dispatches its decode tick before it fetches the
+    first tokens of its admissions.  The streams stay `generate()`'s,
+    the order of delivery stays the order of admission, and a pending
+    first token survives what a tick in flight survives."""
+
+    @pytest.mark.parametrize("kind", ["llama", "moe", "zaya", "granite"])
+    def test_streams_are_generates(self, tiny_model, kind):
+        """Waves of admissions beside ticks in flight, requests of one
+        and of two tokens among them, driven by bare `step()`s; the
+        counter reads the admissions less those ended by their first
+        token, and no step returns with a first token pending."""
+        model = tiny_model(kind)
+        eng = ServeEngine(model, num_slots=3, max_len=48, block_size=8,
+                          max_queue=8)
+        new = [6, 1, 9, 2, 5, 1, 7, 3]
+        prompts = _prompts(len(new), [5, 11, 17, 8], seed=71)
+        hs = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+        while eng.pending:
+            eng.step()
+            assert not eng._first
+        for p, n, h in zip(prompts, new, hs):
+            assert h.tokens == _ref(model, p, n)
+            assert h.finish_reason == "length"
+        snap = eng.metrics.snapshot()
+        assert snap["admitted"] == len(new)
+        assert snap["first_tokens_behind_tick"] == len(new) - new.count(1)
+        # what landed at once left the device with nothing: `admit`
+        assert 1 <= snap["host"]["exposed_n"]["admit"] <= new.count(1)
+        assert eng.pool.free_count == 3 and (eng.pool.ref == 0).all()
+        assert_program_count(eng, (1, 1))
+
+    def test_a_request_of_one_token_frees_its_slot_for_the_same_step(
+            self, llama):
+        eng = ServeEngine(llama, num_slots=1, max_len=32, block_size=8)
+        p, q = _prompts(2, [7, 5], seed=73)
+        seen = []
+
+        def note(tok, h):
+            seen.append((h.rid, len(eng._flying)))
+
+        a = eng.submit(p, max_new_tokens=1, on_token=note)
+        b = eng.submit(q, max_new_tokens=4, on_token=note)
+        assert eng.step() == 2
+        assert a.finish_reason == "length" and a.tokens == _ref(llama, p, 1)
+        # a's token landed before any tick was dispatched, b's behind one
+        assert seen == [(a.rid, 0), (b.rid, 1)]
+        assert b.tokens == _ref(llama, q, 4)[:1] and len(eng._flying) == 1
+        snap = eng.metrics.snapshot()
+        assert (snap["admitted"], snap["first_tokens_behind_tick"],
+                snap["decode_ticks"]) == (2, 1, 1)
+        eng.run_until_idle()
+        assert b.tokens == _ref(llama, q, 4)
+
+    def test_an_eos_as_first_token(self, llama):
+        """As an EOS from a run-ahead tick: the tick dispatched before
+        the landing took the request along, wrote one row into its own
+        block and picked a token nobody gets."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        p, q, r = _prompts(3, [7, 12, 5], seed=75)
+        stays = eng.submit(q, max_new_tokens=10)
+        eng.step()
+        eos, want = _eos_of(_ref(llama, p, 6), 0)
+        a = eng.submit(p, max_new_tokens=6, eos_id=eos)
+        after = eng.submit(r, max_new_tokens=5)
+        eng.step()
+        assert a.finish_reason == "eos" and a.tokens == want == [eos]
+        # it took part in the tick in flight, and is gone from its slot
+        (_, pairs, _), = eng._flying
+        assert [req for _, req in pairs] == [stays._req, a._req]
+        assert list(eng._running.values()) == [stays._req]
+        while eng.pending:
+            eng.step()
+        assert len(a.tokens) == 1
+        assert stays.tokens == _ref(llama, q, 10)
+        assert after.tokens == _ref(llama, r, 5)
+        assert (eng.pool.ref == 0).all()
+
+    def test_two_admissions_deliver_in_the_order_admitted(self, llama):
+        eng = ServeEngine(llama, num_slots=3, max_len=32, block_size=8)
+        prompts = _prompts(3, [7, 12, 5], seed=77)
+        seen = []
+        old = eng.submit(prompts[0], max_new_tokens=9,
+                         on_token=lambda t, h: seen.append(h.rid))
+        for _ in range(2):
+            eng.step()
+        del seen[:]
+        hs = [eng.submit(p, max_new_tokens=6,
+                         on_token=lambda t, h: seen.append(h.rid))
+              for p in prompts[1:]]
+        assert eng.step() == 3
+        # the first tokens, oldest admission first, then the tick before
+        assert seen == [hs[0].rid, hs[1].rid, old.rid]
+        assert eng.metrics.snapshot()["first_tokens_behind_tick"] == 3
+        eng.run_until_idle()
+        for p, h in zip(prompts[1:], hs):
+            assert h.tokens == _ref(llama, p, 6)
+
+    def test_pre_empted_before_its_token_lands_it_replays_from_its_prompt(
+            self, llama):
+        """Four usable blocks: the older request crosses into its third
+        in the step that admitted the younger into the last two.  Growth
+        pre-empts the youngest, whose first token is dropped unfetched."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8,
+                          num_blocks=5)
+        p, q = _prompts(2, [7, 12], seed=79)
+        old = eng.submit(p, max_new_tokens=16)
+        for _ in range(8):
+            eng.step()
+        assert int(eng.pool.pos[old._req.slot]) == 15
+        young = eng.submit(q, max_new_tokens=6)
+        eng.step()
+        snap = eng.metrics.snapshot()
+        assert snap["preempted"] == 1 and not eng._first
+        assert young.status == "queued" and young.tokens == []
+        assert snap["first_tokens_behind_tick"] == 1     # the older's own
+        eng.run_until_idle()
+        assert old.tokens == _ref(llama, p, 16)
+        assert young.tokens == _ref(llama, q, 6)
+        snap = eng.metrics.snapshot()
+        # admitted once, though prefilled twice
+        assert (snap["admitted"], snap["first_tokens_behind_tick"]) == (2, 2)
+        assert snap["ttft_ms"]["count"] == 2
+        assert_program_count(eng, (1, 1))
+
+    @pytest.mark.parametrize("kind", ["decode_false", "speculative"])
+    def test_no_plain_tick_to_put_it_behind_it_lands_at_once(self, llama,
+                                                              kind):
+        """The prefill worker's step dispatches no tick and the
+        speculative engine's no plain one: nothing is pending when
+        `step()` returns, and the token was there before it did."""
+        eng = _engine_of("speculative" if kind == "speculative" else "plain",
+                         llama, num_slots=2, max_len=32, block_size=8)
+        prompts = _prompts(2, [7, 12], seed=81)
+        hs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+        eng.step(decode=kind == "speculative")
+        assert not eng._first and not eng._flying
+        assert all(len(h.tokens) >= 1 for h in hs)
+        if kind == "decode_false":
+            assert [len(h.tokens) for h in hs] == [1, 1]
+        while eng.pending:
+            eng.step()
+            assert not eng._first
+        for p, h in zip(prompts, hs):
+            assert h.tokens == _ref(llama, p, 8)
+        snap = eng.metrics.snapshot()
+        assert snap["first_tokens_behind_tick"] == 0
+        assert snap["ttft_ms"]["count"] == snap["admitted"] == 2
+
+    @pytest.mark.parametrize("how", ["withdraw", "running_items",
+                                     "extract_handoff", "slot_cache",
+                                     "recover", "run_until_idle", "close"])
+    def test_moved_with_a_first_token_pending(self, llama, how):
+        """Whatever reads a request's tokens or moves a slot from
+        outside a step lands a pending first token before a tick in
+        flight."""
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        p, q = _prompts(2, [7, 12], seed=83)
+        old = eng.submit(p, max_new_tokens=10)
+        _fly(eng)
+        n = len(old.tokens)
+        order = []
+        old._req.on_token = lambda t, h: order.append("tick")
+        h = _pend(eng, q, 6)
+        h._req.on_token = lambda t, h: order.append("first")
+        slot = h._req.slot
+        if how == "withdraw":
+            req = eng.withdraw(slot)
+            assert req.tokens == _ref(llama, q, 6)[:1]
+            eng.sched.requeue_front([req])
+        elif how == "running_items":
+            assert [r for _, r in eng.running_items()] == [old._req, h._req]
+        elif how == "extract_handoff":
+            other = ServeEngine(llama, num_slots=2, max_len=32,
+                                block_size=8, programs=eng.programs())
+            pkg = eng.extract_handoff(slot)
+            assert pkg.req.tokens == _ref(llama, q, 6)[:1]
+            assert other.inject_handoff(pkg)
+            other.run_until_idle()
+        elif how == "slot_cache":
+            k, _ = eng.slot_cache(slot)[0]
+            assert k.shape[0] == q.size
+        elif how == "recover":
+            eng.recover("test")
+        else:
+            getattr(eng, how)()
+        assert order[:2] == ["first", "tick"]
+        assert eng._closed or not (eng._first or eng._flying)
+        # no tick was dispatched behind that chunk: the older's own only
+        assert how in ("run_until_idle", "close") or \
+            eng.metrics.snapshot()["first_tokens_behind_tick"] == 1
+        if how in ("running_items", "slot_cache"):
+            assert (len(h.tokens), len(old.tokens)) == (1, n + 1)
+        if not eng._closed:
+            eng.run_until_idle()
+        assert h.tokens == _ref(llama, q, 6)
+        assert old.tokens == _ref(llama, p, 10)
+
+    @pytest.mark.parametrize("where", ["behind", "at_once"])
+    def test_a_chunk_that_died_on_the_device(self, llama, monkeypatch,
+                                             where):
+        """Behind the tick its failure surfaces at the deferred fetch,
+        under `step()`'s handler: the arena is rebuilt and every request
+        replays, the admitted one from its prompt.  At once it is, as
+        before, inside the admission: that request is quarantined."""
+        import warnings
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        p, q = _prompts(2, [7, 12], seed=85)
+        old = eng.submit(p, max_new_tokens=10)
+        _fly(eng)
+        real, died = eng._fetch_first, []
+
+        def dead(arr, slot, behind):
+            if not died:
+                died.append(behind)
+                raise RuntimeError("the chunk died on the device")
+            return real(arr, slot, behind)
+
+        monkeypatch.setattr(eng, "_fetch_first", dead)
+        new = 6 if where == "behind" else 1
+        h = eng.submit(q, max_new_tokens=new)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            eng.step()
+            snap = eng.metrics.snapshot()
+            assert died == [where == "behind"]
+            assert (snap["recoveries"], snap["quarantined"]) == \
+                ((1, 0) if where == "behind" else (0, 1))
+            assert not eng._first
+            eng.run_until_idle()
+        assert old.tokens == _ref(llama, p, 10)
+        if where == "behind":
+            assert h.tokens == _ref(llama, q, new) and not h.failed
+            assert eng.metrics.snapshot()["admitted"] == 2
+        else:
+            assert h.failed and h.tokens == []
+        assert (eng.pool.ref == 0).all()
+
+    def test_the_counter_is_in_the_sink(self, llama, tmp_path):
+        eng = ServeEngine(llama, num_slots=2, max_len=32, block_size=8)
+        path = str(tmp_path / "ev.jsonl")
+        events.configure(path=path)
+        try:
+            for p, n in zip(_prompts(3, [7, 12, 5], seed=87), (5, 1, 3)):
+                eng.submit(p, max_new_tokens=n)
+            eng.run_until_idle()
+        finally:
+            events.configure()
+        lines = [json.loads(l) for l in open(path)]
+        mine = [e for e in lines
+                if e["name"] == "serve.first_tokens_behind_tick"]
+        assert len(mine) == 2 == \
+            eng.metrics.snapshot()["first_tokens_behind_tick"]
+        assert all(e["kind"] == "counter" and "trace" in e for e in mine)
 
 
 def test_loadgen_quick_run_emits_valid_record(llama, engine, tmp_path):
