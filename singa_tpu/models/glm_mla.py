@@ -17,8 +17,15 @@ FFN(RMSNorm(h))``; ``logits = RMSNorm(h) W_head``.
   folded into the query (``q_lat_h = q_nope_h W_UK_h^T``,
   ``kv_lora_rank`` wide) and its value half into the output (``o_h = (p
   c_kv) W_UV_h``), so every head reads the latent rows themselves; a
-  decode token through ``ops.mla_attention``'s kernel where the arena's
-  pools are read in place, a prefill chunk over its gathered view.  The
+  decode token through ``ops.mla_attention``'s decode kernel where the
+  arena's pools are read in place, a prefill chunk over its gathered
+  view through the same module's prefill kernel where
+  ``ops.kv_cache.attends_view`` takes the view (bf16 or f32, whole
+  128-lane latent rows, a length of whole KV tiles, a TPU): the chunk's
+  rows times the heads against the view's tiles up to ``pos + T``,
+  online softmax, no score written to HBM; elsewhere (the CPU, other
+  shapes, ``forward()`` without a cache) masked softmax over the whole
+  view.  The
   EXPANDED form (per-head keys and values from ``W_kvb`` over every row
   attended) is the reference's: on the chip it took 1.35x as long as
   the absorbed at a 256-row chunk over 16.6k cached rows and 1.13x at
@@ -51,7 +58,7 @@ import numpy as np
 
 from .. import autograd, layer, model
 from ..ops import kv_cache as kv_ops
-from ..ops.mla_attention import mla_decode_attention
+from ..ops.mla_attention import mla_decode_attention, mla_prefill_attention
 from ..ops.rope import apply_rope
 from ..tensor import Tensor
 from ._generate import GenerateMixin
@@ -149,6 +156,9 @@ class _MLA(layer.Layer):
         self.o = layer.Linear(c.dim, bias=False)
         self._rope = _rope_tables(c.qk_rope_head_dim, c.max_position,
                                   float(c.rope_theta))
+        # whether the latest cached forward over a dense view that was
+        # traced took the prefill kernel
+        self.took_kernel = False
 
     def initialize(self, x: Tensor, *_):
         lo, hi = self.cfg.latent_norm_init
@@ -210,9 +220,18 @@ class _MLA(layer.Layer):
                     ck.pool, cr.pool, ck.tables, pos + 1, scale=scale)
             o = self._unabsorb(o_lat[:, None], wkv)
         else:
+            # the rows over the slot's dense view: a prefill chunk, a
+            # verify's k + 1 rows, a dense cache's decode token
+            self.took_kernel = kv_ops.attends_view(ck)
             with jax.named_scope("attn.mla.prefill"):
-                o = self._attend(q_nope, q_rope, ck, cr[..., :dr], wkv,
-                                 pos + T, scale)
+                if self.took_kernel:
+                    o_lat = mla_prefill_attention(
+                        self._absorb(q_nope, wkv), q_rope, ck, cr, pos + T,
+                        scale=scale)
+                    o = self._unabsorb(o_lat, wkv)
+                else:
+                    o = self._attend(q_nope, q_rope, ck, cr[..., :dr], wkv,
+                                     pos + T, scale)
         return self._out(o, x), (ck, cr)
 
     def _out(self, o, x):
@@ -327,6 +346,14 @@ class GlmMla(GenerateMixin, model.Model):
         return [(jnp.zeros((batch, max_len, c.kv_lora_rank), dtype),
                  jnp.zeros((batch, max_len, c.rope_lanes), dtype))
                 for _ in range(c.num_layers)]
+
+    @property
+    def prefill_kernel(self) -> bool:
+        """Whether the latest traced cached forward over dense views
+        attended through ``ops.mla_attention``'s prefill kernel: set
+        while a program traces, so a serving engine reads it once for
+        its compiled prefill program."""
+        return any(b.attn.took_kernel for b in self.blocks)
 
     def forward_cached(self, ids: Tensor, caches, pos):
         x = self.tok_emb(ids)

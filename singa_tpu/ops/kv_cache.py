@@ -140,6 +140,16 @@ def reads_blocks(pool) -> bool:
     return tiles(pool.shape, pool.dtype) and on_tpu()
 
 
+def attends_view(view) -> bool:
+    """Whether a latent model's cached attention over this dense view,
+    ``(B, max_len, kv_lora_rank)``, runs as ``ops.mla_attention``'s
+    prefill kernel instead of masked softmax over the whole view: a bf16
+    or f32 view of a shape the kernel tiles, on a TPU.  Decided by what
+    the view is, like :func:`reads_blocks`; there is no switch."""
+    from .mla_attention import prefill_tiles
+    return prefill_tiles(view.shape, view.dtype) and on_tpu()
+
+
 def quantize_kv(x):
     """Quantize ``x`` (..., K, D) to (int8 codes, f32 scales): one
     symmetric absmax scale per leading index (per position), shape

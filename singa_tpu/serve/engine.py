@@ -322,6 +322,10 @@ class ServeEngine:
         cfg = getattr(model, "cfg", None)
         self._moe_top_k = int(getattr(cfg, "moe_top_k", 0)) \
             if getattr(cfg, "num_experts", 0) else 0
+        # whether the prefill program's latent attention took the fused
+        # kernel: recorded by a latent model while the program traces,
+        # read after the first chunk's dispatch
+        self._prefill_kernel = None
         self.share_prefix = bool(share_prefix)
         self.sched = Scheduler(
             max_queue=2 * num_slots if max_queue is None else max_queue)
@@ -1448,8 +1452,12 @@ class ServeEngine:
                         self.metrics.on_prefill_chunk(
                             max(0, start + chunk.size - fresh))
                         if self.pool.latent_row_bytes:
+                            if self._prefill_kernel is None:
+                                self._prefill_kernel = bool(getattr(
+                                    self.model, "prefill_kernel", False))
                             self.metrics.on_mla_prefill(
-                                chunk.size * (start + chunk.size))
+                                chunk.size * (start + chunk.size),
+                                self._prefill_kernel)
                         if self._moe_top_k:
                             self.metrics.on_moe_dispatch(
                                 chunk.size * self._moe_top_k)

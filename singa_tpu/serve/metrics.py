@@ -198,6 +198,13 @@ name                        kind       meaning
                                        scores: its valid rows x the
                                        rows below its end.
                                        ``snapshot()`` keeps both totals
+``serve.mla_prefill_kernel_chunks``  counter  one such chunk dispatched
+                                       through a prefill program whose
+                                       trace took the fused prefill
+                                       kernel (``ops/mla_attention.py``;
+                                       the model records the choice
+                                       while the program traces).
+                                       ``snapshot()`` keeps the total
 ``serve.token``             counter    one token delivered to a request
                                        (prefill first token, decode
                                        tick, recovery/preemption replay
@@ -610,6 +617,7 @@ class ServeMetrics:
         # latent-attention models (models/glm_mla.py); 0 otherwise
         self.mla_latent_bytes = 0
         self.mla_prefill_pairs = 0
+        self.mla_prefill_kernel_chunks = 0
         self._ttft = _Hist()
         self._token = _Hist()
 
@@ -799,11 +807,15 @@ class ServeMetrics:
         self.mla_latent_bytes += nbytes
         events.counter("serve.mla_latent_bytes", nbytes)
 
-    def on_mla_prefill(self, pairs: int) -> None:
+    def on_mla_prefill(self, pairs: int, kernel: bool) -> None:
         """One prefill chunk whose latent attention scores ``pairs``
-        (query row, cached row) pairs."""
+        (query row, cached row) pairs; ``kernel``: through the fused
+        prefill kernel (``ops/mla_attention.py``)."""
         self.mla_prefill_pairs += pairs
         events.counter("serve.mla_prefill_pairs", pairs)
+        if kernel:
+            self.mla_prefill_kernel_chunks += 1
+            events.counter("serve.mla_prefill_kernel_chunks", 1)
 
     @property
     def accept_rate(self) -> Optional[float]:
@@ -898,6 +910,7 @@ class ServeMetrics:
             "ssm_state_bytes": self.ssm_state_bytes,
             "mla_latent_bytes": self.mla_latent_bytes,
             "mla_prefill_pairs": self.mla_prefill_pairs,
+            "mla_prefill_kernel_chunks": self.mla_prefill_kernel_chunks,
             "accept_rate": self.accept_rate,
             "tokens_per_dispatch": self.tokens_per_dispatch,
             "ttft_ms": self._ttft.summary(),

@@ -10,8 +10,9 @@ f32, seeded weights.
 attention, no cache, no chunks, nothing imported from the program).
 The program is held to it through `GlmMla.forward`, and through
 `ServeEngine`: prefill of more than one chunk, a prefix hit on a shared
-document, decode through the latent kernel in interpret mode, what the
-programs left in the arena, and the tokens served.
+document, decode through the latent kernel and a chunk's attention
+through the prefill kernel in interpret mode, what the programs left in
+the arena, and the tokens served.
 """
 
 import contextlib
@@ -26,7 +27,8 @@ import reference_glm_mla as ref
 from singa_tpu import models, tensor
 from singa_tpu.ops import kv_cache as kv_ops
 from singa_tpu.ops import moe as moe_ops
-from singa_tpu.ops.mla_attention import mla_decode_attention
+from singa_tpu.ops.mla_attention import (mla_decode_attention,
+                                         mla_prefill_attention)
 from singa_tpu.serve import ServeEngine
 from singa_tpu.serve.slots import BlockPool
 
@@ -67,9 +69,10 @@ def glm():
 
 @contextlib.contextmanager
 def _as_on_a_tpu():
-    """The platform probe of `ops.kv_cache` steered while a decode
-    program traces: the arena's latent pools are then read in place by
-    the kernel, which itself still sees the CPU (interpret mode)."""
+    """The platform probe of `ops.kv_cache` steered while a program
+    traces: the arena's latent pools are then read in place by the
+    decode kernel, and a view the prefill kernel tiles is attended by
+    it; the kernels themselves still see the CPU (interpret mode)."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(kv_ops, "on_tpu", lambda: True)
         yield
@@ -221,6 +224,7 @@ def _engine(m, paged, **kw):
     kw = dict(dict(num_slots=3, max_len=640, block_size=BS), **kw)
     eng = ServeEngine(m, **kw)
     if paged:
+        # both programs trace here
         with _as_on_a_tpu():
             assert kv_ops.reads_blocks(eng.pool.caches[0][0])
             eng.submit(np.arange(5, dtype=np.int32), max_new_tokens=2)
@@ -228,28 +232,35 @@ def _engine(m, paged, **kw):
     return eng
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["view", "kernel"])
-def test_engine_matches_the_reference(glm, paged):
+@pytest.mark.parametrize("case", ["view", "kernel", "prefill-kernel"])
+def test_engine_matches_the_reference(glm, case):
     """A shared 272-token document and private questions: the first
     prompt prefills in two chunks, the next ones hit the document's
     blocks and prefill their questions; decode reads the arena in place
-    through the latent kernel (or a gathered view).  Every served token
-    is the reference's arg-max of the same sequence, the latent blocks
-    the programs left are the reference's c_kv and rotated k_rope, and
-    one more decode's logits are the reference's."""
+    through the latent kernel (or a gathered view), and with a view of
+    whole KV tiles a chunk attends it through the prefill kernel.
+    Every served token is the reference's arg-max of the same sequence,
+    the latent blocks the programs left are the reference's c_kv and
+    rotated k_rope, and one more decode's logits are the reference's."""
     rng = np.random.default_rng(5)
     doc = rng.integers(0, VOCAB, 272).astype(np.int32)
     prompts = [np.concatenate([doc, rng.integers(0, VOCAB, n)])
                .astype(np.int32) for n in (30, 7, 45)]
     with HI:
-        eng = _engine(glm, paged)
+        eng = _engine(glm, case != "view", **(
+            dict(max_len=1024) if case == "prefill-kernel" else {}))
         hs = [eng.submit(p, max_new_tokens=6) for p in prompts[:2]]
         eng.run_until_idle()
         long = eng.submit(prompts[2], max_new_tokens=40)
         for _ in range(12):
             eng.step()
-    assert eng.metrics.snapshot()["prefix_hit_tokens"] >= 2 * 272 - 2 * BS
+    snap = eng.metrics.snapshot()
+    assert snap["prefix_hit_tokens"] >= 2 * 272 - 2 * BS
     assert eng.compiled_counts() == (1, 1)
+    if case == "prefill-kernel":
+        assert snap["mla_prefill_kernel_chunks"] == snap["prefill_chunks"] > 3
+    else:
+        assert snap["mla_prefill_kernel_chunks"] == 0 < snap["prefill_chunks"]
     params = _params(glm)
     for h, p in zip(hs, prompts):
         seq = np.concatenate([p, h.tokens])
@@ -294,6 +305,104 @@ def test_kernel_at_ragged_lengths(dtype):
         want = (p / p.sum(-1, keepdims=True)) @ L
         np.testing.assert_allclose(np.asarray(got[s], np.float32), want,
                                    atol=tol, rtol=tol)
+
+
+
+def _masked_softmax(ql, qr, view, rope_view, limit, scale):
+    """Absorbed attention of rows (B, T, H, .) over a dense view under
+    per-row limits, in f32 numpy: softmax over the rows each sees."""
+    B, T, H, r = ql.shape
+    ql, qr, view, rope_view = (np.asarray(a, np.float32)
+                               for a in (ql, qr, view, rope_view))
+    rope_view = rope_view[..., :qr.shape[-1]]
+    out = np.zeros((B, T, H, r), np.float32)
+    for b in range(B):
+        for t in range(T):
+            n = limit[b] - T + t + 1
+            sc = (ql[b, t] @ view[b, :n].T
+                  + qr[b, t] @ rope_view[b, :n].T) * scale       # (H, n)
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            out[b, t] = (p / p.sum(-1, keepdims=True)) @ view[b, :n]
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rows,limits,length", [
+    (7, [7], 1024),             # a chunk from position 0: limit = T
+    (9, [511], 1024),           # a limit under a KV tile's edge
+    (9, [512], 1024),           # on it
+    (9, [513], 1024),           # over it
+    (5, [1536], 1536),          # the view's whole length
+    (1, [700], 1024),           # one row, as a dense cache's decode
+    (256, [900], 1024),         # a query tile's worth (256 x 4 heads)
+    (300, [1024], 1024),        # two query tiles, the second padded
+    (3, [9, 1536], 1536),       # two batch rows, a limit each
+], ids=["from-0", "under-edge", "on-edge", "over-edge", "view-end",
+        "one-row", "tile", "two-tiles", "batch"])
+def test_prefill_kernel_against_masked_softmax(dtype, rows, limits, length):
+    """The prefill kernel (interpret mode) against masked softmax over
+    the view, rope lanes zero-padded as the cache holds them."""
+    rng = np.random.default_rng(rows + length)
+    B, H, r, dr = len(limits), 4, 128, 64
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)
+    ql, qr = f(B, rows, H, r), f(B, rows, H, dr)
+    view = f(B, length, r)
+    rope_view = f(B, length, 128).at[..., dr:].set(0)
+    limit = np.asarray(limits, np.int32)
+    got = mla_prefill_attention(
+        ql, qr, view, rope_view, jnp.asarray(limit) if B > 1 else
+        jnp.int32(limit[0]), scale=0.1, interpret=True)
+    assert got.shape == (B, rows, H, r) and got.dtype == dtype
+    want = _masked_softmax(ql, qr, view, rope_view, limit, 0.1)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=tol,
+                               rtol=tol)
+
+
+def test_the_prefill_kernel_is_chosen_by_the_view():
+    """bf16 or f32, whole 128-lane latent rows, a length of whole KV
+    tiles, a TPU: anything else keeps masked softmax over the view."""
+    takes = lambda shape, dt: kv_ops.attends_view(jnp.zeros(shape, dt))
+    assert not takes((1, 1024, 128), jnp.float32)          # the CPU
+    with _as_on_a_tpu():
+        assert takes((1, 1024, 128), jnp.float32)
+        assert takes((2, 18432, 512), jnp.bfloat16)
+        assert not takes((1, 640, 128), jnp.float32)       # length
+        assert not takes((1, 1024, 96), jnp.float32)       # lanes
+        assert not takes((1, 1024, 128), jnp.float16)      # dtype
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr and of those inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+def test_the_prefill_kernel_names_the_view(glm):
+    """What `benchmark/mla_count.py` finds a chunk's attention by: in a
+    steered prefill program every layer's kernel call takes an operand
+    with the engine's `max_len` axis, and none is named as the decode
+    kernel (whose seconds that count leaves out)."""
+    eng = ServeEngine(glm, num_slots=2, max_len=1024, block_size=BS)
+    zero = np.int32(0)
+    with _as_on_a_tpu():
+        jaxpr = jax.make_jaxpr(eng._prefill)(
+            eng._params, eng._buffers, np.zeros((1, eng._chunk), np.int32),
+            zero, np.int32(eng._chunk - 1), zero, zero, eng.pool.tables,
+            eng._toks, eng.pool.caches, None, None, None, None)
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert len(calls) == CFG.num_layers
+    for eqn in calls:
+        assert "mla_decode_attention" not in eqn.params["name"]
+        assert eqn.params["name"] == "mla_prefill_attention"
+        assert any(1024 in v.aval.shape for v in eqn.invars)
 
 
 def test_pool_holds_latent_blocks(glm):

@@ -4,7 +4,9 @@ On the CPU the Pallas kernel runs in interpret mode against the path it
 replaces in `decode_paged`: `gather_block_kv` + `cached_sdpa` over a
 dense view.  Interpret mode cannot see a misaligned slice or too much
 VMEM, so the three serve cells' real shapes are also compiled for a
-described (not attached) TPU v5e, skipped where none can be described.
+described (not attached) TPU v5e, skipped where none can be described,
+and so are the latent prefill kernel's (ops/mla_attention.py) at the
+latent cell's chunk and the f32 view its check scores through.
 """
 
 import re
@@ -135,6 +137,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled(fn, *args):
+    """`fn` compiled for the described chip.  Such a compile is written
+    to the persistent cache and cannot be read back without a chip:
+    keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("S,H,K,max_blocks,window,dtype", [
     (32, 32, 8, 64, 0, "bfloat16"),        # serve-chat-closed
     (32, 32, 4, 64, 1024, "bfloat16"),     # serve-code-closed, sliding
@@ -146,7 +163,6 @@ def test_the_cells_shapes_compile_for_a_v5e(one_chip, S, H, K, max_blocks,
                                             window, dtype):
     """Mosaic takes the kernel at the benchmark's shapes: block copies
     aligned to the pools' tiling, the chunk buffers inside VMEM."""
-    from jax.experimental.compilation_cache import compilation_cache
     bs, d = 32, 128
     pool = (S * max_blocks + 1, bs, K, d)
     assert tiles(pool, dtype)
@@ -158,19 +174,9 @@ def test_the_cells_shapes_compile_for_a_v5e(one_chip, S, H, K, max_blocks,
         return paged_attention(q, kp, vp, tables, lengths, window=window,
                                interpret=False)
 
-    # a compile for a described chip is written to the persistent cache
-    # and cannot be read back without one: keep it out
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(attend).lower(
-            arg((S, H, d), dtype), arg(pool, dtype), arg(pool, dtype),
-            arg((S, max_blocks), "int32"), arg((S,), "int32")).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-    text = compiled.as_text()
+    text = _compiled(
+        attend, arg((S, H, d), dtype), arg(pool, dtype), arg(pool, dtype),
+        arg((S, max_blocks), "int32"), arg((S,), "int32")).as_text()
     assert text.count("tpu_custom_call") == 1
     # the pools go in as they are: a bitcast to (N, bs·K, D), and no
     # copy or relayout of an arena under either shape
@@ -179,3 +185,31 @@ def test_the_cells_shapes_compile_for_a_v5e(one_chip, S, H, K, max_blocks,
         r"= \w+(\[[\d,]*\])(?:\{[^}]*\})? ([\w-]+)\(", text)
         if m.group(1) in arena]
     assert sorted(set(ops)) == ["bitcast", "parameter"], ops
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_latent_prefill_compiles_for_a_v5e(one_chip, dtype):
+    """Mosaic takes the latent prefill kernel at `serve-longdoc-closed`'s
+    chunk, 256 rows x 20 heads over an 18,432-row view of 512 + 128
+    lanes, in bf16 (the timed program) and in f32 at HIGHEST (the
+    check's scorer), its tiles inside VMEM; one call, the view its
+    operand as the engine gathers it."""
+    from singa_tpu.ops.mla_attention import (mla_prefill_attention,
+                                             prefill_tiles)
+    T, H, r, dr, S = 256, 20, 512, 64, 18432
+    assert prefill_tiles((1, S, r), dtype)
+
+    def arg(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def attend(ql, qr, view, rope_view, limit):
+        return mla_prefill_attention(ql, qr, view, rope_view, limit,
+                                     scale=1 / 16, interpret=False)
+
+    text = _compiled(attend, arg((1, T, H, r)), arg((1, T, H, dr)),
+                     arg((1, S, r)), arg((1, S, 128)),
+                     arg((), "int32")).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "mla_prefill_attention" in calls[0]
+    short = {"bfloat16": "bf16", "float32": "f32"}[dtype]
+    assert f"{short}[1,{S},{r}]" in calls[0]
