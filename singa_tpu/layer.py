@@ -678,9 +678,11 @@ class MultiHeadAttention(Layer):
 class _MoEOp(autograd.Operator):
     def __init__(self, cf, top_k=1, swiglu=False, dispatch_mode="auto",
                  dropless=False, router_fn=None, n_router=1,
-                 experts_held=None, sigmoid=False, route_scale=1.0):
+                 experts_held=None, sigmoid=False, route_scale=1.0,
+                 groups=(1, 1)):
         super().__init__()
         self.experts_held = experts_held
+        self.groups = groups
         # a sigmoid router: its correction bias is the array after the
         # router's (MoE.score_bias)
         self.sigmoid = sigmoid
@@ -703,8 +705,8 @@ class _MoEOp(autograd.Operator):
         wi, wo, *wg = ws
         router = rw[0] if self.router_fn is None else \
             (lambda xf: self.router_fn(xf, *rw))
-        extra = dict(score_bias=bias, route_scale=self.route_scale) \
-            if self.sigmoid else {}
+        extra = dict(score_bias=bias, route_scale=self.route_scale,
+                     groups=self.groups) if self.sigmoid else {}
         out, aux = moe_forward(xa, router, wi, wo, self.cf, return_aux=True,
                                top_k=self.top_k,
                                w_gate=wg[0] if self.swiglu else None,
@@ -786,7 +788,10 @@ class MoE(Layer):
     layer's `score_bias` (E,), a correction bias that balances the load
     without an auxiliary loss, and the gates are the chosen scores
     without it, renormalised over the k, times `route_scale`
-    (ops/moe.py::_sigmoid_gates).
+    (ops/moe.py::_sigmoid_gates).  `groups` = (`n_group`,
+    `topk_group`), sigmoid only: the experts are `n_group` groups in id
+    order, and the top-k is taken among the experts of the `topk_group`
+    groups whose two best score + bias sum highest.
 
     The router's load-balance auxiliary losses accumulate across
     *training-mode* calls (eval and compile-time dry runs don't
@@ -808,13 +813,22 @@ class MoE(Layer):
                  dropless: bool = False, router: Optional[Layer] = None,
                  experts_held: Optional[Sequence[int]] = None,
                  score: str = "softmax", route_scale: float = 1.0,
-                 name=None):
+                 groups: Tuple[int, int] = (1, 1), name=None):
         super().__init__(name)
         if score not in ("softmax", "sigmoid"):
             raise ValueError(f"MoE score must be softmax or sigmoid, got "
                              f"{score!r}")
         if score == "sigmoid" and not dropless:
             raise ValueError("a sigmoid router is the dropless form's")
+        n_group, topk_group = groups
+        if (n_group, topk_group) != (1, 1) and (
+                score != "sigmoid" or num_experts % n_group
+                or not 1 <= topk_group <= n_group
+                or top_k > topk_group * (num_experts // n_group)):
+            raise ValueError(
+                f"groups {groups} of {num_experts} experts at top {top_k}: "
+                f"a sigmoid router's groups divide the experts and hold "
+                f"the top-k")
         if experts_held is not None:
             experts_held = tuple(int(e) for e in experts_held)
             if not dropless:
@@ -854,6 +868,7 @@ class MoE(Layer):
         # share of the `num_experts` it routes over (ops/moe.py)
         self.experts_held = experts_held
         self.score, self.route_scale = score, route_scale
+        self.groups = (int(n_group), int(topk_group))
         self._mlp_router = router is not None
         if router is not None:
             self.router = router
@@ -901,7 +916,7 @@ class MoE(Layer):
         out, aux = _MoEOp(self.capacity_factor, self.top_k,
                           self.act == "swiglu", self.dispatch_mode,
                           self.dropless, fn, len(rw), self.experts_held,
-                          sigmoid, self.route_scale)(
+                          sigmoid, self.route_scale, self.groups)(
             x, *rw, *bias, self.w_in, self.w_out, *extra)
         # accumulate only in training: eval/compile-time dry runs must
         # not leave stale entries (an init-trace tracer here would crash

@@ -22,6 +22,11 @@ Families:
                    cache of one compressed row a token, a dense layer,
                    then sigmoid-routed experts with a correction bias
                    beside a shared expert, untied head; inference only
+  * ling         — Ling-3.0-flash: Kimi delta attention (a per-channel
+                   gated delta rule with an f32 state) in five layers of
+                   six, MLA with a head-wise gate in the sixth, experts
+                   routed within the best groups beside a shared expert,
+                   untied head; inference only
 
 Every model is a singa_tpu.model.Model: imperative forward, trains
 eagerly or as one compiled XLA module, shards over a mesh via the
@@ -37,6 +42,7 @@ from . import llama
 from . import zaya
 from . import granite_hybrid
 from . import glm_mla
+from . import ling
 
 from .mlp import MLP
 from .cnn import CNN, LeNet5, AlexNet
@@ -48,19 +54,21 @@ from .llama import Llama, LlamaConfig
 from .zaya import Zaya, ZayaConfig
 from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .glm_mla import GlmMla, GlmMlaConfig
+from .ling import Ling, LingConfig
 from .convert import (from_hf, from_hf_bert, from_hf_gpt2,
                       from_hf_llama, from_hf_mistral,
                       from_hf_mixtral, to_hf)
 
 __all__ = [
     "mlp", "cnn", "resnet", "vgg", "transformer", "llama", "zaya",
-    "granite_hybrid", "glm_mla",
+    "granite_hybrid", "glm_mla", "ling",
     "MLP", "CNN", "LeNet5", "AlexNet",
     "ResNet", "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
     "VGG", "vgg11", "vgg13", "vgg16", "vgg19",
     "GPT2", "BERT", "GPT2Config", "BERTConfig",
     "Llama", "LlamaConfig", "Zaya", "ZayaConfig",
     "GraniteHybrid", "GraniteHybridConfig", "GlmMla", "GlmMlaConfig",
+    "Ling", "LingConfig",
     "from_hf", "from_hf_bert", "from_hf_gpt2", "from_hf_llama",
     "from_hf_mistral", "from_hf_mixtral",
     "to_hf",

@@ -21,7 +21,7 @@ FFN(RMSNorm(h))``; ``logits = RMSNorm(h) W_head``.
   arena's pools are read in place, a prefill chunk over its gathered
   view through the same module's prefill kernel where
   ``ops.kv_cache.attends_view`` takes the view (bf16 or f32, whole
-  128-lane latent rows, a length of whole KV tiles, a TPU): the chunk's
+  128-lane latent rows, a TPU): the chunk's
   rows times the heads against the view's tiles up to ``pos + T``,
   online softmax, no score written to HBM; elsewhere (the CPU, other
   shapes, ``forward()`` without a cache) masked softmax over the whole
@@ -72,7 +72,7 @@ class GlmMlaConfig:
     dim: int = 2048
     num_layers: int = 47
     num_heads: int = 20
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768    # None: no query compression
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
@@ -143,12 +143,25 @@ def _f32(a, b, spec):
 
 
 class _MLA(layer.Layer):
-    def __init__(self, cfg: GlmMlaConfig, name=None):
+    """The latent attention of a layer.  ``cfg.q_lora_rank`` None: the
+    queries straight from the input, ``q = x W_q``, with no norm.
+    ``head_gate``: each head's output scaled by ``sigmoid(x w_h)``
+    before ``W_o`` (``x`` the layer's normed input; one weight column a
+    head, parameter ``gate.W``)."""
+
+    def __init__(self, cfg: GlmMlaConfig, head_gate: bool = False,
+                 name=None):
         super().__init__(name)
         c = self.cfg = cfg
         H = c.num_heads
-        self.q_a = layer.Linear(c.q_lora_rank, bias=False)
-        self.q_b = layer.Linear(H * c.qk_head_dim, bias=False)
+        if c.q_lora_rank:
+            self.q_a = layer.Linear(c.q_lora_rank, bias=False)
+            self.q_b = layer.Linear(H * c.qk_head_dim, bias=False)
+        else:
+            self.q = layer.Linear(H * c.qk_head_dim, bias=False)
+        self.head_gate = head_gate
+        if head_gate:
+            self.gate = layer.Linear(H, bias=False)
         self.kv_a = layer.Linear(c.kv_lora_rank + c.qk_rope_head_dim,
                                  bias=False)
         self.kv_b = layer.Linear(H * (c.qk_nope_head_dim + c.v_head_dim),
@@ -166,8 +179,9 @@ class _MLA(layer.Layer):
         def norm(n):
             return Tensor((n,), x.device, np.float32).uniform(lo, hi)
 
-        self.q_a_norm = self.register_param(
-            "q_a_norm", norm(self.cfg.q_lora_rank))
+        if self.cfg.q_lora_rank:
+            self.q_a_norm = self.register_param(
+                "q_a_norm", norm(self.cfg.q_lora_rank))
         self.kv_a_norm = self.register_param(
             "kv_a_norm", norm(self.cfg.kv_lora_rank))
         # read as a matrix by the absorbed form and never called, so it
@@ -193,9 +207,13 @@ class _MLA(layer.Layer):
                         c.kv_lora_rank)
         cos, sin = self._rope
         with jax.named_scope("attn.mla.q"):
-            cq = self.q_a(x).data
-            cq = _rms(cq, self.q_a_norm.data, c.eps)
-            q = self.q_b(_tensor(cq, x)).data.reshape(B, T, H, c.qk_head_dim)
+            if c.q_lora_rank:
+                cq = self.q_a(x).data
+                cq = _rms(cq, self.q_a_norm.data, c.eps)
+                q = self.q_b(_tensor(cq, x)).data
+            else:
+                q = self.q(x).data
+            q = q.reshape(B, T, H, c.qk_head_dim)
             q_nope = q[..., :dn]
             q_rope = apply_rope(q[..., dn:], cos, sin, pos)
         with jax.named_scope("attn.mla.kv_a"):
@@ -238,6 +256,9 @@ class _MLA(layer.Layer):
         c = self.cfg
         B, T = o.shape[:2]
         with jax.named_scope("attn.mla.out"):
+            if self.head_gate:
+                o = o * jax.nn.sigmoid(
+                    self.gate(x).data.astype(jnp.float32))[..., None]
             return self.o(_tensor(o.reshape(B, T, c.num_heads * c.v_head_dim)
                                   .astype(x.data.dtype), x))
 

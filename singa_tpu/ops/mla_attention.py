@@ -41,10 +41,12 @@ scalar prefetched: folded row ``q`` sees view row ``s`` where ``s <=
 limit - T + q // H`` (bottom-right-aligned causality); a KV tile past
 the limit is skipped and its index clamped to the last live tile, so
 no DMA fetches it, and only the tiles the diagonal crosses are masked.
+A view whose length is not whole KV tiles is padded with zero rows to
+the next tile's edge; they lie past every limit, so the mask drops them.
 The f32 scores of a tile live in VMEM and are never written to HBM.
 bf16 views take single-pass dots, f32 views ``Precision.HIGHEST``.
 ``ops.kv_cache.attends_view`` chooses it: a bf16 or f32 view of whole
-128-lane latent rows and whole KV tiles (``prefill_tiles``), on a TPU.
+128-lane latent rows (``prefill_tiles``), on a TPU.
 
 Interpret mode runs the same kernels on the CPU for tests; it is on only
 when the caller asks for it or the platform is not a TPU.
@@ -87,10 +89,10 @@ def tiles(latent_shape, dtype) -> bool:
 def prefill_tiles(view_shape, dtype) -> bool:
     """Whether the prefill kernel takes a dense latent view of this
     shape, ``(B, max_len, r)``: bf16 or f32, a latent of whole 128-lane
-    rows, a length of whole KV tiles."""
+    rows (any length: the kernel pads the last KV tile)."""
     return (len(view_shape) == 3
             and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
-            and view_shape[2] % 128 == 0 and view_shape[1] % _KV_ROWS == 0)
+            and view_shape[2] % 128 == 0)
 
 
 def _chunk_blocks(bs: int, max_blocks: int) -> int:
@@ -296,6 +298,12 @@ def mla_prefill_attention(q_lat, q_rope, view, rope_view, limit, *,
     B, T, H, r = q_lat.shape
     S, dr = view.shape[1], rope_view.shape[-1]
     dt = view.dtype
+    TK = _KV_ROWS
+    if S % TK:
+        # zero rows up to the last tile's edge, past every limit
+        pad = ((0, 0), (0, -S % TK), (0, 0))
+        view, rope_view = jnp.pad(view, pad), jnp.pad(rope_view, pad)
+        S = view.shape[1]
     if interpret is None:
         interpret = not on_tpu()
     n = T * H
@@ -307,7 +315,6 @@ def mla_prefill_attention(q_lat, q_rope, view, rope_view, limit, *,
         return jnp.pad(q, ((0, 0), (0, N - n), (0, lanes - q.shape[-1])))
 
     lim = jnp.broadcast_to(jnp.asarray(limit, jnp.int32), (B,))
-    TK = _KV_ROWS
 
     def kv(b, i, j, lim_ref):
         return b, jnp.minimum(j, (lim_ref[b] - 1) // TK), 0

@@ -108,15 +108,31 @@ def _topk_gates(logits, k: int):
     return probs, topi, gates
 
 
-def _sigmoid_gates(logits, k: int, bias, scale: float):
+def _sigmoid_gates(logits, k: int, bias, scale: float, groups=(1, 1)):
     """(scores (N, E) f32, topi (N, k), gates (N, k)) of a sigmoid
-    router with a correction bias (DeepSeek-V3's `noaux_tc`, one group):
-    each expert's score is the sigmoid of its logit, the choice is the k
+    router with a correction bias (DeepSeek-V3's `noaux_tc`): each
+    expert's score is the sigmoid of its logit, the choice is the k
     largest of score + `bias` (E,), and the gates are the chosen scores
     WITHOUT the bias, renormalised over the k, times `scale` (the
-    `routed_scaling_factor`)."""
+    `routed_scaling_factor`).
+
+    `groups` = (`n_group`, `topk_group`): the E experts are `n_group`
+    groups of E / `n_group` in id order; a group's score is the sum of
+    its two largest score + bias, only the `topk_group` best groups'
+    experts may be chosen, and the k are the largest among them.  One
+    group is the whole router, and the step is not taken."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    choice = scores + bias.astype(jnp.float32)
+    n_group, topk_group = groups
+    if n_group > 1:
+        N, E = choice.shape
+        by_group = choice.reshape(N, n_group, E // n_group)
+        _, best = jax.lax.top_k(
+            jax.lax.top_k(by_group, 2)[0].sum(-1), topk_group)
+        kept = jnp.zeros((N, n_group), bool).at[
+            jnp.arange(N)[:, None], best].set(True)
+        choice = jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(N, E)
+    _, topi = jax.lax.top_k(choice, k)
     topv = jnp.take_along_axis(scores, topi, axis=-1)
     gates = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20) * scale
     return scores, topi, gates
@@ -174,7 +190,7 @@ def _expert_ffn(buf, w_in, w_out, w_gate):
 
 
 def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k, experts_held=None,
-                  score_bias=None, route_scale: float = 1.0):
+                  score_bias=None, route_scale: float = 1.0, groups=(1, 1)):
     """(N, D) rows -> ((N, D) f32, balance loss): exact top-k, every
     assignment computed: every expert over every row, the unrouted ones
     weighted 0.  No buffer, no capacity, row n's result a function of
@@ -189,7 +205,8 @@ def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k, experts_held=None,
 
     `score_bias` (E,): the router scores with a sigmoid and chooses
     with this bias added, gates without it (`_sigmoid_gates`, scaled by
-    `route_scale`); None: softmax gates (`_topk_gates`).
+    `route_scale`, the choice limited to the best `groups[1]` of
+    `groups[0]` groups of experts); None: softmax gates (`_topk_gates`).
 
     Right where a dispatch holds a few rows an expert (serving: 32
     rows, top 8 of 64), because the matmuls are then weight streaming
@@ -204,7 +221,7 @@ def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k, experts_held=None,
         E = logits.shape[-1]
         probs, topi, gates = _topk_gates(logits, top_k) \
             if score_bias is None else \
-            _sigmoid_gates(logits, top_k, score_bias, route_scale)
+            _sigmoid_gates(logits, top_k, score_bias, route_scale, groups)
         w = jnp.zeros((N, E), jnp.float32).at[
             jnp.arange(N)[:, None], topi].set(gates)   # (N, E), k nonzero
         if experts_held is not None:
@@ -228,7 +245,8 @@ def _moe_dropless(xf, router, w_in, w_out, w_gate, top_k, experts_held=None,
 def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
                 return_aux: bool = False, top_k: int = 1, w_gate=None,
                 dispatch_mode: str = "auto", dropless: bool = False,
-                experts_held=None, score_bias=None, route_scale: float = 1.0):
+                experts_held=None, score_bias=None, route_scale: float = 1.0,
+                groups=(1, 1)):
     """Top-k MoE FFN over flattened tokens (k=1 Switch, k=2 GShard).
 
     x: (..., D); router: (D, E), or a function (N, D) -> (N, E) f32
@@ -270,7 +288,8 @@ def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
     only: the stacks hold those of the router's experts and no others
     (`_moe_dropless`).  `score_bias` (E,) and `route_scale`, dropless
     only: a sigmoid router whose correction bias decides the choice and
-    not the gates (`_sigmoid_gates`)."""
+    not the gates (`_sigmoid_gates`); `groups` = (`n_group`,
+    `topk_group`) limits that choice to the best groups of experts."""
     orig_shape = x.shape
     D = orig_shape[-1]
     xf = x.reshape(-1, D)
@@ -284,7 +303,8 @@ def moe_forward(x, router, w_in, w_out, capacity_factor: float = 1.25,
                          "form's")
     if dropless:
         out, aux = _moe_dropless(xf, router, w_in, w_out, w_gate, top_k,
-                                 experts_held, score_bias, route_scale)
+                                 experts_held, score_bias, route_scale,
+                                 groups)
         out = out.astype(xf.dtype).reshape(orig_shape)
         return (out, aux) if return_aux else out
     logits = _router_logits(xf, router)

@@ -600,6 +600,14 @@ class ServeEngine:
             return toks, new, slot_state, tails, None
 
         dec = decode_step(model)
+        # a model whose tick updates the state in place (models/ling.py)
+        # is handed every slot's state as it stands: an idle slot's is
+        # never read before a prefill writes it again, and a mask would
+        # be a pass of its own over the whole state each way.  That
+        # holds for every model whose state lives in snapshots; the
+        # others keep the mask, so that their programs stay the ones
+        # their benchmark cells measured
+        in_place = bool(getattr(model, "updates_state_in_place", False))
 
         def decode_paged(params, buffers, toks, pos, active, tables,
                          caches, slot_state):
@@ -633,7 +641,10 @@ class ServeEngine:
             else:
                 dense = [spec_mod.gather_view(ck, cv, tables)
                          for ck, cv in caches]
-            if slot_state is not None:
+            if slot_state is not None and in_place:
+                dense = [kv + tuple(state)
+                         for kv, state in zip(dense, slot_state)]
+            elif slot_state is not None:
                 # an inactive slot reads zeros and keeps what it holds
                 live = active[:, None]
                 rows_of = lambda a: live.reshape(
@@ -643,7 +654,9 @@ class ServeEngine:
                          for kv, state in zip(dense, slot_state)]
             logits, dense = dec(params, buffers, toks[:, None], posc,
                                 dense)
-            if slot_state is not None:
+            if slot_state is not None and in_place:
+                slot_state = [tuple(d[2:]) for d in dense]
+            elif slot_state is not None:
                 slot_state = [
                     tuple(jnp.where(rows_of(s), n, s)
                           for n, s in zip(d[2:], state))
@@ -1386,6 +1399,8 @@ class ServeEngine:
                     self.metrics.on_snapshot_miss(keep - start0, evicted)
                 if src is not None:
                     self.metrics.on_snapshot_hit()
+                else:
+                    self.metrics.on_snapshot_cold()
             if start0:
                 self.metrics.on_prefix_hit(start0)
                 if self.pool.tails is not None:

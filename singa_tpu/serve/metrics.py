@@ -205,6 +205,11 @@ name                        kind       meaning
                                        the model records the choice
                                        while the program traces).
                                        ``snapshot()`` keeps the total
+``serve.state_snapshot_misses`` counter  one admission of a model whose
+                                       state lives in snapshots that
+                                       found none on its prompt's chain
+                                       and began from zeros.
+                                       ``snapshot()`` keeps the total
 ``serve.token``             counter    one token delivered to a request
                                        (prefill first token, decode
                                        tick, recovery/preemption replay
@@ -618,6 +623,7 @@ class ServeMetrics:
         self.mla_latent_bytes = 0
         self.mla_prefill_pairs = 0
         self.mla_prefill_kernel_chunks = 0
+        self.state_snapshot_misses = 0
         self._ttft = _Hist()
         self._token = _Hist()
 
@@ -779,6 +785,11 @@ class ServeMetrics:
         self.state_snapshot_hits += 1
         events.counter("serve.state_snapshot_hits", 1)
 
+    def on_snapshot_cold(self) -> None:
+        """One admission that found no state snapshot to enter from."""
+        self.state_snapshot_misses += 1
+        events.counter("serve.state_snapshot_misses", 1)
+
     def on_snapshot_miss(self, recomputed: int, evicted: bool) -> None:
         """One admission whose shared blocks run ``recomputed`` rows past
         the snapshot it entered from (or past position 0): it prefills
@@ -911,6 +922,7 @@ class ServeMetrics:
             "mla_latent_bytes": self.mla_latent_bytes,
             "mla_prefill_pairs": self.mla_prefill_pairs,
             "mla_prefill_kernel_chunks": self.mla_prefill_kernel_chunks,
+            "state_snapshot_misses": self.state_snapshot_misses,
             "accept_rate": self.accept_rate,
             "tokens_per_dispatch": self.tokens_per_dispatch,
             "ttft_ms": self._ttft.summary(),

@@ -199,17 +199,17 @@ def test_each_routing_rule_is_read(glm, fault):
     base = _ref_logits(params, ids)[0]
     orig = moe_ops._sigmoid_gates
 
-    def wrong(logits, k, bias, scale):
+    def wrong(logits, k, bias, scale, groups):
         if fault == "bias_in_gates":
-            s, i, _ = orig(logits, k, bias, scale)
+            s, i, _ = orig(logits, k, bias, scale, groups)
             v = jnp.take_along_axis(s + bias, i, -1)
             return s, i, v / v.sum(-1, keepdims=True) * scale
         if fault == "no_bias":
-            return orig(logits, k, 0 * bias, scale)
+            return orig(logits, k, 0 * bias, scale, groups)
         if fault == "softmax":
             s, i, g = moe_ops._topk_gates(logits, k)
             return s, i, g * scale
-        return orig(logits, k, bias, 1.0)
+        return orig(logits, k, bias, 1.0, groups)
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(moe_ops, "_sigmoid_gates", wrong)
@@ -237,8 +237,9 @@ def test_engine_matches_the_reference(glm, case):
     """A shared 272-token document and private questions: the first
     prompt prefills in two chunks, the next ones hit the document's
     blocks and prefill their questions; decode reads the arena in place
-    through the latent kernel (or a gathered view), and with a view of
-    whole KV tiles a chunk attends it through the prefill kernel.
+    through the latent kernel (or a gathered view), and steered as on a
+    TPU a chunk attends its view through the prefill kernel, whether
+    the view is whole KV tiles (1,024 rows) or ends in a part tile.
     Every served token is the reference's arg-max of the same sequence,
     the latent blocks the programs left are the reference's c_kv and
     rotated k_rope, and one more decode's logits are the reference's."""
@@ -257,10 +258,10 @@ def test_engine_matches_the_reference(glm, case):
     snap = eng.metrics.snapshot()
     assert snap["prefix_hit_tokens"] >= 2 * 272 - 2 * BS
     assert eng.compiled_counts() == (1, 1)
-    if case == "prefill-kernel":
-        assert snap["mla_prefill_kernel_chunks"] == snap["prefill_chunks"] > 3
-    else:
+    if case == "view":
         assert snap["mla_prefill_kernel_chunks"] == 0 < snap["prefill_chunks"]
+    else:
+        assert snap["mla_prefill_kernel_chunks"] == snap["prefill_chunks"] > 3
     params = _params(glm)
     for h, p in zip(hs, prompts):
         seq = np.concatenate([p, h.tokens])
@@ -337,8 +338,12 @@ def _masked_softmax(ql, qr, view, rope_view, limit, scale):
     (256, [900], 1024),         # a query tile's worth (256 x 4 heads)
     (300, [1024], 1024),        # two query tiles, the second padded
     (3, [9, 1536], 1536),       # two batch rows, a limit each
+    (9, [600], 640),            # a view of a tile and a part tile
+    (40, [640], 640),           # to its end, the part tile padded
+    (5, [300, 1100], 1100),     # two batch rows over two part tiles
 ], ids=["from-0", "under-edge", "on-edge", "over-edge", "view-end",
-        "one-row", "tile", "two-tiles", "batch"])
+        "one-row", "tile", "two-tiles", "batch", "part-tile",
+        "part-tile-end", "part-tile-batch"])
 def test_prefill_kernel_against_masked_softmax(dtype, rows, limits, length):
     """The prefill kernel (interpret mode) against masked softmax over
     the view, rope lanes zero-padded as the cache holds them."""
@@ -360,14 +365,16 @@ def test_prefill_kernel_against_masked_softmax(dtype, rows, limits, length):
 
 
 def test_the_prefill_kernel_is_chosen_by_the_view():
-    """bf16 or f32, whole 128-lane latent rows, a length of whole KV
-    tiles, a TPU: anything else keeps masked softmax over the view."""
+    """bf16 or f32, whole 128-lane latent rows, a TPU, any length (the
+    kernel pads the last KV tile): anything else keeps masked softmax
+    over the view."""
     takes = lambda shape, dt: kv_ops.attends_view(jnp.zeros(shape, dt))
     assert not takes((1, 1024, 128), jnp.float32)          # the CPU
     with _as_on_a_tpu():
         assert takes((1, 1024, 128), jnp.float32)
         assert takes((2, 18432, 512), jnp.bfloat16)
-        assert not takes((1, 640, 128), jnp.float32)       # length
+        assert takes((1, 640, 128), jnp.float32)           # a part tile
+        assert takes((1, 12544, 512), jnp.bfloat16)
         assert not takes((1, 1024, 96), jnp.float32)       # lanes
         assert not takes((1, 1024, 128), jnp.float16)      # dtype
 

@@ -191,13 +191,14 @@ def test_the_cells_shapes_compile_for_a_v5e(one_chip, S, H, K, max_blocks,
 def test_the_latent_prefill_compiles_for_a_v5e(one_chip, dtype):
     """Mosaic takes the latent prefill kernel at `serve-longdoc-closed`'s
     chunk, 256 rows x 20 heads over an 18,432-row view of 512 + 128
-    lanes, in bf16 (the timed program) and in f32 at HIGHEST (the
-    check's scorer), its tiles inside VMEM; one call, the view its
-    operand as the engine gathers it."""
+    lanes, and at `serve-think-closed`'s, 256 rows x 32 heads over a
+    12,544-row view (24 and a half KV tiles, the last padded with zero
+    rows to 12,800 inside the call's program), in bf16 (the timed
+    program) and in f32 at HIGHEST (the check's scorer), its tiles
+    inside VMEM; one call, the view its operand as the engine gathers
+    it."""
     from singa_tpu.ops.mla_attention import (mla_prefill_attention,
                                              prefill_tiles)
-    T, H, r, dr, S = 256, 20, 512, 64, 18432
-    assert prefill_tiles((1, S, r), dtype)
 
     def arg(shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
@@ -206,10 +207,15 @@ def test_the_latent_prefill_compiles_for_a_v5e(one_chip, dtype):
         return mla_prefill_attention(ql, qr, view, rope_view, limit,
                                      scale=1 / 16, interpret=False)
 
-    text = _compiled(attend, arg((1, T, H, r)), arg((1, T, H, dr)),
-                     arg((1, S, r)), arg((1, S, 128)),
-                     arg((), "int32")).as_text()
-    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    assert len(calls) == 1 and "mla_prefill_attention" in calls[0]
-    short = {"bfloat16": "bf16", "float32": "f32"}[dtype]
-    assert f"{short}[1,{S},{r}]" in calls[0]
+    T, r, dr = 256, 512, 64
+    for H, S, tiled in ((20, 18432, 18432), (32, 12544, 12800)):
+        assert prefill_tiles((1, S, r), dtype)
+        text = _compiled(attend, arg((1, T, H, r)), arg((1, T, H, dr)),
+                         arg((1, S, r)), arg((1, S, 128)),
+                         arg((), "int32")).as_text()
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line]
+        assert len(calls) == 1 and "mla_prefill_attention" in calls[0]
+        short = {"bfloat16": "bf16", "float32": "f32"}[dtype]
+        assert f"{short}[1,{tiled},{r}]" in calls[0]
+
