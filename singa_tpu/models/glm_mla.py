@@ -235,7 +235,8 @@ class _MLA(layer.Layer):
             with jax.named_scope("attn.mla.decode"):
                 o_lat = mla_decode_attention(
                     q_lat[:, 0], jnp.pad(q_rope[:, 0], pad),
-                    ck.pool, cr.pool, ck.tables, pos + 1, scale=scale)
+                    ck.pool, cr.pool, ck.tables, pos + 1, ck.plan,
+                    scale=scale)
             o = self._unabsorb(o_lat[:, None], wkv)
         else:
             # the rows over the slot's dense view: a prefill chunk, a
@@ -347,6 +348,12 @@ class GlmMla(GenerateMixin, model.Model):
                        for i in range(c.num_layers)]
         self.norm_f = layer.RMSNorm(c.dim, eps=c.eps)
         self.lm_head = layer.Linear(c.vocab_size, bias=False)
+
+    @property
+    def latent_heads(self) -> int:
+        """Query heads of a latent layer: how many rows a slot stacks
+        into the decode kernel's groups (``ops.mla_attention``)."""
+        return self.cfg.num_heads
 
     def _head(self, x: Tensor) -> Tensor:
         with jax.named_scope("lm_head"):

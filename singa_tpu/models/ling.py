@@ -269,6 +269,11 @@ class Ling(GenerateMixin, model.Model):
         self.norm_f = layer.RMSNorm(c.dim, eps=c.eps)
         self.lm_head = layer.Linear(c.vocab_size, bias=False)
 
+    @property
+    def latent_heads(self) -> int:
+        """Query heads of the MLA layers (``GlmMla.latent_heads``)."""
+        return self.cfg.num_heads
+
     def _head(self, x: Tensor) -> Tensor:
         with jax.named_scope("lm_head"):
             return self.lm_head(self.norm_f(x))

@@ -101,18 +101,22 @@ class PagedKV:
     (S,) int32, where this tick's one token a slot is written.
     :func:`update_cache` and :func:`cached_sdpa` take it where a dense
     (S, max_len, K, D) cache would go, so a model's cached attention
-    serves from the arena without learning what an arena is."""
+    serves from the arena without learning what an arena is.  ``plan``:
+    for a latent pool, which slots read their shared leading blocks once
+    for a group (``ops.mla_attention.group_plan``); None elsewhere."""
 
-    __slots__ = ("pool", "tables", "block", "offset")
+    __slots__ = ("pool", "tables", "block", "offset", "plan")
 
-    def __init__(self, pool, tables, block, offset):
+    def __init__(self, pool, tables, block, offset, plan=None):
         self.pool = pool
         self.tables = tables
         self.block = block
         self.offset = offset
+        self.plan = plan
 
     def tree_flatten(self):
-        return (self.pool, self.tables, self.block, self.offset), None
+        return (self.pool, self.tables, self.block, self.offset,
+                self.plan), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -188,8 +192,8 @@ def update_cache(ck, cv, k_new, v_new, pos):
     if isinstance(ck, PagedKV):
         pk, pv = scatter_token_kv(ck.pool, cv.pool, ck.block, ck.offset,
                                   k_new[:, 0], v_new[:, 0])
-        return (PagedKV(pk, ck.tables, ck.block, ck.offset),
-                PagedKV(pv, cv.tables, cv.block, cv.offset))
+        return (PagedKV(pk, ck.tables, ck.block, ck.offset, ck.plan),
+                PagedKV(pv, cv.tables, cv.block, cv.offset, cv.plan))
     if getattr(pos, "ndim", 0):
         def row(c, n, p):
             return jax.lax.dynamic_update_slice_in_dim(c, n, p, axis=0)

@@ -165,6 +165,7 @@ from ..obs import flight as obs_flight
 from ..obs import record as obs_record
 from ..obs import trace as obs_trace
 from ..ops import kv_cache as kv_ops
+from ..ops import mla_attention
 from ..utils import failure
 from ..utils.failure import Heartbeat
 from . import mem as serve_mem
@@ -609,7 +610,7 @@ class ServeEngine:
         # their benchmark cells measured
         in_place = bool(getattr(model, "updates_state_in_place", False))
 
-        def decode_paged(params, buffers, toks, pos, active, tables,
+        def decode_paged(params, buffers, toks, pos, active, tables, plan,
                          caches, slot_state):
             # inactive slots are masked: position clamped to 0 and the
             # write redirected to the null block (their table row may
@@ -632,10 +633,13 @@ class ServeEngine:
             paged = kv_ops.reads_blocks(
                 next(ck for ck, _ in caches if ck is not None))
             if paged:
-                # an inactive slot reads the null block it writes
+                # an inactive slot reads the null block it writes; a
+                # latent pool's slots read their shared blocks once a
+                # group, as the host's ``plan`` says (BlockPool.group_plan:
+                # None for every other model)
                 rows = jnp.where(active[:, None], tables, 0)
-                dense = [(kv_ops.PagedKV(ck, rows, wb, off),
-                          kv_ops.PagedKV(cv, rows, wb, off))
+                dense = [(kv_ops.PagedKV(ck, rows, wb, off, plan),
+                          kv_ops.PagedKV(cv, rows, wb, off, plan))
                          if ck is not None else (None, None)
                          for ck, cv in caches]
             else:
@@ -713,7 +717,7 @@ class ServeEngine:
             self._prefill = jax.jit(prefill_chunk,
                                     donate_argnums=(9, 10, 11, 12))
             self._verify = None
-        self._decode = jax.jit(decode_paged, donate_argnums=(6, 7))
+        self._decode = jax.jit(decode_paged, donate_argnums=(7, 8))
         self._handoff = jax.jit(handoff_gather)
 
     # -- introspection ----------------------------------------------------
@@ -827,8 +831,8 @@ class ServeEngine:
         def lower_decode():
             return self._decode.lower(
                 self._params, self._buffers, self._toks, self.pool.pos,
-                self.pool.active, self.pool.tables, self.pool.caches,
-                self.pool.slot_state)
+                self.pool.active, self.pool.tables, self.pool.group_plan(),
+                self.pool.caches, self.pool.slot_state)
 
         def lower_verify():
             return self._verify.lower(
@@ -1653,11 +1657,15 @@ class ServeEngine:
         # what this tick's attention has to read, from the host's own
         # slot state (no device read): each active slot's blocks up to
         # the position it writes, against every slot's whole table row
-        live = int((pool.pos[pool.active] // pool.block_size + 1).sum())
+        blocks = np.where(pool.active, pool.pos // pool.block_size + 1, 0)
+        live = int(blocks.sum())
         self.metrics.on_decode_kv(live, pool.num_slots * pool.max_blocks)
-        if pool.latent_row_bytes:
+        plan = pool.group_plan()
+        if plan is not None:
+            # the latent kernel fetches a group's shared blocks once
+            row = pool.block_size * pool.latent_row_bytes
             self.metrics.on_mla_decode(
-                live * pool.block_size * pool.latent_row_bytes)
+                mla_attention.blocks_fetched(plan, blocks) * row, live * row)
         if pool.snapshots is not None:
             # what the tick's state updates read and write: each
             # running slot's state once each way
@@ -1670,7 +1678,7 @@ class ServeEngine:
                  self.pool.slot_state) = self._dispatch(
                     "serve.decode", self._decode,
                     (self._params, self._buffers, self._toks,
-                     *self.pool.snapshot(), self.pool.caches,
+                     *self.pool.snapshot(), plan, self.pool.caches,
                      self.pool.slot_state),
                     active=len(self._running))
                 self.pool.advance(1)

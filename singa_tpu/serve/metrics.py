@@ -187,17 +187,27 @@ name                        kind       meaning
                                        totals of all five
 ``serve.mla_latent_bytes``  counter    bytes of latent rows one decode
                                        tick of a latent-attention model
-                                       (models/glm_mla.py) reads: its
-                                       active slots' live blocks x
-                                       block_size x the latent row's
-                                       bytes (both pools, every layer);
-                                       host-known, never emitted for
-                                       another model
+                                       (models/glm_mla.py) fetches: each
+                                       group's shared run of blocks once
+                                       and each active slot's own blocks
+                                       (ops.mla_attention.group_plan,
+                                       blocks_fetched) x block_size x
+                                       the latent row's bytes (both
+                                       pools, every layer); host-known,
+                                       never emitted for another model
+``serve.mla_latent_bytes_attended``
+                            counter    bytes of latent rows that tick
+                                       attends: each active slot's live
+                                       blocks, shared or not, x
+                                       block_size x the row's bytes;
+                                       1 - fetched / attended is the
+                                       share a group's shared pass saves
 ``serve.mla_prefill_pairs`` counter    (query row, cached row) pairs one
                                        prefill chunk's latent attention
                                        scores: its valid rows x the
                                        rows below its end.
-                                       ``snapshot()`` keeps both totals
+                                       ``snapshot()`` keeps all three
+                                       totals
 ``serve.mla_prefill_kernel_chunks``  counter  one such chunk dispatched
                                        through a prefill program whose
                                        trace took the fused prefill
@@ -621,6 +631,7 @@ class ServeMetrics:
         self.ssm_state_bytes = 0
         # latent-attention models (models/glm_mla.py); 0 otherwise
         self.mla_latent_bytes = 0
+        self.mla_latent_bytes_attended = 0
         self.mla_prefill_pairs = 0
         self.mla_prefill_kernel_chunks = 0
         self.state_snapshot_misses = 0
@@ -812,11 +823,13 @@ class ServeMetrics:
         self.ssm_state_bytes += nbytes
         events.counter("serve.ssm_state_bytes", nbytes)
 
-    def on_mla_decode(self, nbytes: int) -> None:
-        """One decode tick whose latent attention reads ``nbytes`` of
-        cached latent rows."""
-        self.mla_latent_bytes += nbytes
-        events.counter("serve.mla_latent_bytes", nbytes)
+    def on_mla_decode(self, fetched: int, attended: int) -> None:
+        """One decode tick whose latent attention fetches ``fetched``
+        bytes of cached latent rows and attends ``attended``."""
+        self.mla_latent_bytes += fetched
+        self.mla_latent_bytes_attended += attended
+        events.counter("serve.mla_latent_bytes", fetched)
+        events.counter("serve.mla_latent_bytes_attended", attended)
 
     def on_mla_prefill(self, pairs: int, kernel: bool) -> None:
         """One prefill chunk whose latent attention scores ``pairs``
@@ -920,6 +933,7 @@ class ServeMetrics:
             "prefix_tokens_recomputed": self.prefix_tokens_recomputed,
             "ssm_state_bytes": self.ssm_state_bytes,
             "mla_latent_bytes": self.mla_latent_bytes,
+            "mla_latent_bytes_attended": self.mla_latent_bytes_attended,
             "mla_prefill_pairs": self.mla_prefill_pairs,
             "mla_prefill_kernel_chunks": self.mla_prefill_kernel_chunks,
             "state_snapshot_misses": self.state_snapshot_misses,

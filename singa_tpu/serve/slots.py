@@ -127,6 +127,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import mla_attention
 from . import mem
 
 __all__ = ["BlockPool", "has_side_state"]
@@ -289,6 +290,12 @@ class BlockPool:
         self.latent_row_bytes = sum(
             mem.arena_block_bytes([c]) // block_size for c in self.caches
             if c[0] is not None and len(c[0].shape) == 3)
+        #: the latent decode kernel's group limits where the pools hold
+        #: latent rows (:meth:`group_plan`), else None
+        self._group_limits = mla_attention.group_limits(
+            model.latent_heads, block_size, self.max_blocks) \
+            if self.latent_row_bytes else None
+        self._plan = None
         #: bytes of side state ONE slot holds across the layers
         self.slot_state_bytes = _nbytes(self.slot_state) // num_slots \
             if side else 0
@@ -689,6 +696,7 @@ class BlockPool:
                 self.ref[b] = 1
         # the rest of the row is already null: release() left it so
         self.tables[slot, :len(blocks)] = blocks
+        self._plan = None
 
     def append_block(self, slot: int, block: int) -> None:
         """Decode-time growth: one more block for a slot whose next
@@ -724,6 +732,7 @@ class BlockPool:
         self.active[slot] = False
         self.pos[slot] = 0
         self._free_slots.append(slot)
+        self._plan = None
 
     def activate(self, slot: int, length: int) -> None:
         """Mark ``slot`` live with ``length`` valid cache positions
@@ -731,6 +740,7 @@ class BlockPool:
         blocks)."""
         self.pos[slot] = length
         self.active[slot] = True
+        self._plan = None
 
     def advance(self, written) -> None:
         """The tick just dispatched wrote ``written`` more positions
@@ -745,6 +755,26 @@ class BlockPool:
         the call returns (module docstring) while the next admission,
         growth or release already stores into :attr:`tables`."""
         return self.tables.copy()
+
+    def group_plan(self) -> Optional[np.ndarray]:
+        """Which running slots read which of their leading blocks once
+        for a group in the latent decode kernel
+        (``ops.mla_attention.group_plan``, ``(2, num_slots)`` int32),
+        for pools that hold latent rows; None for any other model.
+        Made again only after an admission or a release changed a row
+        or the running set, from the tables and the positions as they
+        then stand: a run shared then stays shared, since positions
+        only grow while a slot runs.  A new array each time, never
+        edited, so a dispatch may hold it."""
+        if self._group_limits is None:
+            return None
+        if self._plan is None:
+            max_group, min_blocks = self._group_limits
+            self._plan = mla_attention.group_plan(
+                self.tables, np.where(self.active,
+                                      self.pos // self.block_size, 0),
+                max_group=max_group, min_blocks=min_blocks)
+        return self._plan
 
     def snapshot(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """``(pos, active, tables)`` for one decode or verify dispatch,

@@ -27,7 +27,9 @@ import reference_glm_mla as ref
 from singa_tpu import models, tensor
 from singa_tpu.ops import kv_cache as kv_ops
 from singa_tpu.ops import moe as moe_ops
-from singa_tpu.ops.mla_attention import (mla_decode_attention,
+from singa_tpu.ops import mla_attention
+from singa_tpu.ops.mla_attention import (blocks_fetched, group_plan,
+                                         mla_decode_attention,
                                          mla_prefill_attention)
 from singa_tpu.serve import ServeEngine
 from singa_tpu.serve.slots import BlockPool
@@ -281,6 +283,74 @@ def test_engine_matches_the_reference(glm, case):
     assert long.finish_reason is None
 
 
+def test_slots_of_a_document_read_it_once_a_tick(glm):
+    """Three requests behind one 272-token document run at once, steered
+    as on a TPU with chunks of 8 blocks (so that a 34-block document is
+    a run worth a group): the decode kernel reads the document once for
+    the group. Each tick's `mla_latent_bytes` is what a plain walk of
+    that tick's plan fetches (each group's run once, each running slot's
+    own blocks) and `mla_latent_bytes_attended` every running slot's
+    live blocks, as the counter was before groups; the plan is made
+    again only where an admission or a release came between two ticks;
+    and every served token is the reference's arg-max."""
+    rng = np.random.default_rng(11)
+    doc = rng.integers(0, VOCAB, 272).astype(np.int32)
+    prompts = [np.concatenate([doc, rng.integers(0, VOCAB, n)])
+               .astype(np.int32) for n in (30, 7, 45)]
+    log = []
+    with HI, pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mla_attention, "_CHUNK_ROWS", 8 * BS)
+        # a view of its own: the kernel traces here with these chunks
+        eng = _engine(glm, True, max_len=768)
+        pool = eng.pool
+        assert pool._group_limits == (32, 8)
+        for name in ("map_slot", "activate", "release"):
+            real = getattr(pool, name)
+            mp.setattr(pool, name, lambda *a, _r=real, _n=name: (
+                log.append((_n,)), _r(*a))[1])
+        counted = eng.metrics.on_mla_decode
+        mp.setattr(eng.metrics, "on_mla_decode", lambda f, a: (
+            log.append(("tick", pool.group_plan(), pool.pos.copy(),
+                        pool.active.copy(), f, a)), counted(f, a))[1])
+        before = eng.metrics.snapshot()
+        hs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        eng.run_until_idle()
+    snap = eng.metrics.snapshot()
+    ticks = [e for e in log if e[0] == "tick"]
+    row = BS * pool.latent_row_bytes
+    fetched = attended = 0
+    for _, plan, pos, active, f, a in ticks:
+        walk = 0
+        for s in np.flatnonzero(active):
+            leader, run = plan[:, s]
+            if leader == s:
+                walk += run                       # the group's shared pass
+            walk += pos[s] // BS + 1 - run        # the slot's own pass
+        assert f == walk * row
+        assert a == sum(pos[active] // BS + 1) * row
+        fetched, attended = fetched + f, attended + a
+    assert snap["mla_latent_bytes"] - before["mla_latent_bytes"] == fetched
+    assert snap["mla_latent_bytes_attended"] \
+        - before["mla_latent_bytes_attended"] == attended
+    # the three read the document's 34 blocks through one group
+    assert max(int((t[1][1] > 0).sum()) for t in ticks) == 3
+    assert fetched < attended
+    changed = False
+    for e in log:
+        if e[0] != "tick":
+            changed = True
+            continue
+        if not changed:
+            assert e[1] is last
+        last, changed = e[1], False
+    params = _params(glm)
+    for h, p in zip(hs, prompts):
+        seq = np.concatenate([p, h.tokens])
+        logits = _ref_logits(params, seq)[0]
+        np.testing.assert_array_equal(
+            logits[len(p) - 1:-1].argmax(-1), np.asarray(h.tokens))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kernel_at_ragged_lengths(dtype):
     """Lengths on, under and over block edges and across chunks, tables
@@ -307,6 +377,96 @@ def test_kernel_at_ragged_lengths(dtype):
         np.testing.assert_allclose(np.asarray(got[s], np.float32), want,
                                    atol=tol, rtol=tol)
 
+
+
+def _softmax_rows(ql, qr, lat, rop, tables, lengths, scale):
+    """Each slot's absorbed attention over its gathered rows, f32 numpy."""
+    r, dr = lat.shape[-1], rop.shape[-1]
+    out = []
+    for s, n in enumerate(lengths):
+        L = np.asarray(lat[tables[s]], np.float32).reshape(-1, r)[:n]
+        R = np.asarray(rop[tables[s]], np.float32).reshape(-1, dr)[:n]
+        sc = (np.asarray(ql[s], np.float32) @ L.T
+              + np.asarray(qr[s], np.float32) @ R.T) * scale
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        out.append((p / p.sum(-1, keepdims=True)) @ L)
+    return np.stack(out)
+
+
+#: ten slots of 32 heads (groups of at most four): six share a
+#: 150-block run (a chunk of 128 blocks and 22), the third's row parts
+#: from the others' at block 140 and the second ends on the run's edge;
+#: the eighth and tenth share six blocks; the seventh reads alone; the
+#: ninth does not run (length 1 over the null block)
+_LENGTHS = np.asarray([1203, 1200, 1190, 1270, 1280, 1201, 33, 700, 1, 400],
+                      np.int32)
+
+
+def _shared_rows(rng, S=10, mb=160):
+    rows = rng.permutation(np.arange(1, S * mb + 1)).reshape(S, mb)
+    rows[1:6, :150] = rows[0, :150]
+    rows[2, 140:150] = rows[6, 140:150]
+    rows[9, :6] = rows[7, :6]
+    rows[8] = 0
+    return rows
+
+
+def test_the_plan_groups_slots_by_their_shared_runs():
+    """Six sharers split into a group of four and one of two, a member
+    reads only the run its row shares with the leader's, a run counts
+    whole blocks below both positions, and the plan is the same function
+    of the same tables on the host and in a program."""
+    rows = _shared_rows(np.random.default_rng(6))
+    full = np.where(_LENGTHS > 1, (_LENGTHS - 1) // 8, 0)
+    plan = group_plan(rows, full, max_group=4, min_blocks=4)
+    np.testing.assert_array_equal(plan, [
+        [0, 0, 0, 0, 4, 4, 6, 7, 8, 7],
+        [150, 149, 140, 150, 150, 150, 0, 6, 0, 6]])
+    assert plan.dtype == np.int32
+    on_device = jax.jit(lambda t, f: group_plan(
+        t, f, max_group=4, min_blocks=4, xp=jnp))(rows, full)
+    np.testing.assert_array_equal(np.asarray(on_device), plan)
+    # a run under the threshold reads alone; one group cap of 2 keeps
+    # three groups of two
+    np.testing.assert_array_equal(
+        group_plan(rows, full, max_group=4, min_blocks=8)[1],
+        [150, 149, 140, 150, 150, 150, 0, 0, 0, 0])
+    np.testing.assert_array_equal(
+        group_plan(rows, full, max_group=2, min_blocks=8),
+        [[0, 0, 2, 2, 4, 4, 6, 7, 8, 9],
+         [149, 149, 140, 140, 150, 150, 0, 0, 0, 0]])
+    live = np.where(_LENGTHS > 1, full + 1, 0)
+    # each group's run once, then each slot's own blocks
+    assert blocks_fetched(plan, live) == (
+        150 + 150 + 6 + sum(live) - sum(plan[1]))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_kernel_against_softmax_over_each_slots_rows(dtype):
+    """The two passes (interpret mode) against softmax over each slot's
+    gathered rows: groups of four and of two out of six sharers, a
+    member whose run is shorter than its leader's, runs of 150 and 6
+    blocks (not whole chunks of 128), a slot alone, a slot that does not
+    run, a plan made in the program, and a member whose length ends on
+    its run's edge (nothing of its own to walk)."""
+    rng = np.random.default_rng(7)
+    S, H, r, dr, bs, mb = 10, 32, 128, 128, 8, 160
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)
+    lat, rop = f(S * mb + 1, bs, r), f(S * mb + 1, bs, dr)
+    ql, qr = f(S, H, r), f(S, H, dr)
+    rows = _shared_rows(rng)
+    full = np.where(_LENGTHS > 1, (_LENGTHS - 1) // bs, 0)
+    plan = group_plan(rows, full, max_group=4, min_blocks=4)
+    edge = plan.copy()
+    edge[1, 1] = 150                      # 150 blocks = its 1,200 rows
+    want = _softmax_rows(ql, qr, lat, rop, rows, _LENGTHS, 0.1)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for p in (plan, edge, None):
+        got = mla_decode_attention(ql, qr, lat, rop, jnp.asarray(rows),
+                                   _LENGTHS, p, scale=0.1, interpret=True)
+        assert got.shape == (S, H, r) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   atol=tol, rtol=tol)
 
 
 def _masked_softmax(ql, qr, view, rope_view, limit, scale):

@@ -12,6 +12,7 @@ program).
 """
 
 import contextlib
+import dataclasses
 import os
 
 import jax
@@ -485,8 +486,31 @@ def test_the_scopes_are_in_the_lowered_programs(ling):
         assert name in text["prefill_chunk"], name
     assert "kda.decode" not in text["prefill_chunk"]
     assert "kda/kda.decode" in text["decode"]
-    assert "kda_decode" in text["decode"]
     assert "mla_decode_attention" in text["decode"]
+    # the state kernel by its name in the decode program itself (in
+    # interpret mode it leaves no call of its own in the lowered text),
+    # where the state is whole 128-lane heads, as the kernel needs
+    wide = _build(cfg=dataclasses.replace(CFG, head_dim=128))
+    eng = ServeEngine(wide, num_slots=2, max_len=64, block_size=BS)
+    pool = eng.pool
+    with _as_on_a_tpu():
+        jaxpr = jax.make_jaxpr(eng._decode)(
+            eng._params, eng._buffers, eng._toks, pool.pos, pool.active,
+            pool.tables, pool.group_plan(), pool.caches, pool.slot_state)
+    assert {"kda_decode", "mla_decode_attention"} \
+        <= set(_kernel_names(jaxpr.jaxpr))
+
+
+def _kernel_names(jaxpr):
+    """The name of every `pallas_call` of a jaxpr and of those inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _kernel_names(inner)
 
 
 def test_training_is_refused(ling):

@@ -6,7 +6,8 @@ dense view.  Interpret mode cannot see a misaligned slice or too much
 VMEM, so the three serve cells' real shapes are also compiled for a
 described (not attached) TPU v5e, skipped where none can be described,
 and so are the latent prefill kernel's (ops/mla_attention.py) at the
-latent cell's chunk and the f32 view its check scores through.
+latent cell's chunk and the f32 view its check scores through, and the
+latent decode kernel's two passes at both latent cells' shapes.
 """
 
 import re
@@ -219,3 +220,33 @@ def test_the_latent_prefill_compiles_for_a_v5e(one_chip, dtype):
         short = {"bfloat16": "bf16", "float32": "f32"}[dtype]
         assert f"{short}[1,{tiled},{r}]" in calls[0]
 
+
+
+@pytest.mark.parametrize("S,H,max_blocks", [
+    (32, 20, 576),          # serve-longdoc-closed
+    (64, 32, 384),          # serve-think-closed
+])
+def test_the_latent_decode_compiles_for_a_v5e(one_chip, S, H, max_blocks):
+    """Mosaic takes both passes of the latent decode kernel at the two
+    latent cells' shapes, bf16 pools of 32-row blocks: the groups' shared
+    pass (their members' heads stacked as the MXU's rows) and the
+    slots' own pass, with the plan handed in and made in the program."""
+    from singa_tpu.ops.mla_attention import mla_decode_attention
+
+    def arg(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def attend(ql, qr, lat, rop, tables, lengths, plan=None):
+        return mla_decode_attention(ql, qr, lat, rop, tables, lengths, plan,
+                                    scale=1 / 16, interpret=False)
+
+    pools = (arg((S * max_blocks + 1, 32, 512)),
+             arg((S * max_blocks + 1, 32, 128)))
+    for plan in ((), (arg((2, S), "int32"),)):
+        text = _compiled(attend, arg((S, H, 512)), arg((S, H, 128)), *pools,
+                         arg((S, max_blocks), "int32"), arg((S,), "int32"),
+                         *plan).as_text()
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line]
+        assert len(calls) == 2
+        assert any("mla_decode_attention_shared" in c for c in calls)
